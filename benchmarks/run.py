@@ -41,6 +41,17 @@ def _write_json(suite: str, rows: list) -> pathlib.Path:
     return path
 
 
+def _use_compile_cache() -> None:
+    """JAX's persistent compile cache: ``$JAX_COMPILATION_CACHE_DIR`` when
+    set (JAX reads it itself), else ``.jax_cache/`` in the checkout — a fixed
+    path, since the path is part of the cache key."""
+    import os
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        import jax
+        jax.config.update("jax_compilation_cache_dir",
+                          str(_REPO_ROOT / ".jax_cache"))
+
+
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
@@ -52,6 +63,7 @@ def main() -> None:
                     help="persist each suite's rows to BENCH_<suite>.json "
                          "at the repo root")
     args = ap.parse_args()
+    _use_compile_cache()
 
     from . import common
     if args.quick:

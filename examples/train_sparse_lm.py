@@ -52,7 +52,9 @@ def main():
     data = SyntheticLM(DataConfig(seed=0, vocab_size=cfg.vocab_size,
                                   seq_len=args.seq, global_batch=args.batch))
     step = jax.jit(make_train_step(model.loss_fn, tcfg), donate_argnums=(0,))
-    state = init_state(model.init(jax.random.PRNGKey(0)), tcfg)
+    # the sparse patterns ride the state as operands, not jit constants
+    state = init_state(model.init(jax.random.PRNGKey(0)), tcfg,
+                       consts=model.sparse_inputs())
 
     driver = TrainDriver(
         DriverConfig(total_steps=args.steps, checkpoint_every=50,

@@ -459,8 +459,8 @@ def health() -> dict:
     ``{"counters": {...}, "breakers": {"backend:logical": {...}}}``.
 
     Counters include the named demotions that used to be silent warnings
-    (``demote:quant_range``, ``demote:max_win_pallas_to_xla``,
-    ``demote:chain_fuse``, ``demote:attn_fuse``, ...), sentinel firings
+    (``demote:quant_range``, ``demote:chain_fuse``, ``demote:attn_fuse``,
+    ``demote:traced_pattern_to_xla``, ...), sentinel firings
     (``sentinel:<site>``), kernel reroutes
     (``kernel_reroute:<from>-><to>:<logical>``), and pattern
     validation/repair events.  Breakers carry state / consecutive failures /

@@ -19,8 +19,8 @@ plan/execute subsystem, in four pillars.
    optimizer state (``train/step.py`` ``skip_nonfinite``).
 
 3. **Backend degradation ladder** (``guarded_call`` + ``CircuitBreaker``):
-   a per-(backend, logical-kernel) circuit breaker.  Kernel failures (real
-   or injected at the ``kernel_execute`` fault sites) re-route the call down
+   a per-(backend, logical-kernel) circuit breaker.  Kernel failures
+   injected at the ``kernel_execute`` fault sites re-route the call down
    the demotion ladder (``registry.DEMOTION``: pallas→xla, bsr→xla, sharded
    demotes its inner backend) — gradient math is kernel-independent (one
    backward per substrate family, ``core/vjp.py``), so a rerouted forward
@@ -38,7 +38,8 @@ Everything observable lands in the process ``HEALTH`` registry
 (``api.health()`` / ``engine.metrics()["health"]``): breaker state/trips/
 recoveries, reroutes, sentinel firings, pattern repairs, and the named
 demotion counters for decisions that used to be silent ``warnings.warn``
-calls (quant range fallback, ``max_win``, the fuse crossovers).
+calls (quant range fallback, the fuse crossovers, a traced pattern without
+prep).
 
 Under ``jit`` the breaker decision and sentinel wiring bake at trace time
 (the guard is host-side dispatch); eager execution — the fault-matrix test
@@ -58,7 +59,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.runtime.faults import active_injector
+from repro.runtime.faults import InjectedFault, active_injector
 
 
 class PatternError(ValueError):
@@ -320,13 +321,14 @@ class HealthRegistry:
 HEALTH = HealthRegistry()
 
 
-#: kernel-failure types the degradation ladder catches and reroutes.
-#: Usage errors (ValueError/TypeError/KeyError) propagate — a wrong-shaped
-#: operand is the caller's bug, not a backend health signal — and a
-#: sentinel's ``NumericFault`` is re-raised explicitly (the user asked for
-#: it).  ``InjectedFault`` and ``PlanBuildError`` are RuntimeErrors, as are
-#: jax's runtime errors.
-FAILURE_TYPES = (RuntimeError, NotImplementedError, ArithmeticError)
+#: kernel-failure types the degradation ladder catches and reroutes: the
+#: faults the chaos harness injects (``runtime/faults.py``).  Everything else
+#: propagates — a usage error (ValueError/TypeError/KeyError) is the
+#: caller's bug, a sentinel's ``NumericFault`` is what the user asked for,
+#: and a kernel that fails to lower or compile (``NotImplementedError`` from
+#: Mosaic, a VMEM ``RESOURCE_EXHAUSTED``) or fails at run time is a bug in
+#: the kernel: rerouting it to XLA would hide it behind a counter.
+FAILURE_TYPES = (InjectedFault,)
 
 
 def guarded_call(logical: str, backend: str, primary: Callable[[], Any], *,

@@ -37,7 +37,6 @@ import dataclasses
 import functools
 import hashlib
 import inspect
-import warnings
 from collections import OrderedDict
 from typing import Any
 
@@ -55,7 +54,7 @@ from .formats import (BSR, CSR, ELL, BalancedCOO, csr_to_balanced, csr_to_bsr,
 from .guardrails import HEALTH, NumericFault
 from .selector import (SelectorThresholds, TileGeometry, default_thresholds,
                        select_kernel)
-from .stats import MatrixStats, balanced_tile_span, matrix_stats
+from .stats import MatrixStats, matrix_stats
 from .vjp import (_exec_attn, _exec_balanced, _exec_bsr,  # noqa: F401 (re-export)
                   _exec_chain, _exec_ell, _exec_sddmm, _stream_to_balanced)
 
@@ -382,12 +381,13 @@ class PlanBuilder:
                         "sharded substrates need a plan built with "
                         "mesh=... (plan(csr, backend='sharded', mesh=m))")
                 from . import shard as shard_mod
-                sub = shard_mod.build_sharded_substrate(
-                    self.csr, self.shard_spec, self.mesh,
-                    inner_kind=kind[len("shard_"):], tile=self.tile,
-                    inner_backend=(self.inner_backend
-                                   or registry.default_backend()),
-                    quant=self.quant)
+                sub = shard_mod.place_substrate(
+                    shard_mod.build_sharded_substrate(
+                        self.csr, self.shard_spec, self.mesh,
+                        inner_kind=kind[len("shard_"):], tile=self.tile,
+                        inner_backend=(self.inner_backend
+                                       or registry.default_backend()),
+                        quant=self.quant))
                 if (self.quant is not None and kind == "shard_balanced"
                         and sub.scales is None):
                     HEALTH.bump("demote:quant_range")
@@ -622,10 +622,11 @@ def plan(csr: CSR, *, n_hint: int | None = None,
     ``TileGeometry``; with ``geometry=None`` the thresholds' autotuned table
     is consulted per (pattern fingerprint, ``n_hint`` bucket, backend) —
     ``kernels/tune.py`` is the producer.  ``tile=None`` takes the geometry's
-    nnz quota (default 512); an explicit ``tile`` always wins.  Plans whose
-    worst tile would span more than ``thresholds.max_win`` rows fall back
-    from Pallas to xla with a warning (the spill window — and its one-hot
-    matmul — would otherwise be sized by an empty-row gap).
+    nnz quota (default 512); an explicit ``tile`` always wins.  The fused
+    Pallas kernels size their work per ``wb``-row output block, so a tile
+    spanning a long empty-row gap costs only its own visits; only the spill
+    parity path sizes a window by the worst tile (``thresholds.max_win``
+    warns there, ``kernels/vsr.plan_windows``).
 
     Sharded backend: ``mesh`` (required) names the device mesh; the
     partitioner is chosen from the matrix stats (``cv`` vs.
@@ -637,8 +638,7 @@ def plan(csr: CSR, *, n_hint: int | None = None,
     ``quant`` (DESIGN.md §8): ``"int8"``/``"fp8"`` store the balanced-family
     value stream quantized per nnz-tile with in-kernel dequant.  Gated by
     ``thresholds.quant_min_n`` (below it the dequant ALU cost beats the byte
-    savings, so the plan stays unquantized); an fp8 request on a runtime
-    without the dtype demotes to int8; per-tile dynamic-range blowups demote
+    savings, so the plan stays unquantized); per-tile dynamic-range blowups demote
     to unquantized at substrate-build time (``core/quant.check_tile_range``).
 
     ``chain_op`` (DESIGN.md §9) tags the plan with the SDDMM→SpMM chain
@@ -663,11 +663,6 @@ def plan(csr: CSR, *, n_hint: int | None = None,
         if quant not in quant_mod.QUANT_MODES:
             raise ValueError(f"unknown quant mode {quant!r}; expected one of "
                              f"{quant_mod.QUANT_MODES}")
-        if not quant_mod.supports(quant):
-            warnings.warn(f"quant={quant!r} is not supported by this jax "
-                          "build; demoting to 'int8'", stacklevel=2)
-            HEALTH.bump("demote:fp8_to_int8")
-            quant = "int8"
         if n_hint is not None and n_hint < th.quant_min_n:
             quant = None    # selector crossover: not worth it at this N
     stats = matrix_stats(csr)
@@ -681,29 +676,6 @@ def plan(csr: CSR, *, n_hint: int | None = None,
         geometry = th.geometry_for(fp, n_hint, lookup_backend)
     if tile is None:
         tile = geometry.tile if geometry is not None else 512
-    if backend == "pallas":
-        span = balanced_tile_span(csr, tile)
-        if span > th.max_win:
-            warnings.warn(
-                f"worst balanced tile spans {span} rows > thresholds."
-                f"max_win={th.max_win} (empty-row gaps inflate the spill "
-                "window without adding work); falling back to the xla "
-                "backend", stacklevel=2)
-            HEALTH.bump("demote:max_win_pallas_to_xla")
-            backend = "xla"
-    elif (backend == "sharded"
-          and (inner_backend or registry.default_backend()) == "pallas"):
-        # the same guard one level down: a pathological global span means
-        # per-shard spans (same quota, shard-local alignment) are in the
-        # same regime, so demote the *inner* backend
-        span = balanced_tile_span(csr, tile)
-        if span > th.max_win:
-            warnings.warn(
-                f"worst balanced tile spans {span} rows > thresholds."
-                f"max_win={th.max_win}; sharded plan falls back to the xla "
-                "inner backend", stacklevel=2)
-            HEALTH.bump("demote:max_win_sharded_inner_to_xla")
-            inner_backend = "xla"
     spec = None
     if backend == "sharded":
         if mesh is None:
@@ -1224,12 +1196,17 @@ def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
                     interpret: bool | None = None,
                     mesh: Any = None,
                     shard_axis: str | None = None,
-                    quant: str | None = None) -> jax.Array:
+                    quant: str | None = None,
+                    prep: dict | None = None) -> jax.Array:
     """Differentiable SpMM over a bare BalancedCOO-layout pattern — the
     training entry for sparse-weight layers (no CSR, values are live params).
     rows/cols may be traced (scanned per-layer patterns); they are real args
-    with float0 cotangents, but traced patterns restrict you to backends whose
-    kernels need no host-side prep (the XLA reference backend).
+    with float0 cotangents.  A kernel that needs host-side prep (the Pallas
+    NB visit schedule) cannot run its prep on a traced pattern: pass the
+    pattern's precomputed prep opts as ``prep`` — their array entries ride
+    the call as (possibly traced) kernel operands, the rest bind statically.
+    Without ``prep`` a traced pattern falls back to the XLA reference and
+    bumps the ``demote:traced_pattern_to_xla`` health counter.
 
     ``mesh`` (or ``backend="sharded"``) routes through the sharded backend:
     the pattern's tiles — already fixed-nnz quotas — split evenly across
@@ -1244,11 +1221,6 @@ def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
         if quant not in quant_mod.QUANT_MODES:
             raise ValueError(f"unknown quant mode {quant!r}; expected one of "
                              f"{quant_mod.QUANT_MODES}")
-        if not quant_mod.supports(quant):
-            warnings.warn(f"quant={quant!r} is not supported by this jax "
-                          "build; demoting to 'int8'", stacklevel=2)
-            HEALTH.bump("demote:fp8_to_int8")
-            quant = "int8"
         impl = _quant_logical(impl, quant)
     if mesh is not None or backend == "sharded":
         if mesh is None:
@@ -1261,14 +1233,24 @@ def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
     explicit = backend is not None
     backend = backend or registry.default_backend()
     entry = registry.resolve(impl, backend)
-    if entry.prep is not None and isinstance(rows, jax.core.Tracer) and not explicit:
-        # scanned per-layer patterns are traced; the default backend may need
-        # host-side prep it cannot run on tracers — the XLA reference can
-        # always take them, so fall back rather than fail the train step.
-        backend, entry = "xla", registry.resolve(impl, "xla")
     if entry.substrate != "balanced":
         raise ValueError(f"execute_pattern needs a balanced-substrate kernel; "
                          f"({impl!r}, {backend!r}) consumes {entry.substrate!r}")
+    if prep is not None and entry.prep is not None:
+        from .shard import _make_inner
+        keys = tuple(sorted(k for k, v in prep.items() if hasattr(v, "shape")))
+        statics = {k: v for k, v in prep.items() if k not in keys}
+        if quant is not None:
+            statics["quant"] = quant
+        bound = _make_inner(entry, interpret, statics, keys)
+        return _exec_balanced((bound, tuple(shape)), rows, cols,
+                              vals.reshape(-1), x, *(prep[k] for k in keys))
+    if entry.prep is not None and isinstance(rows, jax.core.Tracer) and not explicit:
+        # scanned per-layer patterns are traced and no prep was handed in;
+        # the XLA reference can always take them, so fall back rather than
+        # fail the train step — visibly, on the health counters.
+        HEALTH.bump("demote:traced_pattern_to_xla")
+        backend, entry = "xla", registry.resolve(impl, "xla")
     if entry.prep is not None:
         if isinstance(rows, jax.core.Tracer):
             raise ValueError(

@@ -36,9 +36,8 @@ import numpy as np
 QUANT_MODES = ("int8", "fp8")
 
 #: fp8 storage dtype — e4m3 (1 sign, 4 exponent, 3 mantissa): the variant
-#: with the range/precision tradeoff tuned for forward values.  ``None``
-#: when this jax build does not ship the dtype; ``supports("fp8")`` gates.
-FP8_DTYPE = getattr(jnp, "float8_e4m3fn", None)
+#: with the range/precision tradeoff tuned for forward values.
+FP8_DTYPE = jnp.float8_e4m3fn
 
 #: symmetric quantization ceiling per mode: int8 clips at +/-127, e4m3's
 #: largest finite value is 448.
@@ -53,23 +52,11 @@ QMAX = {"int8": 127.0, "fp8": 448.0}
 MAX_DYNAMIC_RANGE = 512.0
 
 
-def supports(mode: str) -> bool:
-    """Whether this runtime can store the mode's value stream."""
-    if mode == "int8":
-        return True
-    if mode == "fp8":
-        return FP8_DTYPE is not None
-    return False
-
-
 def quant_dtype(mode: str):
     """The storage dtype for one quant mode (raises on unknown/unsupported)."""
     if mode == "int8":
         return jnp.int8
     if mode == "fp8":
-        if FP8_DTYPE is None:
-            raise ValueError("fp8 substrates need a jax with float8_e4m3fn; "
-                             "use quant='int8'")
         return FP8_DTYPE
     raise ValueError(f"unknown quant mode {mode!r}; expected one of "
                      f"{QUANT_MODES}")
@@ -84,7 +71,7 @@ def is_quantized_dtype(dtype) -> bool:
     dtype = jnp.dtype(dtype)
     if dtype == jnp.dtype(jnp.int8):
         return True
-    return FP8_DTYPE is not None and dtype == jnp.dtype(FP8_DTYPE)
+    return dtype == jnp.dtype(FP8_DTYPE)
 
 
 def value_bytes(dtype) -> int:

@@ -54,8 +54,8 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax import shard_map
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from . import registry
 from .formats import BUILD_COUNTS, CSR, BalancedCOO, row_ids_from_indptr
@@ -276,6 +276,22 @@ def build_sharded_substrate(csr: CSR, spec: ShardSpec, mesh, *,
         shape=tuple(csr.shape), quant=quant)
 
 
+def place_shards(a, mesh, axis: str):
+    """Put a per-shard stack (leading dim = shards) on the mesh, shard ``s``
+    on the devices of position ``s`` along ``axis`` — built host-side, it
+    would otherwise sit on the default device and be scattered again on
+    every call."""
+    return jax.device_put(a, NamedSharding(mesh, P(axis)))
+
+
+def place_substrate(sub: ShardedSubstrate) -> ShardedSubstrate:
+    """``sub`` with every per-shard stack placed on its mesh."""
+    return dataclasses.replace(sub, **{
+        f: place_shards(getattr(sub, f), sub.mesh, sub.spec.axis)
+        for f in ("rows", "cols", "vals", "lens", "src", "scales")
+        if getattr(sub, f) is not None})
+
+
 # ---------------------------------------------------------------------------
 # shard_map kernel wrappers (the "sharded" backend entries)
 # ---------------------------------------------------------------------------
@@ -342,27 +358,28 @@ def stack_visit_schedules(schedules) -> tuple:
     return np.stack(vts), np.stack(vbs), np.stack(vss)
 
 
-def _stack_prep_opts(per_shard: list) -> dict:
+def _stack_prep_opts(per_shard: list, mesh, axis: str) -> dict:
     """Stack per-shard prep-opt dicts into one sharded opts dict.
 
     Tensor opts stack on a leading shard dim (visit schedules pad first);
     the spill ``win`` is the max — the *shared static* the spill parity path
     still needs, and exactly the tax the fused schedules avoid.  Geometry
     statics (``wb``/``tile_n``) must agree across shards (one plan, one
-    geometry)."""
+    geometry).  Tensor opts are placed on the mesh like the substrate."""
     out: dict = {}
     first = per_shard[0]
+    put = lambda a: place_shards(a, mesh, axis)  # noqa: E731
     if "row_base" in first:
-        out["row_base"] = jnp.asarray(
+        out["row_base"] = put(
             np.stack([np.asarray(o["row_base"]) for o in per_shard]))
         out["win"] = max(int(o["win"]) for o in per_shard)
     if "visit_tile" in first:
         vt, vb, vs = stack_visit_schedules(
             [(np.asarray(o["visit_tile"]), np.asarray(o["visit_block"]),
               np.asarray(o["visit_start"])) for o in per_shard])
-        out["visit_tile"] = jnp.asarray(vt)
-        out["visit_block"] = jnp.asarray(vb)
-        out["visit_start"] = jnp.asarray(vs)
+        out["visit_tile"] = put(vt)
+        out["visit_block"] = put(vb)
+        out["visit_start"] = put(vs)
         for k in ("wb", "tile_n"):
             vals = {int(o[k]) for o in per_shard if o.get(k) is not None}
             if len(vals) > 1:
@@ -382,9 +399,11 @@ def _sharded_prep(sub: ShardedSubstrate, *, _logical: str,
     The spill row windows stack alongside as the parity reference (its
     ``win`` is the max over shards; the fused path never pays it)."""
     inner = registry.resolve(_logical, sub.inner_backend)
-    if inner.prep is None:
-        # prep-less inners (XLA reference, Pallas rs_*) still get the
-        # overlap cutoff: the ring wraps the reduction, not the kernel
+    if inner.prep is None or inner.substrate != sub.inner_kind:
+        # prep-less inners (the XLA reference) and inners handed a shard
+        # format other than the one their prep reads (Pallas rs_* on the
+        # row-split ELL shards tiles them in graph) still get the overlap
+        # cutoff: the ring wraps the reduction, not the kernel
         return ({} if overlap_min_n is None
                 else {"overlap_min_n": int(overlap_min_n)})
     from .plan import _prep_context_kwargs
@@ -408,7 +427,7 @@ def _sharded_prep(sub: ShardedSubstrate, *, _logical: str,
                              f"{sorted(opts)} of ({_logical!r}, "
                              f"{sub.inner_backend!r})")
         per_shard.append(opts)
-    stacked = _stack_prep_opts(per_shard)
+    stacked = _stack_prep_opts(per_shard, sub.mesh, sub.spec.axis)
     if overlap_min_n is not None:
         stacked["overlap_min_n"] = int(overlap_min_n)
     return stacked
@@ -539,7 +558,7 @@ def _sharded_exec(sub: ShardedSubstrate, x, *, _logical: str,
         return jax.lax.psum(run(xx), spec.axis)
 
     y = shard_map(local, mesh=sub.mesh, in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)(*ops, x)
+                  out_specs=out_specs, check_vma=False)(*ops, x)
     if spec.reduction == "concat":
         y = y[: sub.shape[0]]  # strip the per-shard row padding
     return y
@@ -603,7 +622,7 @@ def _sddmm_sharded(rows, cols, a, b, *, interpret=None, shape=None,
                         shape=inner_shape)
 
     out = shard_map(local, mesh=mesh, in_specs=in_specs,
-                    out_specs=P(spec.axis), check_rep=False)(*ops)
+                    out_specs=P(spec.axis), check_vma=False)(*ops)
     return out.reshape(rows.shape)
 
 
@@ -705,7 +724,7 @@ def _chain_sharded(rows, cols, a, b, x, *, interpret=None, shape=None,
 
     out_specs = P(spec.axis) if spec.reduction == "concat" else P()
     y = shard_map(local, mesh=mesh, in_specs=tuple(specs),
-                  out_specs=out_specs, check_rep=False)(*ops)
+                  out_specs=out_specs, check_vma=False)(*ops)
     if spec.reduction == "concat":
         y = y[:m]    # strip the per-shard row padding
     return y[:, 0] if x.ndim == 1 else y
@@ -739,13 +758,16 @@ def execute_pattern_sharded(rows, cols, vals, shape, x, *, mesh,
     resolved inner backend has a prep hook (Pallas NB), the per-shard visit
     schedules are built host-side, stacked, and the fused inner kernel runs
     inside shard_map — same hot path as planned sharded execution.  Traced
-    patterns (scanned per-layer) fall back to the prep-free XLA reference."""
+    patterns (scanned per-layer) fall back to the prep-free XLA reference
+    and bump ``demote:traced_pattern_to_xla``."""
     axis = axis or default_shard_axis(mesh)
     n = int(mesh.shape[axis])
     traced = isinstance(rows, jax.core.Tracer)
     backend = backend or registry.default_backend()
     entry = registry.resolve(impl, backend)
     if entry.prep is not None and traced:
+        from .guardrails import HEALTH
+        HEALTH.bump("demote:traced_pattern_to_xla")
         backend, entry = "xla", registry.resolve(impl, "xla")
     if entry.substrate != "balanced":
         raise ValueError(f"execute_pattern_sharded needs a balanced-substrate "
@@ -775,7 +797,7 @@ def execute_pattern_sharded(rows, cols, vals, shape, x, *, mesh,
             per_shard = [dict(entry.prep(BalancedCOO(
                 r_h[s], c_h[s], np.zeros(r_h[s].shape, np.float32),
                 tuple(shape)))) for s in range(n)]
-            opts = _stack_prep_opts(per_shard)
+            opts = _stack_prep_opts(per_shard, mesh, axis)
             _PATTERN_PREP[key] = opts
             while len(_PATTERN_PREP) > _PATTERN_PREP_CAP:
                 _PATTERN_PREP.popitem(last=False)

@@ -30,116 +30,86 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import registry
 from repro.core.formats import BalancedCOO
 from repro.core.selector import TileGeometry
-from repro.core.spmm import SOFTMAX_EPS, SOFTMAX_NEG
-
-from .fused_chain import _pad_2d, _tile_scores
-from .vsr import _pad_n, _prep_windows, plan_visits
+from .fused_chain import (_pad_2d, fold_stats, gather_endpoints,
+                          gather_scratch, row_stats, softmax_weights,
+                          stats_blocks, tile_scores)
+from .vsr import (_pad_n, _prep_windows, accumulate_visit, as_tiles,
+                  column_blocks, hbm_spec, mxu_dot, new_tile, plan_visits,
+                  segment_onehot, start_row_gather, tile_spec,
+                  wait_row_gather)
 
 
 # ---------------------------------------------------------------------------
 # pass 1: online row (max, sum-of-exp) of scale * QK^T + bias over visits
 # ---------------------------------------------------------------------------
 
-def _attn_stats_kernel(vt_ref, vb_ref, vs_ref, rows_ref, cols_ref, q_ref,
-                       k_ref, bias_ref, rm_ref, rs_ref, *, m, wb, scale):
+def _attn_stats_kernel(vt_ref, vb_ref, vs_ref, rows_ref, rows_s, cols_s,
+                       q_hbm, k_hbm, bias_ref, rm_ref, rs_ref, qg, kg, sems,
+                       *, m, wb, scale):
     v = pl.program_id(0)
-    rows = rows_ref[0, :]
-    e, mask0 = _tile_scores(rows, cols_ref[0, :], q_ref, k_ref, m)
-    z = scale * e + bias_ref[0, :].astype(jnp.float32)
-    base = vb_ref[v] * wb
-    local = rows - base
-    mask = mask0 & (local >= 0) & (local < wb)
-    local = jnp.clip(local, 0, wb - 1)
-    t = rows.shape[0]
 
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (wb, t), 0)
-    sel = (local[None, :] == row_iota) & mask[None, :]
-    zt = jnp.where(sel, z[None, :], SOFTMAX_NEG)
-    m_tile = jnp.max(zt, axis=1)                              # (wb,)
-    p_tile = jnp.where(sel, jnp.exp(zt - m_tile[:, None]), 0.0)
-    s_tile = jnp.sum(p_tile, axis=1)
-
-    @pl.when(vs_ref[v] == 1)
+    @pl.when(new_tile(vt_ref, v))
     def _():
-        rm_ref[0, :] = m_tile
-        rs_ref[0, :] = s_tile
+        gather_endpoints(rows_s, cols_s, q_hbm, k_hbm, qg, kg, sems, m)
 
-    @pl.when(vs_ref[v] == 0)
-    def _():
-        m_old = rm_ref[0, :]
-        m_new = jnp.maximum(m_old, m_tile)
-        rm_ref[0, :] = m_new
-        rs_ref[0, :] = (rs_ref[0, :] * jnp.exp(m_old - m_new)
-                        + s_tile * jnp.exp(m_tile - m_new))
+    z = scale * tile_scores(qg, kg) + bias_ref[...].astype(jnp.float32)
+    m_tile, s_tile = row_stats(z, rows_ref[...], vb_ref[v] * wb, wb, m)
+    fold_stats(rm_ref, rs_ref, m_tile, s_tile, vs_ref[v])
 
 
 @functools.partial(jax.jit, static_argnames=("m", "wb", "scale", "interpret"))
 def _attn_stats_call(vt, vb, vs, rows, cols, q, k, bias, *, m, wb, scale,
                      interpret):
     n_tiles, t = rows.shape
-    mq, d = q.shape
-    kk, _ = k.shape
+    d = q.shape[1]
     mb = -(-m // wb)
     n_visits = vt.shape[0]
+    tile = lambda v, vt, *pf: (vt[v], 0, 0)  # noqa: E731
+    stat = pl.BlockSpec((wb, 1), lambda v, vt, vb, *pf: (vb[v], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_visits,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((mq, d), lambda v, *pf: (0, 0)),
-            pl.BlockSpec((kk, d), lambda v, *pf: (0, 0)),
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, wb), lambda v, vt, vb, *pf: (vb[v], 0)),
-            pl.BlockSpec((1, wb), lambda v, vt, vb, *pf: (vb[v], 0)),
-        ],
+        in_specs=[tile_spec(t, tile), tile_spec(t, tile, pltpu.SMEM),
+                  tile_spec(t, tile, pltpu.SMEM),
+                  hbm_spec(),
+                  hbm_spec(),
+                  tile_spec(t, tile)],
+        out_specs=[stat, stat],
+        scratch_shapes=gather_scratch(t, d),
     )
-    return pl.pallas_call(
+    rt = as_tiles(rows)
+    rm, rs = pl.pallas_call(
         functools.partial(_attn_stats_kernel, m=m, wb=wb, scale=scale),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((mb, wb), jnp.float32),
-                   jax.ShapeDtypeStruct((mb, wb), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32)],
         interpret=interpret,
-    )(vt, vb, vs, rows, cols, q, k, bias)
+    )(vt, vb, vs, rt, rt, as_tiles(cols), q, k, as_tiles(bias))
+    return stats_blocks(rm, rs, wb)
 
 
 # ---------------------------------------------------------------------------
 # pass 2: recompute scores, weight in register, accumulate w * V[cols]
 # ---------------------------------------------------------------------------
 
-def _attn_kernel(vt_ref, vb_ref, vs_ref, rows_ref, cols_ref, q_ref, k_ref,
-                 bias_ref, rm_ref, rs_ref, x_ref, o_ref, *, m, wb, scale):
-    v = pl.program_id(1)
-    rows = rows_ref[0, :]
-    cols = cols_ref[0, :]
-    e, mask0 = _tile_scores(rows, cols, q_ref, k_ref, m)
-    z = scale * e + bias_ref[0, :].astype(jnp.float32)
+def _attn_kernel(vt_ref, vb_ref, vs_ref, rows_ref, rows_s, cols_s, q_hbm,
+                 k_hbm, bias_ref, rm_ref, rs_ref, x_hbm, o_ref, qg, kg, xg,
+                 sems, *, m, wb, tile_n, scale):
+    j, v = pl.program_id(0), pl.program_id(1)
+    rows = rows_ref[...]
     base = vb_ref[v] * wb
-    local = rows - base
-    mask = mask0 & (local >= 0) & (local < wb)
-    local = jnp.clip(local, 0, wb - 1)
+
+    @pl.when(new_tile(vt_ref, v))
+    def _():
+        start_row_gather(x_hbm.at[j], cols_s, xg, sems.at[2])
+        gather_endpoints(rows_s, cols_s, q_hbm, k_hbm, qg, kg, sems, m)
+        wait_row_gather(x_hbm.at[j], xg, sems.at[2])
 
     # attention weight in register — the score never leaves VMEM
-    zc = jnp.where(mask, z - jnp.take(rm_ref[0, :], local), SOFTMAX_NEG)
-    w = jnp.exp(zc) / jnp.maximum(jnp.take(rs_ref[0, :], local), SOFTMAX_EPS)
-    w = jnp.where(mask, w, 0.0)
-
-    xg = jnp.take(x_ref[...], cols, axis=0)
-    p = w[:, None] * xg.astype(jnp.float32)
-    t = rows.shape[0]
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (wb, t), 0)
-    onehot = jnp.where((local[None, :] == row_iota) & mask[None, :], 1.0, 0.0)
-    contrib = jnp.dot(onehot, p, preferred_element_type=jnp.float32)
-
-    @pl.when(vs_ref[v] == 1)
-    def _():
-        o_ref[...] = contrib
-
-    @pl.when(vs_ref[v] == 0)
-    def _():
-        o_ref[...] += contrib
+    z = scale * tile_scores(qg, kg) + bias_ref[...].astype(jnp.float32)
+    w = softmax_weights(z, rows, base, rm_ref[...], rs_ref[...], wb, m)
+    s = segment_onehot(rows, w, base, wb, m)
+    accumulate_visit(o_ref, mxu_dot(s, xg[...]), vs_ref[v])
 
 
 @functools.partial(jax.jit, static_argnames=("m", "wb", "tile_n", "scale",
@@ -147,36 +117,37 @@ def _attn_kernel(vt_ref, vb_ref, vs_ref, rows_ref, cols_ref, q_ref, k_ref,
 def _attn_apply_call(vt, vb, vs, rows, cols, q, k, bias, x, rm, rs, *, m, wb,
                      tile_n, scale, interpret):
     n_tiles, t = rows.shape
-    mq, d = q.shape
-    kk, _ = k.shape
+    d = q.shape[1]
     kx, n_pad = x.shape
     nb = n_pad // tile_n
     mb = -(-m // wb)
     n_visits = vt.shape[0]
+    tile = lambda j, v, vt, *pf: (vt[v], 0, 0)  # noqa: E731
+    stat = pl.BlockSpec((wb, 1), lambda j, v, vt, vb, *pf: (vb[v], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         # visits innermost: each output block's visits are consecutive grid
         # steps — the revisited-block accumulation contract
         grid=(nb, n_visits),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((mq, d), lambda j, v, *pf: (0, 0)),
-            pl.BlockSpec((kk, d), lambda j, v, *pf: (0, 0)),
-            pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, wb), lambda j, v, vt, vb, *pf: (vb[v], 0)),
-            pl.BlockSpec((1, wb), lambda j, v, vt, vb, *pf: (vb[v], 0)),
-            pl.BlockSpec((kx, tile_n), lambda j, v, *pf: (0, j)),
-        ],
+        in_specs=[tile_spec(t, tile), tile_spec(t, tile, pltpu.SMEM),
+                  tile_spec(t, tile, pltpu.SMEM),
+                  hbm_spec(),
+                  hbm_spec(),
+                  tile_spec(t, tile), stat, stat,
+                  hbm_spec()],
         out_specs=pl.BlockSpec((wb, tile_n),
                                lambda j, v, vt, vb, *pf: (vb[v], j)),
+        scratch_shapes=gather_scratch(t, d, 1, tile_n),
     )
+    rt = as_tiles(rows)
     out = pl.pallas_call(
-        functools.partial(_attn_kernel, m=m, wb=wb, scale=scale),
+        functools.partial(_attn_kernel, m=m, wb=wb, tile_n=tile_n,
+                          scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
         interpret=interpret,
-    )(vt, vb, vs, rows, cols, q, k, bias, rm, rs, x)
+    )(vt, vb, vs, rt, rt, as_tiles(cols), q, k, as_tiles(bias),
+      rm.reshape(-1, 1), rs.reshape(-1, 1), column_blocks(x, tile_n))
     return out[:m]
 
 

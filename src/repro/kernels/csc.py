@@ -6,103 +6,168 @@ the cached nonzeros *sequentially* while owning a distinct dense column —
 sequential reduction with parallel (coalesced) loading.
 
 TPU adaptation (see DESIGN.md §2):
-  * shared-memory staging → **BlockSpec VMEM staging**: the (TM, TW) slab of
-    ELL cols/vals is DMA'd HBM→VMEM once per grid step (the coalesced load);
-    the ``fori_loop`` below then walks the *cached* slab — data is touched
-    once in HBM, TW times in VMEM.
-  * "each lane owns a dense column" → the lane dimension of the (TM, TN)
-    output block carries TN dense columns; the loop body's gather+FMA is a
-    (TM, TN)-wide VPU op, i.e. all columns advance in lockstep per cached
-    nonzero — exactly the CSC schedule.
-  * row-split: grid axis 0 assigns TM whole rows per step (no cross-row
-    segments → no segment reduction needed; the imbalance cost this leaves
-    on the table is what the adaptive selector weighs against nb_* kernels).
+  * row-split: the grid assigns whole ``wb``-row output blocks; a block's
+    nonzeros are cut into row-aligned tiles (no tile crosses a block), and
+    a heavy block simply takes more consecutive grid steps accumulating into
+    the same output block — the imbalance row-split leaves on the table,
+    which the adaptive selector weighs against the nb_* kernels.
+  * shared-memory staging → the tile's index/value slab is DMA'd HBM→VMEM
+    (SMEM for the column ids) once per grid step, and the dense rows it
+    names are fetched by the same DMA row gather as VSR (``kernels/vsr.py``).
+  * "each lane owns a dense column" → the lane dimension of the ``(wb,
+    tile_n)`` output block carries ``tile_n`` dense columns; the walk over
+    the cached nonzeros is one segment-one-hot matmul, so every column
+    advances in lockstep — the CSC schedule on the MXU.
 
-Accumulation across the W grid axis uses the sequential-TPU-grid revisit
-pattern (init at w==0, add thereafter).
+With row-aligned tiles the fused VSR kernel *is* the row-split kernel: every
+tile belongs to exactly one output block, so its visit schedule is one visit
+per tile.  Two tilings feed it:
+
+  * ``plan_row_tiles`` (the plan-time prep of the registered ``rs_*``
+    entries) cuts the CSR-ordered nonzero stream per block — storage is the
+    nonzeros plus at most one tile of padding per block, whatever the row
+    skew;
+  * ``ell_row_tiles`` reshapes an ELL slab in graph (``spmm_csc``, and the
+    sharded row-split backend's per-shard ELL substrates).  ELL pads every
+    row to the longest one, so it is only for matrices whose longest row is
+    short.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+import numpy as np
 
 from repro.core import registry
-from repro.core.formats import ELL
+from repro.core.formats import ELL, BalancedCOO
+from repro.core.selector import TileGeometry
+
+from .spmv import spmv_vsr_fused
+from .vsr import spmm_vsr_fused
 
 
-def _csc_kernel(cols_ref, vals_ref, x_ref, o_ref, *, tw):
-    w = pl.program_id(2)
+def plan_row_tiles(bal: BalancedCOO, wb: int) -> dict:
+    """Host-side row-split tiling of a row-major nonzero stream.
 
-    @pl.when(w == 0)
-    def _init():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    Cuts the valid entries of ``bal`` (in stream order) per ``wb``-row
+    output block into tiles of ``bal.tile`` slots; every block gets at least
+    one tile so every output block is written.  Returns the tiles' global
+    row ids (sentinel ``m`` on padding) and column ids, ``rt_src`` — each
+    slot's index into the flattened ``bal`` slab (-1 on padding), which is
+    how baked or live values reach the tiles — and the one-visit-per-tile
+    schedule."""
+    rows = np.asarray(bal.rows).reshape(-1)
+    cols = np.asarray(bal.cols).reshape(-1)
+    m = bal.shape[0]
+    t = bal.tile
+    mb = max(1, -(-m // wb))
+    src = np.nonzero(rows < m)[0]
+    blk = rows[src] // wb
+    counts = np.bincount(blk, minlength=mb)
+    per_block = np.maximum(1, -(-counts // t))
+    first_tile = np.concatenate([[0], np.cumsum(per_block)[:-1]])
+    first_entry = np.concatenate([[0], np.cumsum(counts)[:-1]])
+    rank = np.arange(len(src)) - first_entry[blk]
+    slot = (first_tile[blk] + rank // t) * t + rank % t
+    n_tiles = int(per_block.sum())
+    rt_rows = np.full(n_tiles * t, m, np.int32)
+    rt_cols = np.zeros(n_tiles * t, np.int32)
+    rt_src = np.full(n_tiles * t, -1, np.int32)
+    rt_rows[slot] = rows[src]
+    rt_cols[slot] = cols[src]
+    rt_src[slot] = src
+    vb = np.repeat(np.arange(mb, dtype=np.int32), per_block)
+    vs = np.zeros(n_tiles, np.int32)
+    vs[first_tile] = 1
+    shp = (n_tiles, t)
+    return {"rt_rows": jnp.asarray(rt_rows.reshape(shp)),
+            "rt_cols": jnp.asarray(rt_cols.reshape(shp)),
+            "rt_src": jnp.asarray(rt_src.reshape(shp)),
+            "visit_tile": jnp.arange(n_tiles, dtype=jnp.int32),
+            "visit_block": jnp.asarray(vb), "visit_start": jnp.asarray(vs)}
 
-    cols = cols_ref[...]            # (TM, TW) — the VMEM-cached slab
-    vals = vals_ref[...]
-    x = x_ref[...]                  # (K, TN)
 
-    def body(j, acc):
-        # sequential walk over the cached slab (the SR inner loop)
-        c_j = jax.lax.dynamic_index_in_dim(cols, j, axis=1, keepdims=False)
-        v_j = jax.lax.dynamic_index_in_dim(vals, j, axis=1, keepdims=False)
-        xg = jnp.take(x, c_j, axis=0)                      # (TM, TN)
-        return acc + v_j[:, None].astype(jnp.float32) * xg.astype(jnp.float32)
+def ell_row_tiles(ell: ELL, tm: int, tw: int):
+    """An ELL slab as row-aligned tiles, in graph: ``tm``-row blocks, each
+    cut into ``tw``-wide column chunks (tile = one ``(tm, tw)`` chunk, so
+    ``T = tm * tw``).  Returns the tiled ``BalancedCOO`` and its regular
+    visit schedule (chunk ``c`` of block ``b`` is visit ``b * nw + c``)."""
+    m, w = ell.cols.shape
+    m_pad, w_pad = -(-m // tm) * tm, -(-w // tw) * tw
+    mb, nw = m_pad // tm, w_pad // tw
+    pad = ((0, m_pad - m), (0, w_pad - w))
+    row_ids = jnp.arange(m_pad, dtype=jnp.int32)
+    rows = jnp.broadcast_to(jnp.where(row_ids < m, row_ids, m)[:, None],
+                            (m_pad, w_pad))
 
-    acc = jax.lax.fori_loop(0, tw, body,
-                            jnp.zeros(o_ref.shape, jnp.float32))
-    o_ref[...] += acc
+    def tiles(a):
+        return a.reshape(mb, tm, nw, tw).transpose(0, 2, 1, 3).reshape(
+            mb * nw, tm * tw)
+
+    bal = BalancedCOO(tiles(rows), tiles(jnp.pad(ell.cols, pad)),
+                      tiles(jnp.pad(ell.vals, pad)), ell.shape)
+    vt = jnp.arange(mb * nw, dtype=jnp.int32)
+    return bal, (vt, vt // nw, (vt % nw == 0).astype(jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("tm", "tw", "tile_n", "interpret"))
-def _csc_call(cols, vals, x, *, tm, tw, tile_n, interpret):
-    m_pad, w_pad = cols.shape
-    k, n_pad = x.shape
-    grid = (m_pad // tm, n_pad // tile_n, w_pad // tw)
-    return pl.pallas_call(
-        functools.partial(_csc_kernel, tw=tw),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((tm, tw), lambda i, j, w: (i, w)),
-            pl.BlockSpec((tm, tw), lambda i, j, w: (i, w)),
-            pl.BlockSpec((k, tile_n), lambda i, j, w: (0, j)),
-        ],
-        out_specs=pl.BlockSpec((tm, tile_n), lambda i, j, w: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), jnp.float32),
-        interpret=interpret,
-    )(cols, vals, x)
+def _run_tiles(bal: BalancedCOO, x, schedule, *, wb, tile_n, interpret):
+    vt, vb, vs = schedule
+    if x.ndim == 1:
+        return spmv_vsr_fused(bal, x, interpret=interpret, wb=wb,
+                              visit_tile=vt, visit_block=vb, visit_start=vs)
+    return spmm_vsr_fused(bal, x, interpret=interpret, wb=wb, tile_n=tile_n,
+                          visit_tile=vt, visit_block=vb, visit_start=vs)
 
 
 def spmm_csc(ell: ELL, x: jax.Array, *, tm: int = 8, tw: int = 128,
              tile_n: int = 128, interpret: bool | None = None) -> jax.Array:
-    """RS+SR SpMM on the ELL substrate. Pads (M→tm, W→tw, N→tile_n)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    x2 = x[:, None] if x.ndim == 1 else x
-    m, _ = ell.shape
-    k, n = x2.shape
-    w = ell.width
-    tw = min(tw, -(-w // 8) * 8)
-    m_pad, w_pad, n_pad = (-(-m // tm) * tm, -(-w // tw) * tw, -(-n // tile_n) * tile_n)
-    cols = jnp.pad(ell.cols, ((0, m_pad - m), (0, w_pad - w)))
-    vals = jnp.pad(ell.vals, ((0, m_pad - m), (0, w_pad - w)))
-    xp = jnp.pad(x2, ((0, 0), (0, n_pad - n))) if n_pad != n else x2
-    y = _csc_call(cols, vals, xp, tm=tm, tw=tw, tile_n=tile_n, interpret=interpret)
-    y = y[:m, :n].astype(x2.dtype)
-    return y[:, 0] if x.ndim == 1 else y
+    """RS+SR SpMM on the ELL substrate. ``tm`` is the output block height
+    (a multiple of 8); the ELL width is walked in ``tw``-wide chunks,
+    rounded so a tile (``tm * tw`` slots) fills whole 128-lane vregs."""
+    lane_rows = 128 // np.gcd(tm, 128)
+    tw = -(-min(tw, ell.width) // lane_rows) * lane_rows
+    bal, schedule = ell_row_tiles(ell, tm, tw)
+    return _run_tiles(bal, x, schedule, wb=tm, tile_n=tile_n,
+                      interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
 # registry: the Pallas physical kernel for the row-split logical pair.  The
-# VPU is always parallel across lanes and the W grid axis always sequential,
-# so rs_sr and rs_pr collapse onto the same binary on TPU (DESIGN.md §2).
+# segment reduction is one MXU matmul per tile either way, so rs_sr and
+# rs_pr collapse onto the same binary on TPU (DESIGN.md §2).  The entries
+# read the balanced substrate (the CSR-ordered stream) and re-tile it per
+# output block at plan time; handed an ELL (the sharded row-split backend's
+# per-shard substrate) they tile it in graph.
 # ---------------------------------------------------------------------------
 
-def _pallas_rs(ell: ELL, x, *, interpret: bool | None = None):
-    return spmm_csc(ell, x, interpret=interpret)
+def _prep_row_tiles(bal: BalancedCOO, *,
+                    geometry: TileGeometry | None = None) -> dict:
+    geom = (geometry or TileGeometry()).validate()
+    return {**plan_row_tiles(bal, geom.wb), "wb": geom.wb,
+            "tile_n": geom.tile_n}
 
 
-registry.register("rs_sr", "pallas", "ell", _pallas_rs)
-registry.register("rs_pr", "pallas", "ell", _pallas_rs)
+def _pallas_rs(sub, x, scales=None, *, interpret: bool | None = None,
+               rt_rows=None, rt_cols=None, rt_src=None, visit_tile=None,
+               visit_block=None, visit_start=None, wb: int | None = None,
+               tile_n: int | None = None, quant: str | None = None):
+    if isinstance(sub, ELL):
+        return spmm_csc(sub, x, interpret=interpret)
+    if rt_src is None:
+        opts = _prep_row_tiles(sub)
+        return _pallas_rs(sub, x, scales, interpret=interpret, **opts)
+    vals = sub.vals
+    if scales is not None:      # baked int8/fp8 stream: decode per tile
+        vals = vals.astype(jnp.float32) * scales[:, None]
+    flat = vals.reshape(-1)
+    rt_vals = jnp.where(rt_src >= 0, jnp.take(flat, jnp.maximum(rt_src, 0)),
+                        0).astype(flat.dtype)
+    tiles = BalancedCOO(rt_rows, rt_cols, rt_vals, sub.shape)
+    return _run_tiles(tiles, x, (visit_tile, visit_block, visit_start),
+                      wb=wb, tile_n=tile_n, interpret=interpret)
+
+
+registry.register("rs_sr", "pallas", "balanced", _pallas_rs,
+                  prep=_prep_row_tiles)
+registry.register("rs_pr", "pallas", "balanced", _pallas_rs,
+                  prep=_prep_row_tiles)

@@ -10,18 +10,20 @@ the SDDMM, ``nnz`` read back by the SpMM — pure traffic, no flops
 
 The fused kernel eliminates it.  The observation making fusion natural here is
 that the SDDMM's *output* pattern is the SpMM's *input* pattern, so one
-``plan_visits`` schedule (kernels/vsr.py) drives both: each visit gathers the
-endpoint feature rows, computes its tile's scores on the spot, applies the
-transform, and accumulates ``w * X[cols]`` into the revisited ``(wb, tile_n)``
-output block — scores live only in VMEM registers.  The trade is FusedMM's:
-scores are recomputed once per column block (``nb`` times), swapping ``2*nnz``
-value bytes of HBM for gather/dot recompute out of feature rows that are in
-VMEM anyway.
+``plan_visits`` schedule (kernels/vsr.py) drives both: each tile DMA-gathers
+its endpoint feature rows and the X rows it aggregates (``A``, ``B`` and
+``X`` stay in HBM — the VDL row gather of kernels/vsr.py), computes its
+scores on the spot, applies the transform, and accumulates ``w * X[cols]``
+into the revisited ``(wb, tile_n)`` output block — scores live only in VMEM.
+The trade is FusedMM's: scores are recomputed once per column block (``nb``
+times), swapping ``2*nnz`` value bytes of HBM for endpoint-row gathers and
+dot recompute.
 
 Masked softmax needs row totals before any weight can be formed, so it runs
 two passes over the same schedule (same shape as the PR 4 spill-fused
 accumulation): pass 1 folds each visit's per-row ``(max, sum-of-exp)`` into
-``(mb, wb)`` stat blocks with the online-softmax update, pass 2 reads the
+``(wb, 1)`` stat columns with the online-softmax update (exchanged as
+``(mb, wb)`` blocks), pass 2 reads the
 finished stats alongside each visit.  Stats are ``2 * m`` floats of traffic —
 independent of nnz — vs. the ``2 * nnz`` the unfused pair moves.  Empty rows
 keep ``(SOFTMAX_NEG, 0)`` and produce all-zero weights; the ``-1e30`` sentinel
@@ -45,16 +47,20 @@ from repro.core.formats import BalancedCOO
 from repro.core.selector import TileGeometry
 from repro.core.spmm import SOFTMAX_EPS, SOFTMAX_NEG
 
-from .vsr import _pad_n, _prep_windows, plan_visits
+from .vsr import (_pad_n, _prep_windows, accumulate_visit, as_tiles,
+                  block_hits, column_blocks, hbm_spec, mxu_dot, new_tile,
+                  plan_visits, segment_onehot, start_row_gather, tile_spec,
+                  wait_row_gather)
 
 #: per-row transforms the chain supports between its SDDMM and SpMM halves
 CHAIN_TRANSFORMS: tuple[str, ...] = ("identity", "scale", "softmax")
 
 
 def _pad_2d(a: jax.Array, row_mult: int = 8, col_mult: int = 128) -> jax.Array:
-    """Pad a feature matrix to sublane/lane multiples.  Row padding is inert
-    (gather indices stay below the true row count) and zero column padding
-    adds nothing to the score dot products."""
+    """A feature matrix as the f32, sublane/lane-padded operand the row
+    gathers read.  Row padding is inert (gather indices stay below the true
+    row count) and zero column padding adds nothing to the score dots."""
+    a = a.astype(jnp.float32)
     r, c = a.shape
     rp = -(-r // row_mult) * row_mult
     cp = -(-c // col_mult) * col_mult
@@ -63,44 +69,114 @@ def _pad_2d(a: jax.Array, row_mult: int = 8, col_mult: int = 128) -> jax.Array:
     return a
 
 
-def _tile_scores(rows, cols, a_ref, b_ref, m):
-    """In-kernel SDDMM for one nnz-tile: gather both endpoint feature rows
-    (the VDL idiom — one gather per side covers the whole feature dim) and
-    dot them.  Returns masked f32 scores and the validity mask."""
-    mask = rows < m
-    ag = jnp.take(a_ref[...], jnp.where(mask, rows, 0), axis=0)
-    bg = jnp.take(b_ref[...], cols, axis=0)
-    e = jnp.sum(ag.astype(jnp.float32) * bg.astype(jnp.float32), axis=-1)
-    return jnp.where(mask, e, 0.0), mask
+def gather_endpoints(rows_s, cols_s, a_hbm, b_hbm, ag, bg, sems, m):
+    """DMA-gather a tile's endpoint feature rows — ``A[rows]`` and
+    ``B[cols]`` (the VDL idiom: one row copy per side covers the whole
+    feature dim; ``A``/``B`` stay in HBM).  ``rows_s``/``cols_s`` are the
+    tile's SMEM index blocks; sentinel rows read row ``m - 1`` (masked
+    downstream)."""
+    start_row_gather(a_hbm, rows_s, ag, sems.at[0], clamp=m - 1)
+    start_row_gather(b_hbm, cols_s, bg, sems.at[1])
+    wait_row_gather(a_hbm, ag, sems.at[0])
+    wait_row_gather(b_hbm, bg, sems.at[1])
+
+
+def tile_scores(ag, bg):
+    """In-kernel SDDMM for one nnz-tile: the row-wise dots of the gathered
+    endpoint rows, as lane-major ``(1, T)`` f32 scores (an NT matmul
+    against ones — the row sums land lane-major, no relayout)."""
+    prod = ag[...] * bg[...]                                  # (T, d)
+    ones = jnp.ones((8, prod.shape[1]), jnp.float32)
+    e = jax.lax.dot_general(ones, prod, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                            precision=jax.lax.Precision.HIGHEST)
+    return e[0:1]
+
+
+def gather_scratch(t: int, d: int, n_extra: int = 0, tile_n: int = 0):
+    """Scratch of the chain-family kernels: the gathered A and B rows,
+    optionally the gathered X rows, and one DMA semaphore per gather."""
+    shapes = [pltpu.VMEM((t, d), jnp.float32), pltpu.VMEM((t, d), jnp.float32)]
+    if n_extra:
+        shapes.append(pltpu.VMEM((t, tile_n), jnp.float32))
+    return shapes + [pltpu.SemaphoreType.DMA((2 + n_extra,))]
+
+
+def row_stats(z, rows, base, wb: int, m: int):
+    """Per-visit softmax statistics of a tile's scores over the output rows
+    ``[base, base + wb)``: ``(max, sum-of-exp)`` as ``(wb, 1)`` columns;
+    rows the tile does not touch get the identity ``(NEG, 0)``."""
+    sel = block_hits(rows, base, wb, m)
+    zt = jnp.where(sel, z, SOFTMAX_NEG)
+    m_tile = jnp.max(zt, axis=1, keepdims=True)
+    s_tile = jnp.sum(jnp.where(sel, jnp.exp(zt - m_tile), 0.0), axis=1,
+                     keepdims=True)
+    return m_tile, s_tile
+
+
+def fold_stats(rm_ref, rs_ref, m_tile, s_tile, start):
+    """Online-softmax fold across a block's consecutive visits; padding
+    visits (``start == 2``) take neither branch."""
+
+    @pl.when(start == 1)
+    def _():
+        rm_ref[...] = m_tile
+        rs_ref[...] = s_tile
+
+    @pl.when(start == 0)
+    def _():
+        m_old = rm_ref[...]
+        m_new = jnp.maximum(m_old, m_tile)
+        rm_ref[...] = m_new
+        rs_ref[...] = (rs_ref[...] * jnp.exp(m_old - m_new)
+                       + s_tile * jnp.exp(m_tile - m_new))
+
+
+def softmax_weights(z, rows, base, rm, rs, wb: int, m: int):
+    """Per-edge softmax weights from finished ``(wb, 1)`` row statistics:
+    each edge reads its row's (max, sum) through the block one-hot (a
+    sublane reduction, lane-major result)."""
+    sel = block_hits(rows, base, wb, m)
+    rm_t = jnp.max(jnp.where(sel, rm, SOFTMAX_NEG), axis=0, keepdims=True)
+    rs_t = jnp.sum(jnp.where(sel, rs, 0.0), axis=0, keepdims=True)
+    valid = jnp.any(sel, axis=0, keepdims=True)
+    zc = jnp.where(valid, z - rm_t, SOFTMAX_NEG)
+    return jnp.exp(zc) / jnp.maximum(rs_t, SOFTMAX_EPS)
+
+
+def stats_blocks(rm, rs, wb: int):
+    """``(mb * wb, 1)`` kernel stat columns ↔ the ``(mb, wb)`` blocks the
+    public stats API (and the sharded cross-shard merge) exchanges."""
+    return rm.reshape(-1, wb), rs.reshape(-1, wb)
 
 
 # ---------------------------------------------------------------------------
 # standalone SDDMM: one grid step per nnz-tile, scores written tile-in-place
 # ---------------------------------------------------------------------------
 
-def _sddmm_kernel(rows_ref, cols_ref, a_ref, b_ref, o_ref, *, m):
-    e, _ = _tile_scores(rows_ref[0, :], cols_ref[0, :], a_ref, b_ref, m)
-    o_ref[0, :] = e
+def _sddmm_kernel(rows_s, cols_s, a_hbm, b_hbm, o_ref, ag, bg, sems, *, m):
+    gather_endpoints(rows_s, cols_s, a_hbm, b_hbm, ag, bg, sems, m)
+    o_ref[...] = tile_scores(ag, bg)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "interpret"))
 def _sddmm_call(rows, cols, a, b, *, m, interpret):
     n_tiles, t = rows.shape
-    ma, d = a.shape
-    kb, _ = b.shape
-    return pl.pallas_call(
+    d = a.shape[1]
+    tile = lambda i: (i, 0, 0)  # noqa: E731
+    out = pl.pallas_call(
         functools.partial(_sddmm_kernel, m=m),
         grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((1, t), lambda i: (i, 0)),
-            pl.BlockSpec((ma, d), lambda i: (0, 0)),
-            pl.BlockSpec((kb, d), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, t), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, t), jnp.float32),
+        in_specs=[tile_spec(t, tile, pltpu.SMEM),
+                  tile_spec(t, tile, pltpu.SMEM),
+                  hbm_spec(),
+                  hbm_spec()],
+        out_specs=tile_spec(t, tile),
+        out_shape=jax.ShapeDtypeStruct((n_tiles, 1, t), jnp.float32),
+        scratch_shapes=gather_scratch(t, d),
         interpret=interpret,
-    )(rows, cols, a, b)
+    )(as_tiles(rows), as_tiles(cols), a, b)
+    return jnp.where(rows < m, out.reshape(n_tiles, t), 0.0)
 
 
 def sddmm_pallas(rows, cols, a, b, *, interpret: bool | None = None,
@@ -120,92 +196,75 @@ def sddmm_pallas(rows, cols, a, b, *, interpret: bool | None = None,
 # fused chain pass 1 (softmax only): online row (max, sum-of-exp) over visits
 # ---------------------------------------------------------------------------
 
-def _chain_stats_kernel(vt_ref, vb_ref, vs_ref, rows_ref, cols_ref, a_ref,
-                        b_ref, rm_ref, rs_ref, *, m, wb, alpha):
+def _chain_stats_kernel(vt_ref, vb_ref, vs_ref, rows_ref, rows_s, cols_s,
+                        a_hbm, b_hbm, rm_ref, rs_ref, ag, bg, sems, *, m, wb,
+                        alpha):
     v = pl.program_id(0)
-    rows = rows_ref[0, :]
-    e, mask0 = _tile_scores(rows, cols_ref[0, :], a_ref, b_ref, m)
-    z = alpha * e
-    base = vb_ref[v] * wb
-    local = rows - base
-    mask = mask0 & (local >= 0) & (local < wb)
-    local = jnp.clip(local, 0, wb - 1)
-    t = rows.shape[0]
 
-    # per-visit row stats: scatter the tile's scores onto the block's rows
-    # (same one-hot select as the SpMM reduction) and reduce along the tile
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (wb, t), 0)
-    sel = (local[None, :] == row_iota) & mask[None, :]
-    zt = jnp.where(sel, z[None, :], SOFTMAX_NEG)
-    m_tile = jnp.max(zt, axis=1)                              # (wb,)
-    p_tile = jnp.where(sel, jnp.exp(zt - m_tile[:, None]), 0.0)
-    s_tile = jnp.sum(p_tile, axis=1)
-
-    # online-softmax fold across a block's consecutive visits; rows the visit
-    # does not touch combine as the identity (NEG, 0).  Padding visits
-    # (vs == 2, stacked sharded schedules) take neither branch.
-    @pl.when(vs_ref[v] == 1)
+    @pl.when(new_tile(vt_ref, v))
     def _():
-        rm_ref[0, :] = m_tile
-        rs_ref[0, :] = s_tile
+        gather_endpoints(rows_s, cols_s, a_hbm, b_hbm, ag, bg, sems, m)
 
-    @pl.when(vs_ref[v] == 0)
-    def _():
-        m_old = rm_ref[0, :]
-        m_new = jnp.maximum(m_old, m_tile)
-        rm_ref[0, :] = m_new
-        rs_ref[0, :] = (rs_ref[0, :] * jnp.exp(m_old - m_new)
-                        + s_tile * jnp.exp(m_tile - m_new))
+    z = alpha * tile_scores(ag, bg)
+    m_tile, s_tile = row_stats(z, rows_ref[...], vb_ref[v] * wb, wb, m)
+    fold_stats(rm_ref, rs_ref, m_tile, s_tile, vs_ref[v])
 
 
 @functools.partial(jax.jit, static_argnames=("m", "wb", "alpha", "interpret"))
 def _chain_stats_call(vt, vb, vs, rows, cols, a, b, *, m, wb, alpha,
                       interpret):
     n_tiles, t = rows.shape
-    ma, d = a.shape
-    kb, _ = b.shape
+    d = a.shape[1]
     mb = -(-m // wb)
     n_visits = vt.shape[0]
+    tile = lambda v, vt, *pf: (vt[v], 0, 0)  # noqa: E731
+    stat = pl.BlockSpec((wb, 1), lambda v, vt, vb, *pf: (vb[v], 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_visits,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((ma, d), lambda v, *pf: (0, 0)),
-            pl.BlockSpec((kb, d), lambda v, *pf: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, wb), lambda v, vt, vb, *pf: (vb[v], 0)),
-            pl.BlockSpec((1, wb), lambda v, vt, vb, *pf: (vb[v], 0)),
-        ],
+        in_specs=[tile_spec(t, tile), tile_spec(t, tile, pltpu.SMEM),
+                  tile_spec(t, tile, pltpu.SMEM),
+                  hbm_spec(),
+                  hbm_spec()],
+        out_specs=[stat, stat],
+        scratch_shapes=gather_scratch(t, d),
     )
-    return pl.pallas_call(
+    rt, ct = as_tiles(rows), as_tiles(cols)
+    rm, rs = pl.pallas_call(
         functools.partial(_chain_stats_kernel, m=m, wb=wb, alpha=alpha),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((mb, wb), jnp.float32),
-                   jax.ShapeDtypeStruct((mb, wb), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32)],
         interpret=interpret,
-    )(vt, vb, vs, rows, cols, a, b)
+    )(vt, vb, vs, rt, rt, ct, a, b)
+    return stats_blocks(rm, rs, wb)
 
 
 # ---------------------------------------------------------------------------
 # fused chain pass 2: recompute scores, transform, accumulate w * X[cols]
 # ---------------------------------------------------------------------------
 
-def _chain_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, transform, alpha):
+def _chain_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, tile_n, transform,
+                  alpha):
     if transform == "softmax":
-        rows_ref, cols_ref, a_ref, b_ref, rm_ref, rs_ref, x_ref, o_ref = refs
+        (rows_ref, rows_s, cols_s, a_hbm, b_hbm, rm_ref, rs_ref, x_hbm, o_ref,
+         ag, bg, xg, sems) = refs
     else:
-        rows_ref, cols_ref, a_ref, b_ref, x_ref, o_ref = refs
-    v = pl.program_id(1)
-    rows = rows_ref[0, :]
-    cols = cols_ref[0, :]
-    e, mask0 = _tile_scores(rows, cols, a_ref, b_ref, m)
+        (rows_ref, rows_s, cols_s, a_hbm, b_hbm, x_hbm, o_ref,
+         ag, bg, xg, sems) = refs
+    j, v = pl.program_id(0), pl.program_id(1)
+    rows = rows_ref[...]
     base = vb_ref[v] * wb
-    local = rows - base
-    mask = mask0 & (local >= 0) & (local < wb)
-    local = jnp.clip(local, 0, wb - 1)
+
+    # a new tile fetches its endpoint rows and, for this column block, the
+    # X rows its column ids name (VDL) — all three gathers in flight at once
+    @pl.when(new_tile(vt_ref, v))
+    def _():
+        start_row_gather(x_hbm.at[j], cols_s, xg, sems.at[2])
+        gather_endpoints(rows_s, cols_s, a_hbm, b_hbm, ag, bg, sems, m)
+        wait_row_gather(x_hbm.at[j], xg, sems.at[2])
+
+    e = tile_scores(ag, bg)
 
     # per-row transform, in register — the edge weight never leaves VMEM
     if transform == "identity":
@@ -213,27 +272,12 @@ def _chain_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, transform, alpha):
     elif transform == "scale":
         w = alpha * e
     else:
-        z = alpha * e
-        zc = jnp.where(mask, z - jnp.take(rm_ref[0, :], local), SOFTMAX_NEG)
-        w = jnp.exp(zc) / jnp.maximum(jnp.take(rs_ref[0, :], local),
-                                      SOFTMAX_EPS)
-    w = jnp.where(mask, w, 0.0)
+        w = softmax_weights(alpha * e, rows, base, rm_ref[...], rs_ref[...],
+                            wb, m)
 
-    # SpMM half: VDL gather of X rows, one-hot segment matmul on the MXU
-    xg = jnp.take(x_ref[...], cols, axis=0)
-    p = w[:, None] * xg.astype(jnp.float32)
-    t = rows.shape[0]
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (wb, t), 0)
-    onehot = jnp.where((local[None, :] == row_iota) & mask[None, :], 1.0, 0.0)
-    contrib = jnp.dot(onehot, p, preferred_element_type=jnp.float32)
-
-    @pl.when(vs_ref[v] == 1)
-    def _():
-        o_ref[...] = contrib
-
-    @pl.when(vs_ref[v] == 0)
-    def _():
-        o_ref[...] += contrib
+    # SpMM half: the weighted one-hot segment matmul on the MXU
+    s = segment_onehot(rows, w, base, wb, m)
+    accumulate_visit(o_ref, mxu_dot(s, xg[...]), vs_ref[v])
 
 
 @functools.partial(jax.jit, static_argnames=("m", "wb", "tile_n", "transform",
@@ -241,27 +285,24 @@ def _chain_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, transform, alpha):
 def _chain_apply_call(vt, vb, vs, rows, cols, a, b, x, rm, rs, *, m, wb,
                       tile_n, transform, alpha, interpret):
     n_tiles, t = rows.shape
-    ma, d = a.shape
-    kb, _ = b.shape
+    d = a.shape[1]
     k, n_pad = x.shape
     nb = n_pad // tile_n
     mb = -(-m // wb)
     n_visits = vt.shape[0]
-    in_specs = [
-        pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-        pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-        pl.BlockSpec((ma, d), lambda j, v, *pf: (0, 0)),
-        pl.BlockSpec((kb, d), lambda j, v, *pf: (0, 0)),
-    ]
-    ops = [rows, cols, a, b]
+    tile = lambda j, v, vt, *pf: (vt[v], 0, 0)  # noqa: E731
+    rt, ct = as_tiles(rows), as_tiles(cols)
+    in_specs = [tile_spec(t, tile), tile_spec(t, tile, pltpu.SMEM),
+                tile_spec(t, tile, pltpu.SMEM),
+                hbm_spec(),
+                hbm_spec()]
+    ops = [rt, rt, ct, a, b]
     if transform == "softmax":
-        in_specs += [
-            pl.BlockSpec((1, wb), lambda j, v, vt, vb, *pf: (vb[v], 0)),
-            pl.BlockSpec((1, wb), lambda j, v, vt, vb, *pf: (vb[v], 0)),
-        ]
-        ops += [rm, rs]
-    in_specs.append(pl.BlockSpec((k, tile_n), lambda j, v, *pf: (0, j)))
-    ops.append(x)
+        stat = pl.BlockSpec((wb, 1), lambda j, v, vt, vb, *pf: (vb[v], 0))
+        in_specs += [stat, stat]
+        ops += [rm.reshape(-1, 1), rs.reshape(-1, 1)]
+    in_specs.append(hbm_spec())
+    ops.append(column_blocks(x, tile_n))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         # visits iterate innermost so each output block's visits stay
@@ -270,10 +311,11 @@ def _chain_apply_call(vt, vb, vs, rows, cols, a, b, x, rm, rs, *, m, wb,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((wb, tile_n),
                                lambda j, v, vt, vb, *pf: (vb[v], j)),
+        scratch_shapes=gather_scratch(t, d, 1, tile_n),
     )
     out = pl.pallas_call(
-        functools.partial(_chain_kernel, m=m, wb=wb, transform=transform,
-                          alpha=alpha),
+        functools.partial(_chain_kernel, m=m, wb=wb, tile_n=tile_n,
+                          transform=transform, alpha=alpha),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
         interpret=interpret,

@@ -3,20 +3,22 @@
 For N=1 the one-hot MXU matmul of ``vsr.py`` would light up 1/128 of the
 systolic array (paper Insight 1 in reverse), so SpMV keeps the *literal* VSR
 algorithm: a log-depth prefix network whose combine rule is "add if row ids
-match" (paper Fig. 2(e)), realized with lane shifts (``jnp.roll``) — the TPU
-analogue of ``__shfl_up_sync`` — followed by a segment-head dump.
+match" (paper Fig. 2(e)), realized with lane rotates (``pltpu.roll``) — the
+TPU analogue of ``__shfl_up_sync`` — followed by a segment-head dump.
 
-Per tile of T nonzeros:
-  1. p = vals * x[cols]                      (VDL-style vector gather)
+Per tile of T nonzeros (lane-major ``(1, T)`` vectors):
+  1. p = vals * x[cols]                      (x[cols] gathered by XLA: at
+     N=1 a dense row is one scalar, the same bytes as the column ids)
   2. log2(T) shift-and-add-if-same-row steps → p[i] = inclusive segment sum
   3. segment *ends* (next row differs) dump their sum into the tile's
-     (WIN,) output window; cross-tile rows merge in the spill combine.
+     (WIN, 1) output window; cross-tile rows merge in the spill combine.
 
 Like the SpMM family (``kernels/vsr.py``), the SpMV comes in two boundary
 resolutions: the spill-and-combine reference above, and the **fused**
 default (``spmv_vsr_fused``) that walks the same host-side visit schedule
-and accumulates segment-head dumps directly into revisited ``(wb,)`` output
-blocks — no ``(n_tiles, WIN)`` partials, no post-kernel ``segment_sum``.
+and accumulates segment-head dumps directly into revisited ``(wb, 1)``
+output blocks — no ``(n_tiles, WIN)`` partials, no post-kernel
+``segment_sum``.
 """
 from __future__ import annotations
 
@@ -29,79 +31,96 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BalancedCOO
 from repro.core.selector import TileGeometry
-from .vsr import plan_visits, plan_windows
+from .vsr import (accumulate_visit, as_tiles, plan_visits, plan_windows,
+                  segment_onehot, tile_spec)
 
 
-def _spmv_kernel(rows_ref, cols_ref, vals_ref, base_ref, *refs, m, win, quant):
-    # quantized streams: per-tile scale as a (1,)-block tensor input next to
-    # row_base; dequant in register (see kernels/vsr.py, DESIGN.md §8)
-    if quant:
-        sc_ref, x_ref, o_ref = refs
-    else:
-        x_ref, o_ref = refs
-    rows = rows_ref[0, :]
-    cols = cols_ref[0, :]
-    vals = vals_ref[0, :].astype(jnp.float32)
-    if quant:
-        vals = vals * sc_ref[0]
-    base = base_ref[0]
-    t = rows.shape[0]
-    mask = rows < m
-    local = jnp.clip(rows - base, 0, win - 1)
+def _shift_prev(a, d, idx):
+    """``a[t - d]`` at lane ``t`` (wrapping) — the ``__shfl_up_sync`` of the
+    prefix network, as a lane rotate.  The rotate direction is read off the
+    rotated lane iota, so the network does not depend on which way the
+    hardware rotate turns."""
+    t = a.shape[-1]
+    up = pltpu.roll(a, d, 1)
+    down = pltpu.roll(a, t - d, 1)
+    ok = pltpu.roll(idx, d, 1) == (idx - d) % t
+    return jnp.where(ok, up, down)
 
-    p = vals * jnp.take(x_ref[...], cols)                              # (T,)
-    p = jnp.where(mask, p, 0.0)
 
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)[0]
-    # --- the shuffle prefix network: add-if-row-matches, log2(T) rounds ---
+def _segment_scan(p, rows):
+    """Inclusive per-row prefix sums of a ``(1, T)`` tile: log2(T) rounds of
+    shift-and-add-if-same-row (paper Fig. 2(e)).  Returns the scanned
+    values and the segment-*end* mask (last nonzero of each row run)."""
+    t = p.shape[-1]
+    idx = jax.lax.broadcasted_iota(jnp.int32, p.shape, 1)
     d = 1
     while d < t:
-        p_prev = jnp.roll(p, d)
-        r_prev = jnp.roll(rows, d)
-        take = (idx >= d) & (r_prev == rows)
-        p = p + jnp.where(take, p_prev, 0.0)
+        take = (idx >= d) & (_shift_prev(rows, d, idx) == rows)
+        p = p + jnp.where(take, _shift_prev(p, d, idx), 0.0)
         d *= 2
-    # --- segment-head dump: last element of each row-run holds the sum ---
-    r_next = jnp.roll(rows, -1)
+    r_next = _shift_prev(rows, t - 1, idx)     # rows[t + 1]
     is_end = (idx == t - 1) | (r_next != rows)
-    contrib = jnp.where(is_end & mask, p, 0.0)
+    return p, is_end
 
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (win, t), 0)
-    sel = (local[None, :] == row_iota) & (is_end & mask)[None, :]
-    o_ref[0, :] = jnp.sum(jnp.where(sel, contrib[None, :], 0.0), axis=1)
+
+def _head_dump(p, rows, is_end, base, w: int, m: int):
+    """Segment heads of the tile summed onto the output rows
+    ``[base, base + w)`` as a ``(w, 1)`` column."""
+    keep = jnp.where(is_end, p, 0.0)
+    s = segment_onehot(rows, keep, base, w, m)
+    return jnp.sum(s, axis=1, keepdims=True)
+
+
+def _spmv_kernel(base_ref, *refs, m, win, quant):
+    # quantized streams: per-tile scale as a second scalar-prefetch operand
+    # next to row_base; dequant in register (see kernels/vsr.py, DESIGN.md §8)
+    if quant:
+        sc_ref, rows_ref, xg_ref, vals_ref, o_ref = refs
+    else:
+        rows_ref, xg_ref, vals_ref, o_ref = refs
+    i = pl.program_id(0)
+    rows = rows_ref[...]                                   # (1, T)
+    vals = vals_ref[...].astype(jnp.float32)
+    if quant:
+        vals = vals * sc_ref[i]
+    p = jnp.where(rows < m, vals * xg_ref[...], 0.0)
+    p, is_end = _segment_scan(p, rows)
+    o_ref[...] = _head_dump(p, rows, is_end, base_ref[i], win, m)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "win", "interpret"))
 def _spmv_call(rows, cols, vals, row_base, x, scales=None, *, m, win,
                interpret):
     n_tiles, t = rows.shape
-    k = x.shape[0]
     quant = scales is not None
-    in_specs = [
-        pl.BlockSpec((1, t), lambda i: (i, 0)),
-        pl.BlockSpec((1, t), lambda i: (i, 0)),
-        pl.BlockSpec((1, t), lambda i: (i, 0)),
-        pl.BlockSpec((1,), lambda i: (i,)),
-    ]
-    ops = [rows, cols, vals, row_base]
-    if quant:
-        in_specs.append(pl.BlockSpec((1,), lambda i: (i,)))
-        ops.append(scales)
-    in_specs.append(pl.BlockSpec((k,), lambda i: (0,)))
-    ops.append(x)
+    tile = lambda i, *pf: (i, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 if quant else 1,
+        grid=(n_tiles,),
+        in_specs=[tile_spec(t, tile)] * 3,
+        out_specs=pl.BlockSpec((None, win, 1), lambda i, *pf: (i, 0, 0)),
+    )
+    prefetch = ((row_base.astype(jnp.int32), scales) if quant
+                else (row_base.astype(jnp.int32),))
     partials = pl.pallas_call(
         functools.partial(_spmv_kernel, m=m, win=win, quant=quant),
-        grid=(n_tiles,),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, win), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_tiles, win), jnp.float32),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles, win, 1), jnp.float32),
         interpret=interpret,
-    )(*ops)
+    )(*prefetch, as_tiles(rows), _gathered(x, cols), as_tiles(vals))
 
     idx = row_base[:, None].astype(jnp.int32) + jnp.arange(win, dtype=jnp.int32)[None, :]
     y = jax.ops.segment_sum(partials.reshape(-1), idx.reshape(-1),
                             num_segments=m + win + 1)
     return y[:m]
+
+
+def _gathered(x, cols):
+    """The N=1 dense-row loading: ``x[cols]`` as an ``(n_tiles, 1, T)``
+    f32 stream.  At N=1 a gathered row is one scalar, so the XLA-side
+    gather moves exactly the bytes a per-nonzero DMA would — the same size
+    as the column-id stream it replaces — and the kernel streams it."""
+    return as_tiles(jnp.take(x.astype(jnp.float32), cols, axis=0))
 
 
 def spmv_vsr(bal: BalancedCOO, x: jax.Array, *,
@@ -132,86 +151,47 @@ def _spmv_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, quant):
     # with ``quant`` the per-tile scale rides the scalar-prefetch path as a
     # fourth prefetch operand, indexed by the visit's tile id
     if quant:
-        sc_ref, rows_ref, cols_ref, vals_ref, x_ref, o_ref = refs
+        sc_ref, rows_ref, xg_ref, vals_ref, o_ref = refs
     else:
-        rows_ref, cols_ref, vals_ref, x_ref, o_ref = refs
+        rows_ref, xg_ref, vals_ref, o_ref = refs
     v = pl.program_id(0)
-    rows = rows_ref[0, :]
-    cols = cols_ref[0, :]
-    vals = vals_ref[0, :].astype(jnp.float32)
+    rows = rows_ref[...]                                   # (1, T)
+    vals = vals_ref[...].astype(jnp.float32)
     if quant:
         vals = vals * sc_ref[vt_ref[v]]
-    t = rows.shape[0]
-    mask = rows < m
-    base = vb_ref[v] * wb
-    local = jnp.clip(rows - base, 0, wb - 1)
-    in_block = (rows - base >= 0) & (rows - base < wb)
-
-    p = vals * jnp.take(x_ref[...], cols)                              # (T,)
-    p = jnp.where(mask, p, 0.0)
-
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, t), 1)[0]
-    # --- the shuffle prefix network: add-if-row-matches, log2(T) rounds ---
-    # (rows never straddle output blocks, so the network runs un-masked and
-    # the block restriction applies only to the head dump below)
-    d = 1
-    while d < t:
-        p_prev = jnp.roll(p, d)
-        r_prev = jnp.roll(rows, d)
-        take = (idx >= d) & (r_prev == rows)
-        p = p + jnp.where(take, p_prev, 0.0)
-        d *= 2
-    # --- segment-head dump, restricted to this visit's output block ---
-    r_next = jnp.roll(rows, -1)
-    is_end = (idx == t - 1) | (r_next != rows)
-    keep = is_end & mask & in_block
-    contrib = jnp.where(keep, p, 0.0)
-
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (wb, t), 0)
-    sel = (local[None, :] == row_iota) & keep[None, :]
-    block_sum = jnp.sum(jnp.where(sel, contrib[None, :], 0.0), axis=1)
-
+    # the shuffle prefix network runs un-masked (rows never straddle output
+    # blocks); the block restriction applies only to the head dump
+    p = jnp.where(rows < m, vals * xg_ref[...], 0.0)
+    p, is_end = _segment_scan(p, rows)
     # sequential-grid accumulation: boundary-crossing rows are dumped once
-    # per visiting tile and summed here, in VMEM, instead of spilling.
-    # Padding visits (vs == 2, stacked sharded schedules) take neither
-    # branch — a free grid step.
-    @pl.when(vs_ref[v] == 1)
-    def _():
-        o_ref[...] = block_sum
-
-    @pl.when(vs_ref[v] == 0)
-    def _():
-        o_ref[...] += block_sum
+    # per visiting tile and summed here, in VMEM, instead of spilling
+    accumulate_visit(o_ref, _head_dump(p, rows, is_end, vb_ref[v] * wb, wb, m),
+                     vs_ref[v])
 
 
 @functools.partial(jax.jit, static_argnames=("m", "wb", "interpret"))
 def _spmv_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
                      interpret):
     n_tiles, t = rows.shape
-    k = x.shape[0]
     mb = -(-m // wb)
     n_visits = vt.shape[0]
     quant = scales is not None
     # ``*pf`` so the same index maps serve both scalar-prefetch arities
+    tile = lambda v, vt, *pf: (vt[v], 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quant else 3,
         grid=(n_visits,),
-        in_specs=[
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, t), lambda v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((k,), lambda v, vt, *pf: (0,)),
-        ],
-        out_specs=pl.BlockSpec((wb,), lambda v, vt, vb, *pf: (vb[v],)),
+        in_specs=[tile_spec(t, tile)] * 3,
+        out_specs=pl.BlockSpec((wb, 1), lambda v, vt, vb, *pf: (vb[v], 0)),
     )
     prefetch = (vt, vb, vs, scales) if quant else (vt, vb, vs)
     y = pl.pallas_call(
         functools.partial(_spmv_fused_kernel, m=m, wb=wb, quant=quant),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((mb * wb,), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32),
         interpret=interpret,
-    )(*prefetch, rows, cols, vals, x)
-    return y[:m]
+    )(*prefetch, as_tiles(rows), _gathered(x, cols), as_tiles(vals))
+    return y[:m, 0]
 
 
 def spmv_vsr_fused(bal: BalancedCOO, x: jax.Array, *,

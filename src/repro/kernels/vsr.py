@@ -27,12 +27,18 @@ TPU adaptation (see DESIGN.md §2):
       the kernel adds the boundary spills — extra HBM traffic of
       ``n_tiles*WIN*N`` per call, with the *global* ``WIN`` sized by the
       single worst tile.
-  * VDL (§2.1.2) is the gather ``X[cols]`` returning (T, N) blocks: one
-    logical load covers all N output columns (the V→N limit of float4).
+  * VDL (§2.1.2) is a DMA row gather: ``X`` stays in HBM whatever its row
+    count, and each nonzero of a tile copies the ``tile_n``-wide slice of
+    the dense row its column id names into a ``(T, tile_n)`` VMEM scratch —
+    one logical load covers all N output columns (the V→N limit of float4).
+    The column ids ride an SMEM block so the scalar core can address the
+    copies; all ``T`` copies are in flight before the first wait.
 
-Layout: T is kept a multiple of 128 (lane width), WIN/``wb`` multiples of 8
-(sublanes); N is padded to the lane width by the ops wrapper.  ``(T, wb,
-tile_n)`` is the measured tile geometry (``repro.kernels.tune``).
+Layout: the index/value streams are ``(n_tiles, 1, T)`` so one tile is a
+legal ``(1, T)`` block; T is kept a multiple of 128 (lane width),
+WIN/``wb`` multiples of 8 (sublanes); N is padded to ``tile_n`` by the
+wrapper and gathered operands are f32.  ``(T, wb, tile_n)`` is the measured
+tile geometry (``repro.kernels.tune``).
 """
 from __future__ import annotations
 
@@ -135,53 +141,147 @@ def plan_visits(bal: BalancedCOO, wb: int
 
 
 # ---------------------------------------------------------------------------
+# shared kernel pieces: dense-row loading, segment one-hot, block accumulation
+# ---------------------------------------------------------------------------
+
+def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None):
+    """Start the DMA row gather ``dst[i] = src[idx[i]]`` for every row ``i``
+    of the VMEM scratch ``dst`` (``(T, w)``; ``src`` is ``(rows, w)``).
+
+    This is the TPU form of the paper's vectorized dense-row loading (VDL):
+    ``src`` stays in HBM (``hbm_spec``) whatever its row count, and each
+    nonzero fetches exactly the dense row its column id names, one DMA per
+    row, all in flight at once.  A copy spans the full row width (the
+    tiled HBM layout takes single-row slices only at full width — column
+    blocks come from ``column_blocks``).  ``idx_ref`` is the tile's index
+    block in SMEM (``(1, T)``); ``clamp`` caps sentinel ids (padding rows of
+    a row-indexed gather) to a valid row."""
+    t = dst.shape[0]
+
+    def start(i, carry):
+        r = idx_ref[0, i]
+        if clamp is not None:
+            r = jnp.minimum(r, clamp)
+        pltpu.make_async_copy(src_hbm.at[pl.ds(r, 1)], dst.at[pl.ds(i, 1)],
+                              sem).start()
+        return carry
+
+    jax.lax.fori_loop(0, t, start, 0)
+
+
+def wait_row_gather(src_hbm, dst, sem):
+    """Wait for the ``T`` row copies ``start_row_gather`` put in flight on
+    ``sem`` (each wait retires one row-sized copy)."""
+    t = dst.shape[0]
+
+    def wait(i, carry):
+        pltpu.make_async_copy(src_hbm.at[pl.ds(0, 1)], dst.at[pl.ds(0, 1)],
+                              sem).wait()
+        return carry
+
+    jax.lax.fori_loop(0, t, wait, 0)
+
+
+def column_blocks(x: jax.Array, tile_n: int) -> jax.Array:
+    """``(K, nb * tile_n)`` dense operand → ``(nb, K, tile_n)`` f32, so the
+    row gather of column block ``j`` reads full-width rows of ``x[j]``."""
+    k, n_pad = x.shape
+    return x.astype(jnp.float32).reshape(k, n_pad // tile_n, tile_n
+                                         ).transpose(1, 0, 2)
+
+
+def new_tile(vt_ref, v):
+    """True on the first visit of a tile in the current sweep: consecutive
+    visits of one tile (block crossings, padding visits) reuse its gathered
+    rows in VMEM instead of fetching them again."""
+    return (v == 0) | (vt_ref[v] != vt_ref[jnp.maximum(v - 1, 0)])
+
+
+def block_hits(rows, base, wb: int, m: int):
+    """``(wb, T)`` mask: nonzero ``t`` of a tile (lane-major ``(1, T)`` row
+    ids) lands in output row ``base + r``; sentinel rows ``>= m`` never do."""
+    row_iota = jax.lax.broadcasted_iota(jnp.int32, (wb, rows.shape[-1]), 0)
+    return ((rows - base) == row_iota) & (rows < m)
+
+
+def segment_onehot(rows, weights, base, wb: int, m: int):
+    """The segment-reduction operator of one tile restricted to the output
+    rows ``[base, base + wb)``: ``S[r, t] = weights[t]`` where nonzero ``t``
+    lands in row ``base + r``, else 0.  ``S @ P`` is the shuffle-tree
+    segment sum as one MXU matmul."""
+    return jnp.where(block_hits(rows, base, wb, m), weights, 0.0)
+
+
+def mxu_dot(a, b):
+    """f32 matmul at full precision inside a kernel."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def accumulate_visit(o_ref, contrib, start):
+    """Sequential-grid accumulation into a revisited output block: the first
+    visit (``start == 1``) initialises it, later visits (``0``) add, and
+    padding visits (``2``, stacked sharded schedules) take neither branch."""
+
+    @pl.when(start == 1)
+    def _():
+        o_ref[...] = contrib
+
+    @pl.when(start == 0)
+    def _():
+        o_ref[...] += contrib
+
+
+def tile_spec(t: int, index_map, memory_space=None):
+    """BlockSpec of one ``(1, T)`` tile of an ``(n_tiles, 1, T)`` stream.
+    The unit middle dim makes the block's last two dims equal the array's,
+    which is what the TPU (8, 128) block rule accepts for a single row."""
+    if memory_space is None:
+        return pl.BlockSpec((None, 1, t), index_map)
+    return pl.BlockSpec((None, 1, t), index_map, memory_space=memory_space)
+
+
+def hbm_spec():
+    """An operand left in HBM for the row gathers (never staged to VMEM,
+    however small — the gathers slice it one row at a time)."""
+    return pl.BlockSpec(memory_space=pltpu.HBM)
+
+
+def as_tiles(a: jax.Array) -> jax.Array:
+    """``(n_tiles, T)`` stream → the ``(n_tiles, 1, T)`` layout of
+    ``tile_spec``."""
+    return a.reshape(a.shape[0], 1, a.shape[-1])
+
+
+# ---------------------------------------------------------------------------
 # fused kernel: in-kernel spill accumulation over revisited output blocks
 # ---------------------------------------------------------------------------
 
-def _vsr_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, quant):
+def _vsr_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, tile_n, quant):
     # with ``quant`` the per-tile scale rides the scalar-prefetch path as a
     # fourth prefetch operand (next to the visit schedule): the value stream
     # stays int8/fp8 all the way into VMEM and is rescaled *in register* —
     # no dequantized copy ever exists in HBM (DESIGN.md §8).
     if quant:
-        sc_ref, rows_ref, cols_ref, vals_ref, x_ref, o_ref = refs
+        sc_ref, rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
     else:
-        rows_ref, cols_ref, vals_ref, x_ref, o_ref = refs
-    v = pl.program_id(1)
-    rows = rows_ref[0, :]                      # (T,) global row ids
-    cols = cols_ref[0, :]
-    vals = vals_ref[0, :].astype(jnp.float32)
+        rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
+    j, v = pl.program_id(0), pl.program_id(1)
+
+    # dense-row loading (VDL): the tile's X rows for this column block
+    @pl.when(new_tile(vt_ref, v))
+    def _():
+        start_row_gather(x_hbm.at[j], cols_ref, xg, sem)
+        wait_row_gather(x_hbm.at[j], xg, sem)
+
+    vals = vals_ref[...].astype(jnp.float32)              # (1, T)
     if quant:
-        vals = vals * sc_ref[vt_ref[v]]        # in-register dequant
-    t = rows.shape[0]
-    base = vb_ref[v] * wb                      # this visit's block row offset
-    local = rows - base
-    mask = (rows < m) & (local >= 0) & (local < wb)
-    local = jnp.clip(local, 0, wb - 1)
-
-    # dense-row loading (VDL): one gather covers all N columns of this block
-    xg = jnp.take(x_ref[...], cols, axis=0)    # (T, TN)
-    p = vals[:, None] * xg.astype(jnp.float32)
-
-    # segment reduction as one-hot matmul on the MXU, restricted to the
-    # block's rows — (wb, T) instead of the spill path's (WIN, T)
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (wb, t), 0)
-    onehot = jnp.where((local[None, :] == row_iota) & mask[None, :], 1.0, 0.0)
-    contrib = jnp.dot(onehot, p, preferred_element_type=jnp.float32)
-
-    # sequential-grid accumulation: first visit initialises the block, later
-    # visits read-modify-write it in VMEM; the block flushes to HBM once,
-    # when the schedule moves on — no partials array, no segment_sum.
-    # Padding visits (vs == 2, stacked sharded schedules) take neither
-    # branch: the step re-points at the previous (tile, block) pair, so it
-    # costs no DMA and no write.
-    @pl.when(vs_ref[v] == 1)
-    def _():
-        o_ref[...] = contrib
-
-    @pl.when(vs_ref[v] == 0)
-    def _():
-        o_ref[...] += contrib
+        vals = vals * sc_ref[vt_ref[v]]                   # in-register dequant
+    # segment reduction as one weighted one-hot matmul on the MXU, restricted
+    # to this visit's (wb, tile_n) output block — boundary-crossing rows
+    # accumulate across consecutive visits in VMEM, no partials array.
+    s = segment_onehot(rows_ref[...], vals, vb_ref[v] * wb, wb, m)
+    accumulate_visit(o_ref, mxu_dot(s, xg[...]), vs_ref[v])
 
 
 @functools.partial(jax.jit,
@@ -196,25 +296,30 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
     quant = scales is not None
     # ``*pf`` so the same index maps serve the 3- and 4-operand scalar-
     # prefetch arities (scales prepend when the stream is quantized).
+    tile = lambda j, v, vt, *pf: (vt[v], 0, 0)  # noqa: E731
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quant else 3,
         grid=(nb, n_visits),
         in_specs=[
-            pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((1, t), lambda j, v, vt, *pf: (vt[v], 0)),
-            pl.BlockSpec((k, tile_n), lambda j, v, vt, *pf: (0, j)),
+            tile_spec(t, tile),
+            tile_spec(t, tile, pltpu.SMEM),
+            tile_spec(t, tile),
+            hbm_spec(),
         ],
         out_specs=pl.BlockSpec((wb, tile_n),
                                lambda j, v, vt, vb, *pf: (vb[v], j)),
+        scratch_shapes=[pltpu.VMEM((t, tile_n), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
     )
     prefetch = (vt, vb, vs, scales) if quant else (vt, vb, vs)
     out = pl.pallas_call(
-        functools.partial(_vsr_fused_kernel, m=m, wb=wb, quant=quant),
+        functools.partial(_vsr_fused_kernel, m=m, wb=wb, tile_n=tile_n,
+                          quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
         interpret=interpret,
-    )(*prefetch, rows, cols, vals, x)
+    )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(vals),
+      column_blocks(x, tile_n))
     return out[:m]
 
 
@@ -222,33 +327,23 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
 # spill kernel (the parity reference)
 # ---------------------------------------------------------------------------
 
-def _vsr_kernel(rows_ref, cols_ref, vals_ref, base_ref, *refs, m, win, quant):
-    # quantized streams carry their per-tile scale as a (1,)-block tensor
-    # input alongside ``row_base`` (same per-tile indexing); dequant happens
-    # in register right after the stream load.
+def _vsr_kernel(base_ref, *refs, m, win, tile_n, quant):
+    # quantized streams carry their per-tile scale as a second scalar-
+    # prefetch operand next to ``row_base``; dequant happens in register
+    # right after the stream load.
     if quant:
-        sc_ref, x_ref, o_ref = refs
+        sc_ref, rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
     else:
-        x_ref, o_ref = refs
-    rows = rows_ref[0, :]                      # (T,) global row ids
-    cols = cols_ref[0, :]
-    vals = vals_ref[0, :].astype(jnp.float32)
+        rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
+    i, j = pl.program_id(0), pl.program_id(1)
+    start_row_gather(x_hbm.at[j], cols_ref, xg, sem)
+    wait_row_gather(x_hbm.at[j], xg, sem)
+    vals = vals_ref[...].astype(jnp.float32)
     if quant:
-        vals = vals * sc_ref[0]                # in-register dequant
-    base = base_ref[0]
-    t = rows.shape[0]
-    mask = rows < m                            # sentinel padding drops out
-    local = jnp.clip(rows - base, 0, win - 1)  # in-window row id
-
-    # dense-row loading (VDL): one gather covers all N columns of this block
-    xg = jnp.take(x_ref[...], cols, axis=0)    # (T, TN)
-    p = vals[:, None] * xg.astype(jnp.float32)
-
-    # segment reduction as one-hot matmul on the MXU
-    row_iota = jax.lax.broadcasted_iota(jnp.int32, (win, t), 0)
-    onehot = jnp.where((local[None, :] == row_iota) & mask[None, :], 1.0, 0.0)
-    o_ref[0, :, :] = jnp.dot(onehot.astype(jnp.float32), p,
-                             preferred_element_type=jnp.float32)
+        vals = vals * sc_ref[i]                # in-register dequant
+    # the tile's (WIN, tile_n) window of row sums, rows [row_base, +WIN)
+    s = segment_onehot(rows_ref[...], vals, base_ref[i], win, m)
+    o_ref[...] = mxu_dot(s, xg[...])
 
 
 @functools.partial(jax.jit, static_argnames=("m", "win", "tile_n", "interpret"))
@@ -258,26 +353,27 @@ def _vsr_call(rows, cols, vals, row_base, x, scales=None, *, m, win, tile_n,
     k, n_pad = x.shape
     nb = n_pad // tile_n
     quant = scales is not None
-    in_specs = [
-        pl.BlockSpec((1, t), lambda i, j: (i, 0)),
-        pl.BlockSpec((1, t), lambda i, j: (i, 0)),
-        pl.BlockSpec((1, t), lambda i, j: (i, 0)),
-        pl.BlockSpec((1,), lambda i, j: (i,)),
-    ]
-    ops = [rows, cols, vals, row_base]
-    if quant:
-        in_specs.append(pl.BlockSpec((1,), lambda i, j: (i,)))
-        ops.append(scales)
-    in_specs.append(pl.BlockSpec((k, tile_n), lambda i, j: (0, j)))
-    ops.append(x)
-    partials = pl.pallas_call(
-        functools.partial(_vsr_kernel, m=m, win=win, quant=quant),
+    tile = lambda i, j, *pf: (i, 0, 0)  # noqa: E731
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2 if quant else 1,
         grid=(n_tiles, nb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, win, tile_n), lambda i, j: (i, 0, j)),
+        in_specs=[tile_spec(t, tile), tile_spec(t, tile, pltpu.SMEM),
+                  tile_spec(t, tile), hbm_spec()],
+        out_specs=pl.BlockSpec((None, win, tile_n),
+                               lambda i, j, *pf: (i, 0, j)),
+        scratch_shapes=[pltpu.VMEM((t, tile_n), jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    prefetch = ((row_base.astype(jnp.int32), scales) if quant
+                else (row_base.astype(jnp.int32),))
+    partials = pl.pallas_call(
+        functools.partial(_vsr_kernel, m=m, win=win, tile_n=tile_n,
+                          quant=quant),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, win, n_pad), jnp.float32),
         interpret=interpret,
-    )(*ops)
+    )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(vals),
+      column_blocks(x, tile_n))
 
     # spill combine: tile (t, w) holds the sum for global row row_base[t]+w;
     # one segment-sum merges boundary-crossing rows (the atomics analogue).

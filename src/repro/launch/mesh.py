@@ -17,12 +17,19 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(len(axes)))
 
 
 def make_local_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over real local devices (tests/examples on CPU)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=_auto(2))
+
+
+def _auto(n: int) -> tuple:
+    """Auto axis types: sharding constraints and shard_map may name any
+    axis (``jax.make_mesh`` defaults to Explicit axes)."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 # v5e hardware constants for the roofline terms (per chip)

@@ -210,10 +210,14 @@ def mlp_apply(p: dict, x: jax.Array, act: str = "swiglu") -> jax.Array:
 @dataclasses.dataclass(frozen=True)
 class SparsePattern:
     """Static (non-trainable) sparsity pattern of one pruned weight matrix,
-    in BalancedCOO layout. rows/cols: (n_tiles, tile) int32."""
+    in BalancedCOO layout. rows/cols: (n_tiles, tile) int32.  ``prep``: the
+    pattern's precomputed kernel prep opts (the fused kernel's visit
+    schedule), so a scanned — traced — pattern still reaches the Pallas
+    kernel (``execute_pattern(prep=...)``)."""
     rows: jax.Array
     cols: jax.Array
     shape: tuple
+    prep: Optional[dict] = None
 
     @staticmethod
     def random(key, m: int, k: int, density: float, tile: int) -> "SparsePattern":
@@ -244,7 +248,8 @@ def sparse_matmul(pattern: SparsePattern, vals: jax.Array, x: jax.Array, *,
     flat = x.reshape(-1, x.shape[-1])                           # (T, k)
     y = pattern_matmul(pattern.rows, pattern.cols, vals,
                        tuple(pattern.shape), flat.T,
-                       mesh=mesh, shard_axis=shard_axis)        # (m, T)
+                       mesh=mesh, shard_axis=shard_axis,
+                       prep=None if mesh is not None else pattern.prep)  # (m, T)
     return y.T.reshape(x.shape[:-1] + (pattern.shape[0],)).astype(x.dtype)
 
 

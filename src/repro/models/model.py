@@ -69,10 +69,16 @@ class Model:
                 else params["lm_head"])
 
     # ------------------------------------------------------------- backbones
-    def _backbone_uniform(self, params, x, positions, caches=None):
-        """dense/moe/vlm stack via lax.scan; caches scanned when present."""
+    def _backbone_uniform(self, params, x, positions, caches=None,
+                          sparse_inputs=None):
+        """dense/moe/vlm stack via lax.scan; caches scanned when present.
+        ``sparse_inputs``: the patterns' per-layer arrays as call operands
+        (``sparse_inputs()``); by default they are closed over."""
         cfg = self.cfg
         pats = self.patterns
+        pat_xs = (None if pats is None else
+                  sparse_inputs if sparse_inputs is not None
+                  else _pat_leaves(pats))
 
         def body(carry, xs):
             x, aux = carry
@@ -86,9 +92,10 @@ class Model:
                 cache = None
             else:
                 p, cache, pat = xs, None, None
-            patd = ({"gate": dataclasses.replace(pats["gate"], rows=pat[0], cols=pat[1]),
-                     "up": dataclasses.replace(pats["up"], rows=pat[2], cols=pat[3]),
-                     "down": dataclasses.replace(pats["down"], rows=pat[4], cols=pat[5])}
+            patd = ({name: dataclasses.replace(
+                        pats[name], rows=pat[name][0], cols=pat[name][1],
+                        prep={**pats[name].prep, **pat[name][2]})
+                     for name in ("gate", "up", "down")}
                     if pat is not None else None)
             if cache is not None:
                 cache = dict(cache, length=caches["length"])
@@ -101,11 +108,11 @@ class Model:
         body = _remat(cfg, body) if caches is None else body
         xs: Any = params["blocks"]
         if caches is not None and pats is not None:
-            xs = (xs, caches["kv"], _pat_leaves(pats))
+            xs = (xs, caches["kv"], pat_xs)
         elif caches is not None:
             xs = (xs, caches["kv"])
         elif pats is not None:
-            xs = (xs, _pat_leaves(pats))
+            xs = (xs, pat_xs)
         (x, aux), new_kv = jax.lax.scan(body, (x, 0.0), xs)
         new_caches = None
         if caches is not None:
@@ -267,7 +274,8 @@ class Model:
                        kv=jax.tree_util.tree_map(lambda *a: jnp.stack(a), *new_kv),
                        length=caches["length"] + s), 0.0
 
-    def _backbone(self, params, x, positions, caches=None, memory=None):
+    def _backbone(self, params, x, positions, caches=None, memory=None,
+                  sparse_inputs=None):
         fam = self.cfg.family
         if fam == "audio":
             return self._backbone_whisper(params, x, positions, caches, memory)
@@ -277,11 +285,20 @@ class Model:
             return self._backbone_zamba(params, x, positions, caches)
         if fam == "ssm" and self.cfg.ssm.kind == "rwkv6":
             return self._backbone_rwkv(params, x, positions, caches)
-        return self._backbone_uniform(params, x, positions, caches)
+        return self._backbone_uniform(params, x, positions, caches,
+                                      sparse_inputs)
 
     # ------------------------------------------------------------ public fns
-    def loss_fn(self, params, batch):
-        """batch: tokens (B,S), labels (B,S) [-1 = pad]; audio adds frames."""
+    def sparse_inputs(self):
+        """The sparse-FFN patterns' arrays (rows, cols, kernel prep per
+        layer) as a pytree to hand ``loss_fn`` as an operand — closed over
+        instead, gigabytes of pattern would be baked into the compiled
+        program as constants.  ``None`` without ``sparse_ffn``."""
+        return None if self.patterns is None else _pat_leaves(self.patterns)
+
+    def loss_fn(self, params, batch, sparse_inputs=None):
+        """batch: tokens (B,S), labels (B,S) [-1 = pad]; audio adds frames.
+        ``sparse_inputs``: ``self.sparse_inputs()`` passed as an operand."""
         cfg = self.cfg
         tokens = batch["tokens"]
         x = self._embed(params, tokens)
@@ -289,7 +306,8 @@ class Model:
         memory = None
         if cfg.family == "audio":
             memory = self._encode_audio(params, batch["frames"])
-        h, _, aux = self._backbone(params, x, positions, memory=memory)
+        h, _, aux = self._backbone(params, x, positions, memory=memory,
+                                   sparse_inputs=sparse_inputs)
         h = rmsnorm(h, params["final_ln"], cfg.norm_eps)
         loss, ntok = lm_loss(h, self._unembed_w(params), batch["labels"])
         aux_w = cfg.moe.router_aux_weight if cfg.moe else 0.0
@@ -381,8 +399,11 @@ class Model:
 
 
 def _pat_leaves(pats):
-    return (pats["gate"].rows, pats["gate"].cols, pats["up"].rows,
-            pats["up"].cols, pats["down"].rows, pats["down"].cols)
+    """The per-layer (scanned) arrays of each pattern: rows, cols and the
+    array entries of its stacked kernel prep."""
+    return {name: (p.rows, p.cols,
+                   {k: v for k, v in p.prep.items() if hasattr(v, "shape")})
+            for name, p in pats.items()}
 
 
 def _sinusoid(s: int, d: int, dtype):

@@ -198,8 +198,26 @@ def sparse_patterns(cfg: ModelConfig, seed: int = 17):
 
     def stack(ps):
         return SparsePattern(jnp.stack([p.rows for p in ps]),
-                             jnp.stack([p.cols for p in ps]), ps[0].shape)
+                             jnp.stack([p.cols for p in ps]), ps[0].shape,
+                             prep=_stacked_visit_prep(ps))
     return {k: stack(v) for k, v in pats.items()}
+
+
+def _stacked_visit_prep(ps) -> dict:
+    """The fused NB kernel's visit schedule for every layer's pattern, padded
+    to one length with no-op visits and stacked on the layer axis — scanned
+    with the patterns, each layer's slice is its own schedule."""
+    from repro.core.formats import BalancedCOO
+    from repro.core.selector import TileGeometry
+    from repro.core.shard import stack_visit_schedules
+    from repro.kernels.vsr import plan_visits
+    geom = TileGeometry()
+    scheds = [plan_visits(BalancedCOO(np.asarray(p.rows), np.asarray(p.cols),
+                                      None, p.shape), geom.wb) for p in ps]
+    vt, vb, vs = stack_visit_schedules(scheds)
+    return {"visit_tile": jnp.asarray(vt), "visit_block": jnp.asarray(vb),
+            "visit_start": jnp.asarray(vs), "wb": geom.wb,
+            "tile_n": geom.tile_n}
 
 
 # ---------------------------------------------------------------------------

@@ -17,7 +17,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as PS
 
 from .compress import ef_accumulate, int8_decode
@@ -56,7 +56,7 @@ def make_dp_compressed_allreduce(mesh, dp_axis: str = "data"):
         @functools.partial(shard_map, mesh=mesh,
                            in_specs=(PS(dp_axis), PS(dp_axis)),
                            out_specs=(PS(), PS(dp_axis)),
-                           check_rep=False)
+                           check_vma=False)
         def inner(g, r):
             g = jax.tree_util.tree_map(lambda x: x[0], g)  # drop dp dim
             r = jax.tree_util.tree_map(lambda x: x[0], r)

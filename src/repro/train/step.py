@@ -35,24 +35,27 @@ def make_train_step(loss_fn: Callable, tcfg: TrainConfig) -> Callable:
     """loss_fn(params, batch) -> (loss, metrics dict).
 
     Returns train_step(state, batch) -> (state, metrics) where
-    state = {"params": ..., "opt": ...}.  ``tcfg.sparse_backend`` pins the
+    state = {"params": ..., "opt": ...}.  A state built with
+    ``init_state(..., consts=c)`` also carries non-trainable operands
+    (``Model.sparse_inputs()``): they pass through unchanged and reach the
+    loss as ``loss_fn(params, batch, c)``.  ``tcfg.sparse_backend`` pins the
     sparse-kernel backend for the whole step's trace through the facade's
     ``use_backend`` scope — no kwarg threading through model code."""
     if tcfg.sparse_backend is not None:
         from repro.api import use_backend
         inner_loss = loss_fn
 
-        def loss_fn(params, batch):
+        def loss_fn(params, batch, *consts):
             with use_backend(tcfg.sparse_backend):
-                return inner_loss(params, batch)
+                return inner_loss(params, batch, *consts)
 
     grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
 
-    def single(params, batch):
-        (loss, metrics), grads = grad_fn(params, batch)
+    def single(params, batch, *consts):
+        (loss, metrics), grads = grad_fn(params, batch, *consts)
         return loss, metrics, grads
 
-    def accumulate(params, batch):
+    def accumulate(params, batch, *consts):
         mb = tcfg.microbatches
         adt = jnp.dtype(tcfg.accum_dtype)
         split = jax.tree_util.tree_map(
@@ -60,7 +63,7 @@ def make_train_step(loss_fn: Callable, tcfg: TrainConfig) -> Callable:
 
         def body(carry, mbatch):
             acc, loss_acc = carry
-            (loss, _), grads = grad_fn(params, mbatch)
+            (loss, _), grads = grad_fn(params, mbatch, *consts)
             acc = jax.tree_util.tree_map(
                 lambda a, g: a + g.astype(adt), acc, grads)
             return (acc, loss_acc + loss), None
@@ -73,10 +76,11 @@ def make_train_step(loss_fn: Callable, tcfg: TrainConfig) -> Callable:
 
     def train_step(state, batch):
         params, opt = state["params"], state["opt"]
+        consts = (state["consts"],) if "consts" in state else ()
         if tcfg.microbatches > 1:
-            loss, metrics, grads = accumulate(params, batch)
+            loss, metrics, grads = accumulate(params, batch, *consts)
         else:
-            loss, metrics, grads = single(params, batch)
+            loss, metrics, grads = single(params, batch, *consts)
         new_params, new_opt, opt_metrics = adamw_update(params, grads, opt, tcfg.opt)
         out = {"loss": loss, **{k: v for k, v in metrics.items()
                                 if jnp.ndim(v) == 0}, **opt_metrics}
@@ -92,13 +96,21 @@ def make_train_step(loss_fn: Callable, tcfg: TrainConfig) -> Callable:
             new_params = keep(new_params, params)
             new_opt = keep(new_opt, opt)
             out["skipped_nonfinite"] = jnp.where(ok, 0, 1)
-        return {"params": new_params, "opt": new_opt}, out
+        new_state = {"params": new_params, "opt": new_opt}
+        if consts:
+            new_state["consts"] = consts[0]
+        return new_state, out
 
     return train_step
 
 
-def init_state(params: Any, tcfg: TrainConfig) -> dict:
-    return {"params": params, "opt": init_opt_state(params, tcfg.opt)}
+def init_state(params: Any, tcfg: TrainConfig, consts: Any = None) -> dict:
+    """``consts``: non-trainable loss operands carried with the state (see
+    ``make_train_step``); ``None`` leaves them out."""
+    state = {"params": params, "opt": init_opt_state(params, tcfg.opt)}
+    if consts is not None:
+        state["consts"] = consts
+    return state
 
 
 def sparse_weight_shardings(params: Any, mesh, rules=None) -> Any:

@@ -148,8 +148,6 @@ def test_quant_bf16_accumulation(rng):
 
 
 def test_fp8_parity(rng):
-    if not qm.supports("fp8"):
-        pytest.skip("no float8_e4m3fn in this jax")
     csr, _ = random_csr(rng, 48, 40, 0.2)
     p = plan(csr, backend="xla", quant="fp8")
     assert p.quant == "fp8"

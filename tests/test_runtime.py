@@ -118,7 +118,8 @@ def test_elastic_restore(tmp_ckpt):
     mgr = CheckpointManager(tmp_ckpt)
     tree = {"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}
     mgr.save(1, tree)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
     sh = {"w": NamedSharding(mesh, P("data", None))}
     back = mgr.restore(1, like=tree, shardings=sh)

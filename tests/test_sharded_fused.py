@@ -174,7 +174,8 @@ def test_sharded_fused_empty_row_shard():
 def test_sharded_fused_single_shard_mesh():
     csr = _skewed_csr(seed=7)
     mesh = _mesh(1) if jax.device_count() == 1 else jax.make_mesh(
-        (1, 1), ("data", "model"), devices=np.asarray(jax.devices()[:1]))
+        (1, 1), ("data", "model"), devices=np.asarray(jax.devices()[:1]),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
     p = plan(csr, backend="sharded", mesh=mesh, shard_kind="nnz", tile=64,
              inner_backend="pallas")
     x = jnp.asarray(np.random.default_rng(2)

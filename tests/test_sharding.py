@@ -13,6 +13,7 @@ from repro.launch.sharding_rules import (LONG_CTX_OVERRIDES, TRAIN_RULES,
                                          check_divisibility, partition_spec,
                                          resolve_rules)
 from repro.configs import ARCH_NAMES, get
+from repro.launch.mesh import make_local_mesh
 from repro.models.config import SHAPES
 from repro.models.params import param_count
 from repro.models.transformer import model_specs
@@ -155,9 +156,9 @@ def test_constrain_divisibility_fallback():
     """24 heads on model=16 must fall back to unsharded, not crash."""
     import os
     if jax.device_count() < 2:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_local_mesh(1, 1)
     else:
-        mesh = jax.make_mesh((1, jax.device_count()), ("data", "model"))
+        mesh = make_local_mesh(1, jax.device_count())
     from repro.models.sharding_ctx import activation_sharding, constrain
     from repro.launch.sharding_rules import resolve_rules
     with activation_sharding(mesh, resolve_rules()):

@@ -248,17 +248,20 @@ def test_modeled_traffic_fused_wins_on_skew():
 
 
 def test_pathological_span_falls_back_to_xla(rng):
+    """A tile spanning a 5000-row gap no longer demotes the plan: the fused
+    kernels size work per output block, so the plan stays on Pallas and
+    only the spill window's ``max_win`` guard warns (at prep)."""
     a = np.zeros((5000, 16), np.float32)
     a[0, :4] = 1.0
     a[4999, 0] = 1.0                                   # 5000-row gap in 1 tile
     csr = csr_from_dense(a)
-    with pytest.warns(UserWarning, match="max_win"):
-        p = plan(csr, backend="pallas")
-    assert p.backend == "xla"
+    p = plan(csr, backend="pallas")
+    assert p.backend == "pallas"
     x = jnp.asarray(rng.standard_normal((16, 3)).astype(np.float32))
-    np.testing.assert_allclose(np.asarray(execute(p, x)), a @ np.asarray(x),
-                               atol=1e-4)
-    # a permissive bound keeps pallas (fused handles the gap fine)
+    with pytest.warns(UserWarning, match="max_win"):
+        got = np.asarray(execute(p, x, impl="nb_pr", interpret=True))
+    np.testing.assert_allclose(got, a @ np.asarray(x), atol=2e-3)
+    # a permissive bound silences the spill-window guard
     th = SelectorThresholds(max_win=1 << 20)
     p2 = plan(csr, backend="pallas", thresholds=th)
     assert p2.backend == "pallas"

@@ -1,0 +1,173 @@
+"""Compile the Pallas kernels of the main path for a described TPU v5e.
+
+No chip is attached: the TPU compiler compiles for a topology it is only
+told about, so these tests catch what interpret mode cannot — block shapes
+off the (8, 128) tiling, primitives Mosaic cannot lower, more VMEM than a
+kernel may use.  Each case builds its plan through ``repro.api.sparse`` on
+the Pallas backend and compiles the registered kernel with
+``interpret=False`` at a size where the dense operand cannot sit in VMEM.
+
+The topology is described inside a fixture (never at import): only one
+process may load the TPU library, and pytest-xdist workers import every
+test file.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from repro import api
+from repro.core import csr_from_coo, rmat
+from repro.core.registry import resolve
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    # a compile for a described chip cannot be read back from the
+    # persistent cache here; keep these compiles out of it
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+@pytest.fixture(scope="module")
+def graph():
+    """R-MAT scale 16: x at N=128 is 32 MiB of f32, four times VMEM."""
+    return api.sparse(rmat(16, 16, seed=1), backend="pallas")
+
+
+def _spec(a, sharding):
+    return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+
+def _compile(fn, args, sharding):
+    specs = jax.tree_util.tree_map(lambda a: _spec(a, sharding), args)
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _split_opts(opts):
+    arrays = {k: v for k, v in opts.items() if hasattr(v, "shape")}
+    return arrays, {k: v for k, v in opts.items() if k not in arrays}
+
+
+def _compile_matmul(p, impl, x, sharding):
+    entry = p.entry(impl)
+    assert entry.backend == "pallas"
+    arrays, statics = _split_opts(p.kernel_opts(entry))
+
+    def run(sub, arrays, x):
+        return entry.fn(sub, x, interpret=False, **statics, **arrays)
+
+    return _compile(run, (p.substrate(entry.substrate), arrays, x), sharding)
+
+
+@pytest.mark.parametrize("impl,n", [("nb_pr", 128), ("rs_pr", 128),
+                                    ("nb_pr", 1)])
+def test_spmm_compiles(no_cache, one_chip, graph, impl, n):
+    k = graph.shape[1]
+    x = jax.ShapeDtypeStruct((k, n) if n > 1 else (k,), jnp.float32)
+    _compile_matmul(graph.plan, impl, x, one_chip)
+
+
+def _chain_operands(p, logical):
+    entry = p.entry(logical)
+    arrays, statics = _split_opts(p.kernel_opts(entry))
+    bal = p.substrate("balanced")
+    return entry, arrays, statics, bal
+
+
+def test_sddmm_compiles(no_cache, one_chip, graph):
+    m, k = graph.shape
+    entry, _, _, bal = _chain_operands(graph.plan, "sddmm")
+    a = jax.ShapeDtypeStruct((m, 64), jnp.float32)
+    b = jax.ShapeDtypeStruct((k, 64), jnp.float32)
+
+    def run(rows, cols, a, b):
+        return entry.fn(rows, cols, a, b, interpret=False, shape=(m, k))
+
+    _compile(run, (bal.rows, bal.cols, a, b), one_chip)
+
+
+def test_fused_chain_compiles(no_cache, one_chip, graph):
+    m, k = graph.shape
+    entry, arrays, statics, bal = _chain_operands(graph.plan, "chain")
+    a = jax.ShapeDtypeStruct((m, 64), jnp.float32)
+    b = jax.ShapeDtypeStruct((k, 64), jnp.float32)
+    x = jax.ShapeDtypeStruct((k, 64), jnp.float32)
+
+    def run(rows, cols, a, b, x, arrays):
+        return entry.fn(rows, cols, a, b, x, interpret=False, shape=(m, k),
+                        transform="softmax", alpha=0.125, **statics,
+                        **arrays)
+
+    _compile(run, (bal.rows, bal.cols, a, b, x, arrays), one_chip)
+
+
+def test_block_sparse_attention_compiles(no_cache, one_chip):
+    seq, d = 4096, 128
+    p = api.attention_plan(api.sliding_window(seq, 2, block=64),
+                           backend="pallas", cache=False)
+    entry, arrays, statics, bal = _chain_operands(p, "attn_chain")
+    q = jax.ShapeDtypeStruct((seq, d), jnp.float32)
+
+    def run(rows, cols, q, k, bias, v, arrays):
+        return entry.fn(rows, cols, q, k, bias, v, interpret=False,
+                        shape=(seq, seq), scale=d ** -0.5, **statics,
+                        **arrays)
+
+    bias = jax.ShapeDtypeStruct(bal.rows.shape, jnp.float32)
+    _compile(run, (bal.rows, bal.cols, q, q, bias, q, arrays), one_chip)
+
+
+def test_sparse_ffn_spmm_compiles(no_cache, one_chip):
+    """One pruned FFN weight (d_ff 8192 x d_model 2048 at density 0.15)
+    against 2048 tokens, the way ``execute_pattern`` binds it."""
+    m, k, tile, tokens = 8192, 2048, 512, 2048
+    rng = np.random.default_rng(0)
+    flat = np.sort(rng.choice(m * k, size=int(m * k * 0.15), replace=False))
+    csr = csr_from_coo(flat // k, flat % k,
+                       np.ones(len(flat), np.float32), (m, k))
+    p = api.sparse(csr, backend="pallas", tile=tile).plan
+    x = jax.ShapeDtypeStruct((k, tokens), jnp.bfloat16)
+    _compile_matmul(p, "nb_pr", x, one_chip)
+
+
+def test_execute_pattern_prep_is_traceable(no_cache, one_chip):
+    """The sparse-FFN layers scan per-layer patterns: their visit schedules
+    arrive traced, as kernel operands, and still compile to the kernel."""
+    m, k = 512, 256
+    csr = rmat(9, 8, seed=2, m=m, k=k)
+    bal = api.sparse(csr, backend="pallas").plan.substrate("balanced")
+    opts = resolve("nb_pr", "pallas").prep(bal)
+    arrays, statics = _split_opts(opts)
+    x = jax.ShapeDtypeStruct((k, 128), jnp.float32)
+
+    def run(rows, cols, vals, x, arrays):
+        return api.pattern_matmul(rows, cols, vals, (m, k), x,
+                                  backend="pallas", interpret=False,
+                                  prep=dict(arrays, **statics))
+
+    _compile(run, (bal.rows, bal.cols, bal.vals.reshape(-1), x, arrays),
+             one_chip)

@@ -82,6 +82,38 @@ def test_overfit_single_batch():
     assert float(metrics["loss"]) < first - 1.0, "overfit failed"
 
 
+def test_sparse_ffn_step_reaches_pallas_through_scan():
+    """The scanned sparse-FFN layers hand their precomputed visit schedules
+    to the Pallas kernel (no traced-pattern fallback to XLA), and the
+    patterns ride the state as operands: the step matches the XLA backend."""
+    import dataclasses
+
+    from repro import api
+    from repro.models.config import SparseFFNConfig
+    cfg = dataclasses.replace(get_smoke("llama3.2-1b"),
+                              sparse_ffn=SparseFFNConfig(density=0.3, tile=128))
+    model = Model(cfg)
+    opt = OptConfig(lr=1e-3, warmup_steps=0, total_steps=2)
+    data = SyntheticLM(DataConfig(seed=0, vocab_size=cfg.vocab_size,
+                                  seq_len=8, global_batch=2))
+    batch = {k: jnp.asarray(v) for k, v in data.batch(0).items()}
+    params = model.init(jax.random.PRNGKey(0))
+    out = {}
+    api.reset_health()
+    for backend in ("pallas", "xla"):
+        tcfg = TrainConfig(opt=opt, sparse_backend=backend)
+        state = init_state(params, tcfg, consts=model.sparse_inputs())
+        state, metrics = jax.jit(make_train_step(model.loss_fn, tcfg))(
+            state, batch)
+        assert "consts" in state
+        out[backend] = (float(metrics["loss"]), state["params"])
+    assert "demote:traced_pattern_to_xla" not in api.health()["counters"]
+    assert out["pallas"][0] == pytest.approx(out["xla"][0], rel=1e-5)
+    d = jax.tree_util.tree_map(lambda a, b: float(jnp.abs(a - b).max()),
+                               out["pallas"][1], out["xla"][1])
+    assert max(jax.tree_util.tree_leaves(d)) < 1e-4
+
+
 def test_int8_roundtrip_and_ef():
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal(512).astype(np.float32))
@@ -113,7 +145,8 @@ def test_dp_compressed_allreduce_matches_mean():
     from repro.train.manual_collectives import make_dp_compressed_allreduce
 
     n = jax.device_count()
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = jax.make_mesh((n,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     rng = np.random.default_rng(0)
     g_np = rng.standard_normal((n, 8)).astype(np.float32)
     grads = {"w": jnp.asarray(g_np)}
