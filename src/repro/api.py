@@ -455,8 +455,8 @@ def clear_cache(cache: PlanCache | None = None) -> None:
 
 
 def health() -> dict:
-    """Snapshot of the guardrail health registry (DESIGN.md §12):
-    ``{"counters": {...}, "breakers": {"backend:logical": {...}}}``.
+    """Snapshot of the health registry (DESIGN.md §12):
+    ``{"counters", "breakers", "spans", "compile", "work"}``.
 
     Counters include the named demotions that used to be silent warnings
     (``demote:quant_range``, ``demote:chain_fuse``, ``demote:attn_fuse``,
@@ -464,12 +464,31 @@ def health() -> dict:
     (``sentinel:<site>``), kernel reroutes
     (``kernel_reroute:<from>-><to>:<logical>``), and pattern
     validation/repair events.  Breakers carry state / consecutive failures /
-    trips / recoveries per (backend, logical kernel)."""
+    trips / recoveries per (backend, logical kernel).
+
+    ``spans``: per span name (``repro.plan``, ``repro.plan.cache``,
+    ``repro.plan.stats``, ``repro.plan.fingerprint``,
+    ``repro.plan.substrate``, ``repro.plan.prep`` with its children
+    ``repro.plan.windows`` and ``repro.plan.visits``, and
+    ``repro.execute.trace``, the Python side of ``execute``, which under
+    ``jit`` runs while the step is traced): ``count``, ``total_s``,
+    ``self_s`` (less the child spans), ``parents`` (enclosing span name →
+    count) and ``args`` (``key=value`` → count; the substrate's ``kind``).
+    ``compile``: backend compilations seen by a ``jax.monitoring``
+    listener — ``count``, ``seconds`` (a persistent-cache read or a
+    compile and cache write), ``cache_hits``, ``cache_misses``,
+    ``retrieval_s`` and ``by_function`` (``jit(<name>)`` → count,
+    seconds).  ``work``: per ``<kernel>:<topology[:12]>:n<N>``, the
+    per-call work of the kernel ``execute`` bound, counted from its visit
+    schedule and operands — ``visits``, ``dummy_visits``, ``tile_loads``,
+    ``rows_gathered``, with ``kernel``, ``topology``, ``n`` and ``binds``
+    (times ``execute`` bound it)."""
     return HEALTH.snapshot()
 
 
 def reset_health() -> None:
-    """Drop all guardrail counters and breakers (tests / fresh epochs)."""
+    """Drop all counters, breakers, spans, compile and work records (tests /
+    fresh epochs)."""
     HEALTH.reset()
 
 

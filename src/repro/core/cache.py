@@ -26,7 +26,7 @@ from typing import Any, Callable, Optional
 import numpy as np
 
 from .formats import CSR
-from .guardrails import plan_digest, validate_csr
+from .guardrails import HEALTH, plan_digest, validate_csr
 from .selector import SelectorThresholds
 
 
@@ -34,6 +34,7 @@ from .selector import SelectorThresholds
 # key components
 # ---------------------------------------------------------------------------
 
+@HEALTH.span("repro.plan.fingerprint")
 def pattern_fingerprint(csr: CSR) -> str:
     """Sparsity-topology digest of a CSR: pattern + shape, values excluded —
     matrices differing only in values share a fingerprint (and a plan; value
@@ -241,6 +242,7 @@ class PlanCache:
 DEFAULT_CACHE = PlanCache()
 
 
+@HEALTH.span("repro.plan.cache")
 def cached_plan(csr: CSR, *, cache: PlanCache | None = None,
                 backend: str | None = None,
                 thresholds: SelectorThresholds | None = None,

@@ -39,7 +39,9 @@ Everything observable lands in the process ``HEALTH`` registry
 recoveries, reroutes, sentinel firings, pattern repairs, and the named
 demotion counters for decisions that used to be silent ``warnings.warn``
 calls (quant range fallback, the fuse crossovers, a traced pattern without
-prep).
+prep).  The registry also keeps the program's host spans, the XLA
+compilations a ``jax.monitoring`` listener sees, and the per-call work of
+each kernel ``execute`` binds (DESIGN.md §12.6).
 
 Under ``jit`` the breaker decision and sentinel wiring bake at trace time
 (the guard is host-side dispatch); eager execution — the fault-matrix test
@@ -256,15 +258,26 @@ class CircuitBreaker:
                     "trips": self.trips, "recoveries": self.recoveries}
 
 
+def _empty_compile() -> dict:
+    return {"count": 0, "seconds": 0.0, "cache_hits": 0, "cache_misses": 0,
+            "retrieval_s": 0.0, "by_function": {}}
+
+
 class HealthRegistry:
-    """Process-wide guardrail observability: named counters plus the
-    per-(backend, logical-kernel) breakers.  ``api.health()`` and
+    """Process-wide observability: named counters, the per-(backend,
+    logical-kernel) breakers, host spans (``span``), XLA compilations (fed by
+    a ``jax.monitoring`` listener) and the per-call work of the kernels
+    ``execute`` binds (``record_work``).  ``api.health()`` and
     ``engine.metrics()["health"]`` are snapshots of this object."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self._counters: dict[str, int] = {}
         self._breakers: dict[tuple, CircuitBreaker] = {}
+        self._spans: dict[str, dict] = {}
+        self._open = threading.local()      # per-thread stack of open spans
+        self._compile = _empty_compile()
+        self._work: dict[str, dict] = {}
         self._threshold = 3
         self._cooldown_s = 30.0
         self._clock: Callable[[], float] = time.monotonic
@@ -301,24 +314,127 @@ class HealthRegistry:
         with self._lock:
             return self._counters.get(name, 0)
 
+    @contextlib.contextmanager
+    def span(self, name: str, **args):
+        """Time a piece of host work under ``name``.
+
+        The span opens a ``jax.profiler.TraceAnnotation`` (``args`` ride
+        along), so under an active profiler trace it lands on the host plane
+        on the device ops' clock; and it always adds to the in-memory record
+        of ``name``: count, total and self seconds (total less the time its
+        child spans on the same thread cover), the names of the spans it
+        opened under, and a count per ``key=value`` of ``args``.  Also a
+        decorator (``@HEALTH.span("repro.plan")``)."""
+        stack = getattr(self._open, "stack", None)
+        if stack is None:
+            stack = self._open.stack = []
+        frame = [name, 0.0]                 # name, seconds of child spans
+        stack.append(frame)
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name, **args):
+                yield
+        finally:
+            dt = time.perf_counter() - t0
+            stack.pop()
+            parent = stack[-1][0] if stack else None
+            if stack:
+                stack[-1][1] += dt
+            with self._lock:
+                rec = self._spans.get(name)
+                if rec is None:
+                    rec = self._spans[name] = {
+                        "count": 0, "total_s": 0.0, "self_s": 0.0,
+                        "parents": {}, "args": {}}
+                rec["count"] += 1
+                rec["total_s"] += dt
+                rec["self_s"] += dt - frame[1]
+                if parent is not None:
+                    rec["parents"][parent] = rec["parents"].get(parent, 0) + 1
+                for k, v in args.items():
+                    kv = f"{k}={v}"
+                    rec["args"][kv] = rec["args"].get(kv, 0) + 1
+
+    def record_compile(self, seconds: float, fun_name: str | None) -> None:
+        """One backend compilation of ``fun_name`` (persistent-cache
+        retrieval, or compile and cache write, included)."""
+        with self._lock:
+            c = self._compile
+            c["count"] += 1
+            c["seconds"] += seconds
+            if fun_name:
+                f = c["by_function"].setdefault(fun_name,
+                                                {"count": 0, "seconds": 0.0})
+                f["count"] += 1
+                f["seconds"] += seconds
+
+    def record_cache(self, hit: bool, retrieval_s: float = 0.0) -> None:
+        """A persistent compilation-cache hit (with the seconds it took to
+        read) or miss."""
+        with self._lock:
+            self._compile["cache_hits" if hit else "cache_misses"] += 1
+            self._compile["retrieval_s"] += retrieval_s
+
+    def record_work(self, key: str, work: dict) -> None:
+        """The per-call work of a bound kernel, as ``execute`` counted it
+        when it bound the call; a later bind under the same key replaces the
+        numbers and adds to ``binds``."""
+        with self._lock:
+            binds = self._work.get(key, {}).get("binds", 0)
+            self._work[key] = dict(work, binds=binds + 1)
+
     def snapshot(self) -> dict:
         with self._lock:
             return {
                 "counters": dict(self._counters),
                 "breakers": {f"{b}:{l}": br.snapshot()
                              for (b, l), br in self._breakers.items()},
+                "spans": {n: dict(r, parents=dict(r["parents"]),
+                                  args=dict(r["args"]))
+                          for n, r in self._spans.items()},
+                "compile": dict(self._compile, by_function={
+                    f: dict(v)
+                    for f, v in self._compile["by_function"].items()}),
+                "work": {k: dict(v) for k, v in self._work.items()},
             }
 
     def reset(self) -> None:
-        """Drop counters and breakers (tests; production code never calls
-        this — lifetime counters are the point)."""
+        """Drop counters, breakers, spans, compile and work records (tests;
+        production code never calls this — lifetime counters are the
+        point)."""
         with self._lock:
             self._counters.clear()
             self._breakers.clear()
+            self._spans.clear()
+            self._compile = _empty_compile()
+            self._work.clear()
 
 
 #: the process default every core hook writes to.
 HEALTH = HealthRegistry()
+
+#: the ``jax.monitoring`` events ``HEALTH``'s compile record reads.  JAX
+#: times ``compile_or_get_cached`` as the backend compile, so its seconds
+#: already hold a cache read (hit) or the compile and cache write (miss).
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+def _on_duration(event: str, secs: float, **kw) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        HEALTH.record_compile(secs, kw.get("fun_name"))
+    elif event == CACHE_RETRIEVAL_EVENT:
+        HEALTH.record_cache(True, secs)
+
+
+def _on_event(event: str, **_kw) -> None:
+    if event == CACHE_MISS_EVENT:
+        HEALTH.record_cache(False)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_event_listener(_on_event)
 
 
 #: kernel-failure types the degradation ladder catches and reroutes: the

@@ -38,7 +38,7 @@ import functools
 import hashlib
 import inspect
 from collections import OrderedDict
-from typing import Any
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -348,7 +348,8 @@ class PlanBuilder:
         return sub
 
     def _build_substrate(self, kind: str):
-        with jax.ensure_compile_time_eval():
+        with HEALTH.span("repro.plan.substrate", kind=kind), \
+                jax.ensure_compile_time_eval():
             if kind == "ell":
                 sub = csr_to_ell(self.csr)
             elif kind == "balanced":
@@ -464,7 +465,8 @@ class PlanBuilder:
                     entry.prep, {"geometry": self.geometry,
                                  "max_win": self.thresholds.max_win,
                                  "overlap_min_n": self.thresholds.overlap_min_n})
-                with jax.ensure_compile_time_eval():
+                with HEALTH.span("repro.plan.prep"), \
+                        jax.ensure_compile_time_eval():
                     opts = dict(entry.prep(sub, **ctx))
             if self.quant is not None and entry.substrate == "balanced":
                 # static mode flag for the kernel wrappers: baked substrates
@@ -597,6 +599,7 @@ class PlanBuilder:
 SparsePlan = PlanBuilder
 
 
+@HEALTH.span("repro.plan")
 def plan(csr: CSR, *, n_hint: int | None = None,
          thresholds: SelectorThresholds | None = None,
          backend: str | None = None, tile: int | None = None,
@@ -801,12 +804,36 @@ def _demoted_inner(p: PlanBuilder) -> PlanBuilder:
     return cached
 
 
+def _record_work(entry: registry.KernelEntry, sub, opts: dict, x,
+                 topology: Callable[[], str], memo: dict | None = None
+                 ) -> None:
+    """Record in ``HEALTH`` the per-call work of the kernel ``entry`` binds
+    (its ``work`` hook, on this call's schedule and operands), keyed by
+    kernel name, plan topology and N.  ``memo`` (a builder's) keeps the
+    count for the next call of the same kernel and operand shape."""
+    if entry.work is None:
+        return
+    n = 1 if x.ndim == 1 else int(x.shape[1])
+    key = (entry.logical, entry.backend, x.ndim, n)
+    record = None if memo is None else memo.get(key)
+    if record is None:
+        kernel, work = entry.work(sub, x, **opts)
+        topo = topology()
+        record = (f"{kernel}:{topo[:12]}:n{n}",
+                  dict(work, kernel=kernel, topology=topo, n=n))
+        if memo is not None:
+            memo[key] = record
+    HEALTH.record_work(*record)
+
+
 def _builder_exec(p: PlanBuilder, name: str, backend: str | None, x, vals,
                   interpret):
     """The unguarded builder dispatch: resolve → substrate → bind → run."""
     entry = p.entry(name, backend)
     sub = p.substrate(entry.substrate)
     bound = p.bound_kernel(entry, interpret)
+    _record_work(entry, sub, p.kernel_opts(entry), x, p.topology_key,
+                 p._opts.setdefault(("work",), {}))
     builder_aux = {"ell_lens": p.ell_lens, "ell_src": p.ell_src,
                    "bsr_map": p.bsr_map, "bsr_brow": p.bsr_brow,
                    "quant_scales": p.quant_scales}
@@ -814,6 +841,7 @@ def _builder_exec(p: PlanBuilder, name: str, backend: str | None, x, vals,
                       lambda name: builder_aux[name]())
 
 
+@HEALTH.span("repro.execute.trace")
 def execute(p: "PlanBuilder | PlanArtifact", x: jax.Array, *,
             vals: jax.Array | None = None, impl: str | None = None,
             backend: str | None = None,
@@ -891,8 +919,11 @@ def _execute_artifact(art: PlanArtifact, x, *, vals, impl, backend, interpret,
             "kernels= covering it")
 
     def run(entry_, sub_):
-        bound = _bound_kernel(entry_, interpret,
-                              dict(meta.prep).get(entry_.logical))
+        digest = dict(meta.prep).get(entry_.logical)
+        bound = _bound_kernel(entry_, interpret, digest)
+        _record_work(entry_, sub_, {} if digest is None
+                     else _OPTS_STORE.get(digest, {}), x,
+                     lambda: meta.topology)
         return _run_entry(entry_, sub_, bound, x, vals, meta.nnz,
                           lambda name: art.aux[name])
 

@@ -68,6 +68,9 @@ class KernelEntry:
     fn: Callable                 # fn(substrate, x, *, interpret=None, **opts)
     prep: Optional[Callable] = None   # prep(substrate) -> opts dict (host-side)
     differentiable: bool = True  # eligible for the unified custom-VJP path
+    # work(substrate, x, **opts) -> (kernel name, per-call counts); what
+    # ``execute`` records in ``HEALTH`` when it binds the entry (host-side)
+    work: Optional[Callable] = None
 
 
 _REGISTRY: dict[tuple[str, str], KernelEntry] = {}
@@ -82,7 +85,8 @@ _LAZY_BACKENDS: dict[str, str] = {
 
 
 def register(logical: str, backend: str, substrate: str, fn: Callable, *,
-             prep: Callable | None = None, differentiable: bool = True) -> KernelEntry:
+             prep: Callable | None = None, differentiable: bool = True,
+             work: Callable | None = None) -> KernelEntry:
     """Register (or replace) the physical implementation of a logical kernel."""
     if logical not in LOGICAL_KERNELS:
         raise ValueError(f"unknown logical kernel {logical!r}; "
@@ -90,7 +94,8 @@ def register(logical: str, backend: str, substrate: str, fn: Callable, *,
     if substrate not in SUBSTRATES:
         raise ValueError(f"unknown substrate {substrate!r}; "
                          f"expected one of {SUBSTRATES}")
-    entry = KernelEntry(logical, backend, substrate, fn, prep, differentiable)
+    entry = KernelEntry(logical, backend, substrate, fn, prep, differentiable,
+                        work)
     _REGISTRY[(logical, backend)] = entry
     return entry
 

@@ -13,6 +13,7 @@ import dataclasses
 import numpy as np
 
 from .formats import CSR
+from .guardrails import HEALTH
 
 
 @dataclasses.dataclass(frozen=True)
@@ -52,6 +53,7 @@ def balanced_tile_span(csr: CSR, tile: int) -> int:
     return int((row_of(ends) - row_of(starts) + 1).max())
 
 
+@HEALTH.span("repro.plan.stats")
 def matrix_stats(csr: CSR) -> MatrixStats:
     indptr = np.asarray(csr.indptr)
     lens = np.diff(indptr).astype(np.float64)

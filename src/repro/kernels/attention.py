@@ -83,6 +83,7 @@ def _attn_stats_call(vt, vb, vs, rows, cols, q, k, bias, *, m, wb, scale,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32),
                    jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32)],
+        name="repro_attn_stats",
         interpret=interpret,
     )(vt, vb, vs, rt, rt, as_tiles(cols), q, k, as_tiles(bias))
     return stats_blocks(rm, rs, wb)
@@ -145,6 +146,7 @@ def _attn_apply_call(vt, vb, vs, rows, cols, q, k, bias, x, rm, rs, *, m, wb,
                           scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
+        name="repro_attn_apply",
         interpret=interpret,
     )(vt, vb, vs, rt, rt, as_tiles(cols), q, k, as_tiles(bias),
       rm.reshape(-1, 1), rs.reshape(-1, 1), column_blocks(x, tile_n))

@@ -77,6 +77,7 @@ def _bsr_call(bcols_flat, blocks, x, *, wb, bm, bk, tile_n, interpret):
         _bsr_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * bm, n_pad), jnp.float32),
+        name="repro_bsr",
         interpret=interpret,
     )(bcols_flat, blocks, x)
 

@@ -174,6 +174,7 @@ def _sddmm_call(rows, cols, a, b, *, m, interpret):
         out_specs=tile_spec(t, tile),
         out_shape=jax.ShapeDtypeStruct((n_tiles, 1, t), jnp.float32),
         scratch_shapes=gather_scratch(t, d),
+        name="repro_sddmm",
         interpret=interpret,
     )(as_tiles(rows), as_tiles(cols), a, b)
     return jnp.where(rows < m, out.reshape(n_tiles, t), 0.0)
@@ -235,6 +236,7 @@ def _chain_stats_call(vt, vb, vs, rows, cols, a, b, *, m, wb, alpha,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32),
                    jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32)],
+        name="repro_chain_stats",
         interpret=interpret,
     )(vt, vb, vs, rt, rt, ct, a, b)
     return stats_blocks(rm, rs, wb)
@@ -318,6 +320,7 @@ def _chain_apply_call(vt, vb, vs, rows, cols, a, b, x, rm, rs, *, m, wb,
                           transform=transform, alpha=alpha),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
+        name="repro_chain_apply",
         interpret=interpret,
     )(vt, vb, vs, *ops)
     return out[:m]

@@ -106,6 +106,7 @@ def _spmv_call(rows, cols, vals, row_base, x, scales=None, *, m, win,
         functools.partial(_spmv_kernel, m=m, win=win, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, win, 1), jnp.float32),
+        name="repro_spmv_spill",
         interpret=interpret,
     )(*prefetch, as_tiles(rows), _gathered(x, cols), as_tiles(vals))
 
@@ -120,7 +121,8 @@ def _gathered(x, cols):
     f32 stream.  At N=1 a gathered row is one scalar, so the XLA-side
     gather moves exactly the bytes a per-nonzero DMA would — the same size
     as the column-id stream it replaces — and the kernel streams it."""
-    return as_tiles(jnp.take(x.astype(jnp.float32), cols, axis=0))
+    with jax.named_scope("repro.spmv.gather_x"):
+        return as_tiles(jnp.take(x.astype(jnp.float32), cols, axis=0))
 
 
 def spmv_vsr(bal: BalancedCOO, x: jax.Array, *,
@@ -189,6 +191,7 @@ def _spmv_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
         functools.partial(_spmv_fused_kernel, m=m, wb=wb, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, 1), jnp.float32),
+        name="repro_spmv_fused",
         interpret=interpret,
     )(*prefetch, as_tiles(rows), _gathered(x, cols), as_tiles(vals))
     return y[:m, 0]

@@ -53,9 +53,11 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import registry
 from repro.core.formats import BalancedCOO
+from repro.core.guardrails import HEALTH
 from repro.core.selector import TileGeometry
 
 
+@HEALTH.span("repro.plan.windows")
 def plan_windows(bal: BalancedCOO, *, max_win: int | None = None
                  ) -> tuple[np.ndarray, int]:
     """Host-side prep for the spill path: per-tile first row (row_base) and
@@ -82,6 +84,7 @@ def plan_windows(bal: BalancedCOO, *, max_win: int | None = None
     return first, win
 
 
+@HEALTH.span("repro.plan.visits")
 def plan_visits(bal: BalancedCOO, wb: int
                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
     """Host-side prep for the fused path: the (tile, output-block) visit
@@ -186,8 +189,9 @@ def column_blocks(x: jax.Array, tile_n: int) -> jax.Array:
     """``(K, nb * tile_n)`` dense operand → ``(nb, K, tile_n)`` f32, so the
     row gather of column block ``j`` reads full-width rows of ``x[j]``."""
     k, n_pad = x.shape
-    return x.astype(jnp.float32).reshape(k, n_pad // tile_n, tile_n
-                                         ).transpose(1, 0, 2)
+    with jax.named_scope("repro.spmm.column_blocks"):
+        return x.astype(jnp.float32).reshape(k, n_pad // tile_n, tile_n
+                                             ).transpose(1, 0, 2)
 
 
 def new_tile(vt_ref, v):
@@ -317,10 +321,12 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
                           quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
+        name="repro_vsr_fused",
         interpret=interpret,
     )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(vals),
       column_blocks(x, tile_n))
-    return out[:m]
+    with jax.named_scope("repro.spmm.unpad"):
+        return out[:m]
 
 
 # ---------------------------------------------------------------------------
@@ -371,6 +377,7 @@ def _vsr_call(rows, cols, vals, row_base, x, scales=None, *, m, win, tile_n,
                           quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles, win, n_pad), jnp.float32),
+        name="repro_vsr_spill",
         interpret=interpret,
     )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(vals),
       column_blocks(x, tile_n))
@@ -421,7 +428,8 @@ def spmm_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
                         bal.rows, bal.cols, bal.vals, xp, scales,
                         m=bal.shape[0], wb=wb, tile_n=tile_n,
                         interpret=interpret)
-    y = y[:, :n].astype(x2.dtype)
+    with jax.named_scope("repro.spmm.unpad"):
+        y = y[:, :n].astype(x2.dtype)
     return y[:, 0] if x.ndim == 1 else y
 
 
@@ -543,5 +551,59 @@ def _pallas_nb(bal: BalancedCOO, x: jax.Array, scales: jax.Array | None = None,
                     **({} if tile_n is None else {"tile_n": tile_n}))
 
 
-registry.register("nb_pr", "pallas", "balanced", _pallas_nb, prep=_prep_windows)
-registry.register("nb_sr", "pallas", "balanced", _pallas_nb, prep=_prep_windows)
+def _nb_work(bal: BalancedCOO, x: jax.Array, *,
+             visit_tile: jax.Array | None = None,
+             visit_block: jax.Array | None = None,
+             wb: int | None = None, tile_n: int | None = None,
+             spill: bool = False, **_) -> tuple[str, dict]:
+    """The per-call work of the kernel ``_pallas_nb`` binds, counted from
+    the visit schedule and the operands of the call (host-side).
+
+    ``visits``: grid steps along the schedule; ``tile_loads``: runs of
+    equal ``visit_tile`` (``new_tile`` gathers on each), one per tile on
+    the spill path; ``dummy_visits``: visits whose tile has no row in their
+    block (empty output blocks; counted where the row stream is concrete
+    and row-ordered, as ``csr_to_balanced`` builds it); ``rows_gathered``:
+    for N > 1 the DMA row gather's ``tile_loads × T × column blocks``, for
+    N = 1 the ``cols.size`` elements XLA gathers from ``x``
+    (``spmv._gathered``)."""
+    n_tiles, t = bal.cols.shape
+    n = 1 if x.ndim == 1 else x.shape[1]
+    m = bal.shape[0]
+    if visit_tile is None or spill:
+        name = "repro_spmv_spill" if x.ndim == 1 else "repro_vsr_spill"
+        visits = tile_loads = n_tiles
+        dummy = 0
+    else:
+        name = "repro_spmv_fused" if x.ndim == 1 else "repro_vsr_fused"
+        vt = np.asarray(visit_tile)
+        vb = np.asarray(visit_block).astype(np.int64)
+        visits = len(vt)
+        tile_loads = int(visits > 0) + int(np.count_nonzero(vt[1:] != vt[:-1]))
+        dummy = None
+        if not isinstance(bal.rows, jax.core.Tracer):
+            wb = TileGeometry().wb if wb is None else wb
+            rf = np.asarray(bal.rows).reshape(-1)
+            # queries in the stream's dtype: a mixed-dtype search would
+            # copy the whole row stream to the wider type
+            lo = np.searchsorted(rf, (vb * wb).astype(rf.dtype))
+            hi = np.searchsorted(rf, np.minimum((vb + 1) * wb, m)
+                                 .astype(rf.dtype))
+            start = vt.astype(np.int64) * t
+            hits = np.minimum(hi, start + t) - np.maximum(lo, start)
+            dummy = int(np.count_nonzero(hits <= 0))
+    if x.ndim == 1:
+        rows = n_tiles * t
+    else:
+        tile_n = TileGeometry().tile_n if tile_n is None else tile_n
+        rows = tile_loads * t * -(-n // tile_n)
+    work = {"visits": visits, "tile_loads": tile_loads, "rows_gathered": rows}
+    if dummy is not None:
+        work["dummy_visits"] = dummy
+    return name, work
+
+
+registry.register("nb_pr", "pallas", "balanced", _pallas_nb, prep=_prep_windows,
+                  work=_nb_work)
+registry.register("nb_sr", "pallas", "balanced", _pallas_nb, prep=_prep_windows,
+                  work=_nb_work)

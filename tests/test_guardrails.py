@@ -551,7 +551,11 @@ def test_health_api_surface():
     api.configure_guardrails(threshold=1, cooldown_s=0.0)
     assert G.HEALTH.breaker("pallas", "nb_pr").threshold == 1
     api.reset_health()
-    assert api.health() == {"counters": {}, "breakers": {}}
+    assert api.health() == {
+        "counters": {}, "breakers": {}, "spans": {}, "work": {},
+        "compile": {"count": 0, "seconds": 0.0, "cache_hits": 0,
+                    "cache_misses": 0, "retrieval_s": 0.0,
+                    "by_function": {}}}
 
 
 def test_health_summary_shape():

@@ -1,0 +1,188 @@
+"""Spans, compile counters and per-call work in ``repro.api.health()``
+(DESIGN.md §12): the records the program keeps of its own planning, of
+XLA's compilations and of what each bound kernel does per call."""
+import glob
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from repro import api
+from repro.core import csr_from_coo, rmat
+from repro.core import guardrails as G
+from repro.core.plan import plan
+from repro.kernels.vsr import plan_visits
+
+
+@pytest.fixture(autouse=True)
+def _fresh_health():
+    api.reset_health()
+    yield
+    api.reset_health()
+
+
+def test_span_nesting_self_time_and_parent():
+    with G.HEALTH.span("outer"):
+        time.sleep(0.02)
+        with G.HEALTH.span("inner", kind="a"):
+            time.sleep(0.03)
+        with G.HEALTH.span("inner", kind="b"):
+            pass
+    spans = api.health()["spans"]
+    outer, inner = spans["outer"], spans["inner"]
+    assert outer["count"] == 1 and inner["count"] == 2
+    assert outer["parents"] == {} and inner["parents"] == {"outer": 2}
+    assert inner["args"] == {"kind=a": 1, "kind=b": 1}
+    assert inner["self_s"] == pytest.approx(inner["total_s"])
+    assert outer["self_s"] == pytest.approx(
+        outer["total_s"] - inner["total_s"], abs=1e-9)
+    assert outer["self_s"] >= 0.02 and inner["total_s"] >= 0.03
+
+
+def test_span_records_through_an_exception_and_as_a_decorator():
+    @G.HEALTH.span("decorated")
+    def fails():
+        raise ValueError("boom")
+
+    for _ in range(2):
+        with pytest.raises(ValueError):
+            fails()
+    assert api.health()["spans"]["decorated"]["count"] == 2
+    with G.HEALTH.span("after"):
+        pass
+    assert api.health()["spans"]["after"]["parents"] == {}
+
+
+def test_plan_span_lands_in_a_profiler_trace(tmp_path):
+    """``repro.plan`` is a host annotation on the trace's clock: it covers
+    its ``repro.plan.stats`` child and ends before the jitted call that
+    follows it starts."""
+    from jax.profiler import ProfileData
+    f = jax.jit(lambda x: x * 2.0)
+    x = jnp.ones(8)
+    f(x).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        plan(rmat(8, 4, seed=1))
+        with jax.profiler.TraceAnnotation("call"):
+            f(x).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(tmp_path, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    ev = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                for e in line.events:
+                    ev.setdefault(e.name, (e.start_ns,
+                                           e.start_ns + e.duration_ns))
+    (p0, p1), (s0, s1), (c0, _) = (ev["repro.plan"], ev["repro.plan.stats"],
+                                   ev["call"])
+    assert p0 <= s0 <= s1 <= p1 <= c0
+
+
+def test_planning_and_tracing_record_their_spans():
+    A = api.sparse(rmat(9, 4, seed=3), backend="pallas", cache=False)
+    jax.jit(lambda x: A @ x).lower(
+        jax.ShapeDtypeStruct((A.shape[1], 16), jnp.float32))
+    spans = api.health()["spans"]
+    for name in ("repro.plan", "repro.plan.stats", "repro.plan.substrate",
+                 "repro.plan.prep", "repro.plan.windows", "repro.plan.visits",
+                 "repro.plan.fingerprint", "repro.execute.trace"):
+        assert spans[name]["count"] >= 1, name
+    assert spans["repro.plan.substrate"]["args"] == {"kind=balanced": 1}
+    assert spans["repro.plan.substrate"]["parents"] == {
+        "repro.execute.trace": 1}
+    assert spans["repro.plan.visits"]["parents"] == {"repro.plan.prep": 1}
+    assert spans["repro.plan.prep"]["parents"] == {"repro.execute.trace": 1}
+
+
+def _banded(m=640, k=512, seed=0):
+    """Rows in bands with empty 64-row blocks between them, one long row:
+    block crossings, shared tiles and dummy visits all occur."""
+    rng = np.random.default_rng(seed)
+    rows, cols = [], []
+    for r in range(m):
+        if (r // 64) % 3 == 1:
+            continue                        # an empty band of output blocks
+        n = 300 if r == 5 else int(rng.integers(1, 9))
+        rows += [r] * n
+        cols += list(rng.choice(k, n, replace=False))
+    return csr_from_coo(np.array(rows), np.array(cols),
+                        rng.standard_normal(len(rows)).astype(np.float32),
+                        (m, k))
+
+
+def _independent_count(bal, wb, n, tile_n):
+    """Visits, dummy visits, tile loads and gathered rows from the
+    ``plan_visits`` schedule and the tiles' rows, by direct iteration."""
+    vt, vb, _ = plan_visits(bal, wb)
+    rows = np.asarray(bal.rows)
+    m = bal.shape[0]
+    dummy = sum(1 for t, b in zip(vt, vb)
+                if not np.any((rows[t] < m) & (rows[t] // wb == b)))
+    loads = sum(1 for v in range(len(vt)) if v == 0 or vt[v] != vt[v - 1])
+    t = rows.shape[1]
+    gathered = rows.size if n == 1 else loads * t * -(-n // tile_n)
+    return {"visits": len(vt), "dummy_visits": dummy, "tile_loads": loads,
+            "rows_gathered": gathered}
+
+
+@pytest.mark.parametrize("csr", ["rmat", "banded"])
+@pytest.mark.parametrize("n", [1, 128])
+def test_work_matches_the_visit_schedule(csr, n):
+    csr = rmat(10, 8, seed=5) if csr == "rmat" else _banded()
+    A = api.sparse(csr, backend="pallas", cache=False)
+    shape = (A.shape[1],) if n == 1 else (A.shape[1], n)
+    jax.jit(lambda x: A @ x).lower(jax.ShapeDtypeStruct(shape, jnp.float32))
+    p = A.plan
+    opts = p.kernel_opts(p.entry(p.select(n)))
+    (key, rec), = api.health()["work"].items()
+    kernel = "repro_spmv_fused" if n == 1 else "repro_vsr_fused"
+    assert key == f"{kernel}:{p.topology_key()[:12]}:n{n}"
+    assert rec["kernel"] == kernel and rec["n"] == n and rec["binds"] == 1
+    want = _independent_count(p.substrate("balanced"), opts["wb"], n,
+                              opts["tile_n"])
+    assert {k: rec[k] for k in want} == want
+    if n == 128:                        # one 128-wide column block
+        assert want["rows_gathered"] == want["tile_loads"] * p.tile
+
+
+def test_banded_graph_has_dummy_visits():
+    A = api.sparse(_banded(), backend="pallas", cache=False)
+    bal = A.plan.substrate("balanced")
+    want = _independent_count(bal, 64, 128, 128)
+    assert want["dummy_visits"] > 0
+    assert want["tile_loads"] < want["visits"]
+
+
+def test_a_fresh_jit_bumps_the_compile_record():
+    x = jnp.arange(5.0).block_until_ready()
+    before = api.health()["compile"]
+
+    def triple(x):
+        return x * 3.0 + 1.0
+
+    jax.jit(triple)(x).block_until_ready()
+    after = api.health()["compile"]
+    assert after["count"] == before["count"] + 1
+    assert after["seconds"] > before["seconds"]
+    assert after["by_function"]["jit(triple)"]["count"] == 1
+
+
+def test_reset_clears_spans_compile_and_work():
+    A = api.sparse(rmat(8, 4, seed=2), backend="pallas", cache=False)
+    jax.jit(lambda x: A @ x)(jnp.ones((A.shape[1], 8))).block_until_ready()
+    h = api.health()
+    assert h["spans"] and h["work"] and h["compile"]["count"] >= 1
+    api.reset_health()
+    h = api.health()
+    assert h["spans"] == {} and h["work"] == {}
+    assert h["compile"] == {"count": 0, "seconds": 0.0, "cache_hits": 0,
+                            "cache_misses": 0, "retrieval_s": 0.0,
+                            "by_function": {}}

@@ -9,8 +9,13 @@ the Pallas backend and compiles the registered kernel with
 
 The topology is described inside a fixture (never at import): only one
 process may load the TPU library, and pytest-xdist workers import every
-test file.
+test file.  Each compile also shows the stable ``name=`` of the kernels it
+holds (``repro_<kernel>``) as HLO instruction names, which is how a device
+trace names them.
 """
+import re
+from pathlib import Path
+
 import numpy as np
 import pytest
 
@@ -60,10 +65,15 @@ def _spec(a, sharding):
     return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
 
 
-def _compile(fn, args, sharding):
+def _compile(fn, args, sharding, names=()):
+    """Compile ``fn`` for ``sharding``'s device; each of ``names`` must name
+    a custom call of the compiled program."""
     specs = jax.tree_util.tree_map(lambda a: _spec(a, sharding), args)
     compiled = jax.jit(fn).lower(*specs).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for name in names:
+        assert re.search(rf"%{name}(\.\d+)? = .*tpu_custom_call", text), name
     return compiled
 
 
@@ -72,15 +82,15 @@ def _split_opts(opts):
     return arrays, {k: v for k, v in opts.items() if k not in arrays}
 
 
-def _compile_matmul(p, impl, x, sharding):
+def _compile_matmul(p, impl, x, sharding, name, **kw):
     entry = p.entry(impl)
-    assert entry.backend == "pallas"
     arrays, statics = _split_opts(p.kernel_opts(entry))
 
     def run(sub, arrays, x):
-        return entry.fn(sub, x, interpret=False, **statics, **arrays)
+        return entry.fn(sub, x, interpret=False, **statics, **arrays, **kw)
 
-    return _compile(run, (p.substrate(entry.substrate), arrays, x), sharding)
+    return _compile(run, (p.substrate(entry.substrate), arrays, x), sharding,
+                    (name,))
 
 
 @pytest.mark.parametrize("impl,n", [("nb_pr", 128), ("rs_pr", 128),
@@ -88,7 +98,38 @@ def _compile_matmul(p, impl, x, sharding):
 def test_spmm_compiles(no_cache, one_chip, graph, impl, n):
     k = graph.shape[1]
     x = jax.ShapeDtypeStruct((k, n) if n > 1 else (k,), jnp.float32)
-    _compile_matmul(graph.plan, impl, x, one_chip)
+    assert graph.plan.entry(impl).backend == "pallas"
+    _compile_matmul(graph.plan, impl, x, one_chip,
+                    "repro_vsr_fused" if n > 1 else "repro_spmv_fused")
+
+
+@pytest.mark.parametrize("n", [128, 1])
+def test_spill_kernels_compile(no_cache, one_chip, graph, n):
+    """The spill-and-combine parity path of the nnz-balanced kernels."""
+    k = graph.shape[1]
+    x = jax.ShapeDtypeStruct((k, n) if n > 1 else (k,), jnp.float32)
+    _compile_matmul(graph.plan, "nb_pr", x, one_chip,
+                    "repro_vsr_spill" if n > 1 else "repro_spmv_spill",
+                    spill=True)
+
+
+def test_bsr_compiles(no_cache, one_chip):
+    p = api.sparse(rmat(10, 4, seed=1), backend="bsr").plan
+    x = jax.ShapeDtypeStruct((p.csr.shape[1], 128), jnp.float32)
+    _compile_matmul(p, "nb_pr", x, one_chip, "repro_bsr")
+
+
+def test_kernel_names_are_unique():
+    """Every ``pallas_call`` of ``repro.kernels`` passes a ``name=`` of its
+    own, so a trace can tell the kernels apart."""
+    src = Path(api.__file__).parent / "kernels"
+    calls, names = 0, []
+    for path in sorted(src.glob("*.py")):
+        text = path.read_text()
+        calls += text.count("pl.pallas_call(")
+        names += re.findall(r'name="(repro_\w+)"', text)
+    assert calls == len(names) == 10
+    assert len(set(names)) == len(names), names
 
 
 def _chain_operands(p, logical):
@@ -107,7 +148,7 @@ def test_sddmm_compiles(no_cache, one_chip, graph):
     def run(rows, cols, a, b):
         return entry.fn(rows, cols, a, b, interpret=False, shape=(m, k))
 
-    _compile(run, (bal.rows, bal.cols, a, b), one_chip)
+    _compile(run, (bal.rows, bal.cols, a, b), one_chip, ("repro_sddmm",))
 
 
 def test_fused_chain_compiles(no_cache, one_chip, graph):
@@ -122,7 +163,8 @@ def test_fused_chain_compiles(no_cache, one_chip, graph):
                         transform="softmax", alpha=0.125, **statics,
                         **arrays)
 
-    _compile(run, (bal.rows, bal.cols, a, b, x, arrays), one_chip)
+    _compile(run, (bal.rows, bal.cols, a, b, x, arrays), one_chip,
+             ("repro_chain_stats", "repro_chain_apply"))
 
 
 def test_block_sparse_attention_compiles(no_cache, one_chip):
@@ -138,7 +180,8 @@ def test_block_sparse_attention_compiles(no_cache, one_chip):
                         **arrays)
 
     bias = jax.ShapeDtypeStruct(bal.rows.shape, jnp.float32)
-    _compile(run, (bal.rows, bal.cols, q, q, bias, q, arrays), one_chip)
+    _compile(run, (bal.rows, bal.cols, q, q, bias, q, arrays), one_chip,
+             ("repro_attn_stats", "repro_attn_apply"))
 
 
 def test_sparse_ffn_spmm_compiles(no_cache, one_chip):
@@ -151,7 +194,7 @@ def test_sparse_ffn_spmm_compiles(no_cache, one_chip):
                        np.ones(len(flat), np.float32), (m, k))
     p = api.sparse(csr, backend="pallas", tile=tile).plan
     x = jax.ShapeDtypeStruct((k, tokens), jnp.bfloat16)
-    _compile_matmul(p, "nb_pr", x, one_chip)
+    _compile_matmul(p, "nb_pr", x, one_chip, "repro_vsr_fused")
 
 
 def test_execute_pattern_prep_is_traceable(no_cache, one_chip):
@@ -170,4 +213,4 @@ def test_execute_pattern_prep_is_traceable(no_cache, one_chip):
                                   prep=dict(arrays, **statics))
 
     _compile(run, (bal.rows, bal.cols, bal.vals.reshape(-1), x, arrays),
-             one_chip)
+             one_chip, ("repro_vsr_fused",))
