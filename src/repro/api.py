@@ -481,6 +481,7 @@ def health() -> dict:
     seconds).  ``work``: per ``<kernel>:<topology[:12]>:n<N>``, the
     per-call work of the kernel ``execute`` bound, counted from its visit
     schedule and operands — ``visits``, ``dummy_visits``, ``tile_loads``,
+    ``prefetched_loads`` (the fused SpMM's double-buffered gather),
     ``rows_gathered``, with ``kernel``, ``topology``, ``n`` and ``binds``
     (times ``execute`` bound it)."""
     return HEALTH.snapshot()

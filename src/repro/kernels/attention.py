@@ -104,7 +104,7 @@ def _attn_kernel(vt_ref, vb_ref, vs_ref, rows_ref, rows_s, cols_s, q_hbm,
     def _():
         start_row_gather(x_hbm.at[j], cols_s, xg, sems.at[2])
         gather_endpoints(rows_s, cols_s, q_hbm, k_hbm, qg, kg, sems, m)
-        wait_row_gather(x_hbm.at[j], xg, sems.at[2])
+        wait_row_gather(xg, sems.at[2])
 
     # attention weight in register — the score never leaves VMEM
     z = scale * tile_scores(qg, kg) + bias_ref[...].astype(jnp.float32)

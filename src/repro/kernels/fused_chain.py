@@ -77,8 +77,8 @@ def gather_endpoints(rows_s, cols_s, a_hbm, b_hbm, ag, bg, sems, m):
     downstream)."""
     start_row_gather(a_hbm, rows_s, ag, sems.at[0], clamp=m - 1)
     start_row_gather(b_hbm, cols_s, bg, sems.at[1])
-    wait_row_gather(a_hbm, ag, sems.at[0])
-    wait_row_gather(b_hbm, bg, sems.at[1])
+    wait_row_gather(ag, sems.at[0])
+    wait_row_gather(bg, sems.at[1])
 
 
 def tile_scores(ag, bg):
@@ -264,7 +264,7 @@ def _chain_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, tile_n, transform,
     def _():
         start_row_gather(x_hbm.at[j], cols_s, xg, sems.at[2])
         gather_endpoints(rows_s, cols_s, a_hbm, b_hbm, ag, bg, sems, m)
-        wait_row_gather(x_hbm.at[j], xg, sems.at[2])
+        wait_row_gather(xg, sems.at[2])
 
     e = tile_scores(ag, bg)
 

@@ -32,7 +32,13 @@ TPU adaptation (see DESIGN.md §2):
     the dense row its column id names into a ``(T, tile_n)`` VMEM scratch —
     one logical load covers all N output columns (the V→N limit of float4).
     The column ids ride an SMEM block so the scalar core can address the
-    copies; all ``T`` copies are in flight before the first wait.
+    copies; all ``T`` copies are in flight before one wait retires them (a
+    DMA semaphore counts bytes, so a descriptor the size of the scratch
+    covers every row copy).  The fused kernel double-buffers the gather:
+    two ``(T, tile_n)`` slots, and on a tile's first visit it starts the
+    next tile's copies (their column ids come in a second SMEM block, one
+    tile ahead) before it waits on its own, which the previous tile
+    started, so the copies fly while the tile reduces.
 
 Layout: the index/value streams are ``(n_tiles, 1, T)`` so one tile is a
 legal ``(1, T)`` block; T is kept a multiple of 128 (lane width),
@@ -147,9 +153,10 @@ def plan_visits(bal: BalancedCOO, wb: int
 # shared kernel pieces: dense-row loading, segment one-hot, block accumulation
 # ---------------------------------------------------------------------------
 
-def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None):
-    """Start the DMA row gather ``dst[i] = src[idx[i]]`` for every row ``i``
-    of the VMEM scratch ``dst`` (``(T, w)``; ``src`` is ``(rows, w)``).
+def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None, row0=0):
+    """Start the DMA row gather ``dst[row0 + i] = src[idx[i]]`` for each of
+    the ``T`` ids ``i`` of ``idx_ref`` into the VMEM scratch ``dst``
+    (``(rows, w)``; ``src`` is ``(rows, w)``).
 
     This is the TPU form of the paper's vectorized dense-row loading (VDL):
     ``src`` stays in HBM (``hbm_spec``) whatever its row count, and each
@@ -158,31 +165,27 @@ def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None):
     tiled HBM layout takes single-row slices only at full width — column
     blocks come from ``column_blocks``).  ``idx_ref`` is the tile's index
     block in SMEM (``(1, T)``); ``clamp`` caps sentinel ids (padding rows of
-    a row-indexed gather) to a valid row."""
-    t = dst.shape[0]
+    a row-indexed gather) to a valid row; ``row0`` offsets the rows written
+    (one slot of a multi-tile scratch)."""
 
     def start(i, carry):
         r = idx_ref[0, i]
         if clamp is not None:
             r = jnp.minimum(r, clamp)
-        pltpu.make_async_copy(src_hbm.at[pl.ds(r, 1)], dst.at[pl.ds(i, 1)],
-                              sem).start()
+        pltpu.make_async_copy(src_hbm.at[pl.ds(r, 1)],
+                              dst.at[pl.ds(row0 + i, 1)], sem).start()
         return carry
 
-    jax.lax.fori_loop(0, t, start, 0)
+    jax.lax.fori_loop(0, idx_ref.shape[-1], start, 0)
 
 
-def wait_row_gather(src_hbm, dst, sem):
+def wait_row_gather(dst, sem):
     """Wait for the ``T`` row copies ``start_row_gather`` put in flight on
-    ``sem`` (each wait retires one row-sized copy)."""
-    t = dst.shape[0]
-
-    def wait(i, carry):
-        pltpu.make_async_copy(src_hbm.at[pl.ds(0, 1)], dst.at[pl.ds(0, 1)],
-                              sem).wait()
-        return carry
-
-    jax.lax.fori_loop(0, t, wait, 0)
+    ``sem``, with one wait: a DMA semaphore counts the bytes its copies
+    land, so a descriptor the size of the whole ``(T, w)`` scratch retires
+    all ``T`` row-sized copies at once (the source of a wait-only
+    descriptor is never read)."""
+    pltpu.make_async_copy(dst, dst, sem).wait()
 
 
 def column_blocks(x: jax.Array, tile_n: int) -> jax.Array:
@@ -267,25 +270,55 @@ def _vsr_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, tile_n, quant):
     # stays int8/fp8 all the way into VMEM and is rescaled *in register* —
     # no dequantized copy ever exists in HBM (DESIGN.md §8).
     if quant:
-        sc_ref, rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
-    else:
-        rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
+        sc_ref, *refs = refs
+    (rows_ref, cols_ref, next_cols_ref, vals_ref, x_hbm, o_ref, xg, sem,
+     pending_ref) = refs
     j, v = pl.program_id(0), pl.program_id(1)
+    cur = vt_ref[v]
+    t = rows_ref.shape[-1]
 
-    # dense-row loading (VDL): the tile's X rows for this column block
-    @pl.when(new_tile(vt_ref, v))
+    # dense-row loading (VDL), double-buffered: tile ``i``'s X rows land in
+    # slot ``i % 2`` of ``xg`` (rows ``[(i % 2) T, (i % 2 + 1) T)``) while
+    # the tile before it reduces out of the other slot.
+    def gather(idx_ref, tile):
+        start_row_gather(x_hbm.at[j], idx_ref, xg, sem.at[tile % 2],
+                         row0=tile % 2 * t)
+
+    def wait(tile):
+        wait_row_gather(xg.at[pl.ds(tile % 2 * t, t)], sem.at[tile % 2])
+
+    first = new_tile(vt_ref, v)
+    # the tile whose rows are in flight (none on a sweep's first visit)
+    pending = jnp.where(v > 0, pending_ref[0], -1)
+    # prefetch the next tile, unless this sweep never visits it
+    prefetch = first & (cur + 1 <= vt_ref[pl.num_programs(1) - 1])
+
+    @pl.when(first & (pending >= 0) & (pending != cur))
+    def _():                          # the schedule skipped that tile: drain
+        wait(pending)
+
+    @pl.when(first & (pending != cur))
     def _():
-        start_row_gather(x_hbm.at[j], cols_ref, xg, sem)
-        wait_row_gather(x_hbm.at[j], xg, sem)
+        gather(cols_ref, cur)
+
+    @pl.when(prefetch)
+    def _():
+        gather(next_cols_ref, cur + 1)
+
+    @pl.when(first)
+    def _():
+        pending_ref[0] = jnp.where(prefetch, cur + 1, -1)
+        wait(cur)
 
     vals = vals_ref[...].astype(jnp.float32)              # (1, T)
     if quant:
-        vals = vals * sc_ref[vt_ref[v]]                   # in-register dequant
+        vals = vals * sc_ref[cur]                         # in-register dequant
     # segment reduction as one weighted one-hot matmul on the MXU, restricted
     # to this visit's (wb, tile_n) output block — boundary-crossing rows
     # accumulate across consecutive visits in VMEM, no partials array.
     s = segment_onehot(rows_ref[...], vals, vb_ref[v] * wb, wb, m)
-    accumulate_visit(o_ref, mxu_dot(s, xg[...]), vs_ref[v])
+    xs = xg[pl.ds(pl.multiple_of(cur % 2 * t, t), t), :]
+    accumulate_visit(o_ref, mxu_dot(s, xs), vs_ref[v])
 
 
 @functools.partial(jax.jit,
@@ -301,19 +334,25 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
     # ``*pf`` so the same index maps serve the 3- and 4-operand scalar-
     # prefetch arities (scales prepend when the stream is quantized).
     tile = lambda j, v, vt, *pf: (vt[v], 0, 0)  # noqa: E731
+    # the column ids of the tile after this visit's, for the prefetch: the
+    # pipeline fetches this block a step ahead like any other
+    next_tile = lambda j, v, vt, *pf: (  # noqa: E731
+        jnp.minimum(vt[v] + 1, n_tiles - 1), 0, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quant else 3,
         grid=(nb, n_visits),
         in_specs=[
             tile_spec(t, tile),
             tile_spec(t, tile, pltpu.SMEM),
+            tile_spec(t, next_tile, pltpu.SMEM),
             tile_spec(t, tile),
             hbm_spec(),
         ],
         out_specs=pl.BlockSpec((wb, tile_n),
                                lambda j, v, vt, vb, *pf: (vb[v], j)),
-        scratch_shapes=[pltpu.VMEM((t, tile_n), jnp.float32),
-                        pltpu.SemaphoreType.DMA(())],
+        scratch_shapes=[pltpu.VMEM((2 * t, tile_n), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
     prefetch = (vt, vb, vs, scales) if quant else (vt, vb, vs)
     out = pl.pallas_call(
@@ -323,8 +362,8 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
         name="repro_vsr_fused",
         interpret=interpret,
-    )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(vals),
-      column_blocks(x, tile_n))
+    )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(cols),
+      as_tiles(vals), column_blocks(x, tile_n))
     with jax.named_scope("repro.spmm.unpad"):
         return out[:m]
 
@@ -343,7 +382,7 @@ def _vsr_kernel(base_ref, *refs, m, win, tile_n, quant):
         rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
     i, j = pl.program_id(0), pl.program_id(1)
     start_row_gather(x_hbm.at[j], cols_ref, xg, sem)
-    wait_row_gather(x_hbm.at[j], xg, sem)
+    wait_row_gather(xg, sem)
     vals = vals_ref[...].astype(jnp.float32)
     if quant:
         vals = vals * sc_ref[i]                # in-register dequant
@@ -561,15 +600,19 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
 
     ``visits``: grid steps along the schedule; ``tile_loads``: runs of
     equal ``visit_tile`` (``new_tile`` gathers on each), one per tile on
-    the spill path; ``dummy_visits``: visits whose tile has no row in their
-    block (empty output blocks; counted where the row stream is concrete
-    and row-ordered, as ``csr_to_balanced`` builds it); ``rows_gathered``:
-    for N > 1 the DMA row gather's ``tile_loads × T × column blocks``, for
-    N = 1 the ``cols.size`` elements XLA gathers from ``x``
+    the spill path; ``prefetched_loads`` (fused SpMM only): the tile loads
+    whose rows were started while the tile before them reduced, counted
+    per column-block sweep like ``tile_loads``; ``dummy_visits``: visits
+    whose tile has no row in their block (empty output blocks; counted
+    where the row stream is concrete and row-ordered, as
+    ``csr_to_balanced`` builds it); ``rows_gathered``: for N > 1 the DMA
+    row gather's ``(tile_loads + drained prefetches) × T × column
+    blocks``, for N = 1 the ``cols.size`` elements XLA gathers from ``x``
     (``spmv._gathered``)."""
     n_tiles, t = bal.cols.shape
     n = 1 if x.ndim == 1 else x.shape[1]
     m = bal.shape[0]
+    prefetched, drained = None, 0
     if visit_tile is None or spill:
         name = "repro_spmv_spill" if x.ndim == 1 else "repro_vsr_spill"
         visits = tile_loads = n_tiles
@@ -579,7 +622,17 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
         vt = np.asarray(visit_tile)
         vb = np.asarray(visit_block).astype(np.int64)
         visits = len(vt)
-        tile_loads = int(visits > 0) + int(np.count_nonzero(vt[1:] != vt[:-1]))
+        runs = vt[np.r_[True, vt[1:] != vt[:-1]]] if visits else vt
+        tile_loads = len(runs)
+        if x.ndim > 1:
+            # as ``_vsr_fused_kernel`` runs: each tile load starts tile
+            # i + 1 unless the sweep's last visit is on an earlier tile;
+            # the next load uses it, or drains it when the schedule skips
+            # that tile
+            started = runs[:-1] + 1 <= vt[-1:]
+            used = started & (runs[1:] == runs[:-1] + 1)
+            prefetched = int(np.count_nonzero(used))
+            drained = int(np.count_nonzero(started & ~used))
         dummy = None
         if not isinstance(bal.rows, jax.core.Tracer):
             wb = TileGeometry().wb if wb is None else wb
@@ -596,8 +649,10 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
         rows = n_tiles * t
     else:
         tile_n = TileGeometry().tile_n if tile_n is None else tile_n
-        rows = tile_loads * t * -(-n // tile_n)
+        rows = (tile_loads + drained) * t * -(-n // tile_n)
     work = {"visits": visits, "tile_loads": tile_loads, "rows_gathered": rows}
+    if prefetched is not None:
+        work["prefetched_loads"] = prefetched
     if dummy is not None:
         work["dummy_visits"] = dummy
     return name, work

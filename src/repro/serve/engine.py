@@ -209,8 +209,10 @@ class PlanPrep:
     def wait(self, timeout: float = 0.05) -> None:
         """Block briefly on any in-flight build (the engine calls this when a
         tick decoded nothing — spinning would burn ``max_ticks`` in
-        microseconds while a build compiles)."""
-        futs = [f for f, _, _, _ in self._pending.values()]
+        microseconds while a build compiles).  A finished build no tick has
+        polled yet (a key the batch topology moved past) is not in flight:
+        waiting on it would return at once, every tick."""
+        futs = [f for f, _, _, _ in self._pending.values() if not f.done()]
         if futs:
             concurrent.futures.wait(
                 futs, timeout=timeout,

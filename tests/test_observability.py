@@ -12,10 +12,11 @@ import jax
 import jax.numpy as jnp
 
 from repro import api
-from repro.core import csr_from_coo, rmat
+from repro.core import csr_from_coo, csr_to_balanced, rmat
 from repro.core import guardrails as G
+from repro.core.formats import BalancedCOO
 from repro.core.plan import plan
-from repro.kernels.vsr import plan_visits
+from repro.kernels.vsr import _nb_work, plan_visits
 
 
 @pytest.fixture(autouse=True)
@@ -118,9 +119,28 @@ def _banded(m=640, k=512, seed=0):
                         (m, k))
 
 
+def _prefetches(vt):
+    """``(used, drained)``: one sweep of the fused SpMM kernel's
+    double-buffered gather, walked visit by visit — each new tile starts
+    tile + 1 unless no later visit names a tile that far on."""
+    used = drained = 0
+    pending = None
+    for v in range(len(vt)):
+        if v and vt[v] == vt[v - 1]:
+            continue
+        if pending == vt[v]:
+            used += 1
+        elif pending is not None:
+            drained += 1
+        pending = vt[v] + 1 if vt[v] + 1 <= vt[-1] else None
+    assert pending is None                  # nothing in flight at the end
+    return used, drained
+
+
 def _independent_count(bal, wb, n, tile_n):
-    """Visits, dummy visits, tile loads and gathered rows from the
-    ``plan_visits`` schedule and the tiles' rows, by direct iteration."""
+    """Visits, dummy visits, tile loads, prefetched loads (N > 1) and
+    gathered rows from the ``plan_visits`` schedule and the tiles' rows,
+    by direct iteration."""
     vt, vb, _ = plan_visits(bal, wb)
     rows = np.asarray(bal.rows)
     m = bal.shape[0]
@@ -128,9 +148,12 @@ def _independent_count(bal, wb, n, tile_n):
                 if not np.any((rows[t] < m) & (rows[t] // wb == b)))
     loads = sum(1 for v in range(len(vt)) if v == 0 or vt[v] != vt[v - 1])
     t = rows.shape[1]
-    gathered = rows.size if n == 1 else loads * t * -(-n // tile_n)
-    return {"visits": len(vt), "dummy_visits": dummy, "tile_loads": loads,
-            "rows_gathered": gathered}
+    count = {"visits": len(vt), "dummy_visits": dummy, "tile_loads": loads}
+    if n == 1:
+        return dict(count, rows_gathered=rows.size)
+    used, drained = _prefetches(vt)
+    return dict(count, prefetched_loads=used,
+                rows_gathered=(loads + drained) * t * -(-n // tile_n))
 
 
 @pytest.mark.parametrize("csr", ["rmat", "banded"])
@@ -151,6 +174,35 @@ def test_work_matches_the_visit_schedule(csr, n):
     assert {k: rec[k] for k in want} == want
     if n == 128:                        # one 128-wide column block
         assert want["rows_gathered"] == want["tile_loads"] * p.tile
+        # a row-ordered stream visits every tile: each load but the
+        # sweep's first was prefetched, and no prefetch was drained
+        assert rec["prefetched_loads"] == rec["tile_loads"] - 1
+    else:
+        assert "prefetched_loads" not in rec
+
+
+def test_work_counts_a_drained_prefetch():
+    """An all-sentinel tile no visit names: the copies started for it are
+    drained, gathered all the same, and the load after it is not a
+    prefetched one."""
+    bal = csr_to_balanced(rmat(8, 8, seed=5), tile=64)
+    m, t = bal.shape[0], bal.rows.shape[1]
+
+    def skip(a, fill):
+        return jnp.concatenate([a[:3], jnp.full((1, t), fill, a.dtype),
+                                a[3:]])
+
+    bal = BalancedCOO(skip(bal.rows, m), skip(bal.cols, 0),
+                      skip(bal.vals, 0), bal.shape)
+    vt, vb, _ = plan_visits(bal, 16)
+    assert 3 not in vt
+    name, work = _nb_work(bal, jax.ShapeDtypeStruct((m, 256), jnp.float32),
+                          visit_tile=vt, visit_block=vb, wb=16, tile_n=128)
+    loads = work["tile_loads"]
+    assert name == "repro_vsr_fused"
+    assert _prefetches(vt) == (loads - 2, 1)
+    assert work["prefetched_loads"] == loads - 1 - 1   # less the drain
+    assert work["rows_gathered"] == (loads + 1) * t * 2  # two sweeps
 
 
 def test_banded_graph_has_dummy_visits():

@@ -13,10 +13,15 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax.experimental.pallas import tpu as pltpu
+
 from repro.core import (SelectorThresholds, TileGeometry, csr_from_dense,
                         csr_to_balanced, csr_to_ell, execute, geometry_key,
                         plan, rmat, spmm_rs_pr)
 from repro.core.cache import PlanCache, cached_plan, pattern_fingerprint
+from repro.core.formats import BalancedCOO
+from repro.core.quant import dequantize_stream, quantize_stream
+from repro.core.shard import VISIT_PAD, stack_visit_schedules
 from repro.kernels.spmv import spmv_vsr, spmv_vsr_fused
 from repro.kernels.vsr import (plan_visits, plan_windows, spmm_vsr,
                                spmm_vsr_fused)
@@ -40,7 +45,7 @@ def _cases(rng):
     return cases
 
 
-@pytest.mark.parametrize("n", [1, 7, 128, 300])
+@pytest.mark.parametrize("n", [1, 7, 128, 300, 256])
 def test_fused_matches_spill_and_xla(rng, n):
     for name, csr, a in _cases(rng):
         bal = csr_to_balanced(csr, tile=64)
@@ -126,6 +131,134 @@ def test_fused_grads_empty_rows_under_jit(rng):
     gv, gx = grad_fn(csr.data, x)
     np.testing.assert_allclose(np.asarray(gv), np.asarray(gd_v), atol=2e-3)
     np.testing.assert_allclose(np.asarray(gx), np.asarray(gd_x), atol=2e-3)
+
+
+# ---------------------------------------------------------------------------
+# the double-buffered row gather of repro_vsr_fused
+# ---------------------------------------------------------------------------
+
+def _dma_interpret(mode):
+    """The TPU interpreter over VMEM that reads NaN until written, carrying
+    out each DMA as it starts (``eager``) or only once a wait asks for it
+    (``on_wait``).  ``on_wait`` shows a tile reduced before its row copies
+    were waited on; ``eager`` shows a prefetch landing in the slot still
+    being reduced.  Plain interpret mode shows neither."""
+    return pltpu.InterpretParams(dma_execution_mode=mode,
+                                 uninitialized_memory="nan")
+
+
+def _skip_tile(bal, at):
+    """``bal`` with an all-sentinel tile put before tile ``at``: no visit
+    names it, so the copies prefetched for it are drained unused."""
+    m, t = bal.shape[0], bal.rows.shape[1]
+
+    def put(a, fill):
+        return jnp.concatenate([a[:at], jnp.full((1, t), fill, a.dtype),
+                                a[at:]])
+
+    return BalancedCOO(put(bal.rows, m), put(bal.cols, 0), put(bal.vals, 0),
+                       bal.shape)
+
+
+def _pipeline_case(name, rng):
+    """``(csr, bal, wb)`` of a schedule that takes one branch of the
+    double-buffered gather."""
+    if name == "single_tile":                  # one tile over five blocks
+        csr, _ = random_csr(rng, 40, 40, 0.03)
+        return csr, csr_to_balanced(csr, tile=64), 8
+    if name == "block_crossings":              # tiles visit several blocks
+        csr = rmat(6, 8, seed=3)
+        return csr, csr_to_balanced(csr, tile=64), 16
+    if name == "empty_row_bands":              # dummy visits between tiles
+        a = np.zeros((96, 40), np.float32)
+        a[:20] = (rng.random((20, 40)) < 0.2) * rng.standard_normal((20, 40))
+        a[70:] = (rng.random((26, 40)) < 0.2) * rng.standard_normal((26, 40))
+        csr = csr_from_dense(a.astype(np.float32))
+        return csr, csr_to_balanced(csr, tile=32), 8
+    csr = rmat(6, 8, seed=3)                   # skipped_tile: a drain
+    return csr, _skip_tile(csr_to_balanced(csr, tile=64), 2), 16
+
+
+@pytest.mark.parametrize("mode", ["on_wait", "eager"])
+@pytest.mark.parametrize("n", [8, 256])
+@pytest.mark.parametrize("case", ["single_tile", "block_crossings",
+                                  "empty_row_bands", "skipped_tile"])
+def test_pipelined_gather_matches_spill_and_xla(rng, case, n, mode):
+    """Tile i + 1's rows land in one slot while tile i reduces out of the
+    other; N = 256 makes two column-block sweeps, each starting cold."""
+    csr, bal, wb = _pipeline_case(case, rng)
+    vt, vb, _ = plan_visits(bal, wb)
+    runs = vt[np.r_[True, vt[1:] != vt[:-1]]]
+    rows = np.asarray(csr.to_dense()).any(axis=1)
+    empty = [not rows[b * wb:(b + 1) * wb].any() for b in vb]
+    assert len(runs) < len(vt)                 # tiles revisited
+    assert (bal.n_tiles == 1) == (case == "single_tile")
+    assert any(empty) == (case == "empty_row_bands")
+    assert np.any(np.diff(runs) > 1) == (case == "skipped_tile")
+    x = jnp.asarray(rng.standard_normal((csr.shape[1], n)).astype(np.float32))
+    got = np.asarray(spmm_vsr_fused(bal, x, wb=wb,
+                                    interpret=_dma_interpret(mode)))
+    spill = np.asarray(spmm_vsr(bal, x, interpret=True))
+    xla = np.asarray(execute(plan(csr, backend="xla"), x))
+    np.testing.assert_allclose(got, spill, atol=2e-3)
+    np.testing.assert_allclose(got, xla, atol=2e-3)
+    np.testing.assert_allclose(got, np.asarray(csr.to_dense()) @ x,
+                               atol=2e-3)
+
+
+@pytest.mark.parametrize("mode", ["on_wait", "eager"])
+def test_pipelined_gather_int8_stream(rng, mode):
+    """An int8 value stream with per-tile scales, over two sweeps."""
+    csr = rmat(6, 8, seed=5)
+    bal = csr_to_balanced(csr, tile=64)
+    q, sc = quantize_stream(bal.vals, "int8")
+    qbal = BalancedCOO(bal.rows, bal.cols, q, bal.shape)
+    x = jnp.asarray(rng.standard_normal((64, 130)).astype(np.float32))
+    got = np.asarray(spmm_vsr_fused(qbal, x, wb=16, scales=sc,
+                                    interpret=_dma_interpret(mode)))
+    spill = np.asarray(spmm_vsr(qbal, x, scales=sc, interpret=True))
+    deq = BalancedCOO(bal.rows, bal.cols, dequantize_stream(q, sc),
+                      bal.shape)
+    xla = np.asarray(spmm_vsr_fused(deq, x, wb=16, interpret=True))
+    np.testing.assert_allclose(got, spill, atol=2e-3)
+    np.testing.assert_allclose(got, xla, atol=2e-3)
+
+
+def test_pipelined_gather_grads_match_dense(rng):
+    csr = rmat(5, 6, seed=7)                          # skewed 32x32
+    a = np.asarray(csr.to_dense())
+    p = plan(csr, backend="pallas", tile=32)
+    x = jnp.asarray(rng.standard_normal((32, 130)).astype(np.float32))
+    gd_v, gd_x = _dense_grads(csr, a, x)
+    ip = _dma_interpret("on_wait")
+    gv, gx = jax.grad(
+        lambda v, xx: (execute(p, xx, vals=v, impl="nb_pr",
+                               interpret=ip) ** 2).sum(),
+        argnums=(0, 1))(csr.data, x)
+    np.testing.assert_allclose(np.asarray(gv), np.asarray(gd_v), atol=2e-3)
+    np.testing.assert_allclose(np.asarray(gx), np.asarray(gd_x), atol=2e-3)
+
+
+def test_pipelined_gather_stacked_schedule(rng):
+    """A shard's schedule padded to a longer one's with ``VISIT_PAD``
+    visits, as the sharded backend stacks them: the padding repeats the
+    last tile, so it neither waits nor prefetches."""
+    long_csr = rmat(6, 8, seed=3)
+    short_csr, a = random_csr(rng, 64, 64, 0.05)
+    short = csr_to_balanced(short_csr, tile=64)
+    vt, vb, vs = stack_visit_schedules(
+        [plan_visits(csr_to_balanced(long_csr, tile=64), 16),
+         plan_visits(short, 16)])
+    assert (vs[1] == VISIT_PAD).sum() > 0
+    x = jnp.asarray(rng.standard_normal((64, 8)).astype(np.float32))
+    got = np.asarray(spmm_vsr_fused(
+        short, x, wb=16, visit_tile=jnp.asarray(vt[1]),
+        visit_block=jnp.asarray(vb[1]), visit_start=jnp.asarray(vs[1]),
+        interpret=_dma_interpret("on_wait")))
+    np.testing.assert_allclose(got, np.asarray(spmm_vsr(short, x,
+                                                        interpret=True)),
+                               atol=2e-3)
+    np.testing.assert_allclose(got, a @ np.asarray(x), atol=2e-3)
 
 
 # ---------------------------------------------------------------------------

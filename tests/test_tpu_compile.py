@@ -94,7 +94,7 @@ def _compile_matmul(p, impl, x, sharding, name, **kw):
 
 
 @pytest.mark.parametrize("impl,n", [("nb_pr", 128), ("rs_pr", 128),
-                                    ("nb_pr", 1)])
+                                    ("nb_pr", 1), ("nb_pr", 256)])
 def test_spmm_compiles(no_cache, one_chip, graph, impl, n):
     k = graph.shape[1]
     x = jax.ShapeDtypeStruct((k, n) if n > 1 else (k,), jnp.float32)
