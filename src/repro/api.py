@@ -47,7 +47,8 @@ from repro.core.guardrails import (HEALTH, NumericFault, PatternError,
                                    repair_csr, sentinel_scope, validate_csr)
 from repro.core.plan import (PlanArtifact, PlanBuildError, PlanBuilder,
                              execute, execute_attention, execute_chain,
-                             execute_pattern, execute_sddmm, plan)
+                             execute_pattern, execute_sddmm, pattern_prep,
+                             plan)
 from repro.core.registry import backend_scope, default_backend
 from repro.core.selector import (SelectorThresholds, TileGeometry,
                                  default_thresholds, geometry_key,
@@ -61,6 +62,7 @@ from repro.serve import Request, ServeEngine
 
 __all__ = [
     "SparseMatrix", "sparse", "sparse_chain", "sddmm", "pattern_matmul",
+    "pattern_prep",
     "use_backend", "use_mesh",
     "calibrate", "calibrate_backend", "autotune_geometry", "autotune_overlap",
     "autotune_quant", "autotune_chain", "autotune_attention", "cache_stats",

@@ -55,7 +55,8 @@ from .guardrails import HEALTH, NumericFault
 from .selector import (SelectorThresholds, TileGeometry, default_thresholds,
                        select_kernel)
 from .stats import MatrixStats, matrix_stats
-from .vjp import (_exec_attn, _exec_balanced, _exec_bsr,  # noqa: F401 (re-export)
+from .vjp import (FLAT_BACKWARD, PatternBackward,
+                  _exec_attn, _exec_balanced, _exec_bsr,  # noqa: F401 (re-export)
                   _exec_chain, _exec_ell, _exec_sddmm, _stream_to_balanced)
 
 
@@ -1219,6 +1220,182 @@ def execute_attention(p: PlanBuilder, q: jax.Array, k: jax.Array,
 
 # module-level bound-kernel cache for the plan-free training entry
 _PATTERN_BOUND: dict = {}
+_PATTERN_BWD: dict = {}
+
+#: prefix of the prep entries that describe the pattern's transpose
+TRANSPOSE = "transpose_"
+#: the transpose's arrays, in the order the kernel backward takes them; its
+#: visit schedule follows, under ``TRANSPOSE`` + the forward schedule's keys
+TRANSPOSE_ARRAYS = ("perm", "rows", "cols")
+
+
+@dataclasses.dataclass(frozen=True)
+class PatternWork:
+    """What the kernels of one (stacked) pattern do per call, counted on the
+    host when ``pattern_prep`` builds its schedules, and recorded in
+    ``HEALTH`` (``<kernel>:<topology[:12]>:n<N>``) when a call binds them.
+
+    ``forward`` and ``transpose``: the fused SpMM's work hook
+    (``kernels/vsr._nb_work``) over the forward and the transposed
+    schedules at one column block, summed over the stacked layers, as
+    ``(key, value)`` pairs; ``rows_gathered`` scales with the column blocks
+    of the call's N.  ``tiles``: the forward's tiles over all layers, which
+    the SDDMM of the value gradient loads once per feature block.  The
+    topologies are digests of the stacked forward and transposed index
+    streams, so the two SpMMs keep records of their own."""
+    kernel: str
+    topology: str
+    forward: tuple
+    transpose_topology: str
+    transpose: tuple
+    tiles: int
+    tile: int
+    tile_n: int
+
+    def _record(self, kernel, topology, work, n):
+        HEALTH.record_work(f"{kernel}:{topology[:12]}:n{n}",
+                           dict(work, kernel=kernel, topology=topology, n=n))
+
+    def _spmm(self, counts, n):
+        work = dict(counts)
+        work["rows_gathered"] *= -(-n // self.tile_n)
+        return work
+
+    def record_forward(self, n: int) -> None:
+        self._record(self.kernel, self.topology,
+                     self._spmm(self.forward, n), n)
+
+    def record_backward(self, n: int) -> None:
+        """The kernels of the value and input gradients: the SDDMM on the
+        forward's tiles and the SpMM over the transposed pattern."""
+        from repro.kernels.fused_chain import sddmm_work
+        kernel, work = sddmm_work(
+            jax.ShapeDtypeStruct((self.tiles, self.tile), jnp.int32),
+            jax.ShapeDtypeStruct((1, n), jnp.float32))
+        self._record(kernel, self.topology, work, n)
+        self._record(self.kernel, self.transpose_topology,
+                     self._spmm(self.transpose, n), n)
+
+
+def transpose_pattern(rows, cols, shape: tuple) -> tuple:
+    """Host side: the balanced layout of ``Aᵀ`` for a balanced pattern of
+    ``A`` (``(n_tiles, T)`` rows and cols; sentinel rows ``>= m``).
+
+    Returns ``(perm, rows_t, cols_t)``, each ``(n_tiles_t, T)`` int32: the
+    transposed stream holds the nonzeros ordered by column, then row (a
+    row-ordered stream of ``Aᵀ``, as ``plan_visits`` needs), and its
+    values are ``vals.reshape(-1)[perm]``; padding entries carry the
+    sentinel row ``k`` and point at entry 0."""
+    m, k = shape
+    r = np.asarray(rows).reshape(-1)
+    c = np.asarray(cols).reshape(-1)
+    t = np.shape(rows)[-1]
+    valid = np.flatnonzero(r < m)
+    # the stream is row-ordered, so a stable sort on the column gives
+    # (column, row) order
+    order = valid[np.argsort(c[valid], kind="stable")]
+    n_tiles = max(1, -(-len(order) // t))
+    pad = n_tiles * t - len(order)
+    perm = np.concatenate([order, np.zeros(pad, np.int64)]).astype(np.int32)
+    rows_t = np.concatenate([c[order], np.full(pad, k)]).astype(np.int32)
+    cols_t = np.concatenate([r[order], np.zeros(pad)]).astype(np.int32)
+    return (perm.reshape(n_tiles, t), rows_t.reshape(n_tiles, t),
+            cols_t.reshape(n_tiles, t))
+
+
+def pattern_prep(rows, cols, shape: tuple) -> dict:
+    """Host-side prep of a balanced pattern for ``execute_pattern(prep=...)``
+    on the Pallas backend: the fused SpMM's visit schedule (default
+    ``TileGeometry``) and the transposed pattern its backward runs on.
+
+    ``rows``/``cols``: ``(n_tiles, T)``, or ``(L, n_tiles, T)`` for ``L``
+    patterns of one shape stacked on a leading axis (the scanned layers of
+    a model): every array of the result then carries that axis too, the
+    visit schedules padded to one length with no-op visits, so a layer's
+    slice is its own prep.  Entries: ``visit_tile``, ``visit_block``,
+    ``visit_start`` (arrays), ``wb``, ``tile_n`` (the geometry), ``work``
+    (a ``PatternWork``), ``transpose_perm``, ``transpose_rows``,
+    ``transpose_cols`` and ``transpose_visit_*`` — ``transpose_pattern``
+    and the transposed pattern's schedule, built here once (span
+    ``repro.plan.transpose``) so the custom VJP never builds it.
+    Array entries are device arrays; pass them as call operands (a scan's
+    ``xs``), not as constants."""
+    from repro.kernels.vsr import _nb_work, plan_visits
+    from .shard import stack_visit_schedules
+    geom = TileGeometry()
+    m, k = (int(d) for d in shape)
+    rows_np, cols_np = np.asarray(rows), np.asarray(cols)
+    stacked = rows_np.ndim == 3
+    if not stacked:
+        rows_np, cols_np = rows_np[None], cols_np[None]
+    t = rows_np.shape[-1]
+
+    def schedule(r, c, shp):
+        bal = BalancedCOO(r, c, None, shp)
+        vt, vb, vs = plan_visits(bal, geom.wb)
+        kernel, work = _nb_work(
+            bal, jax.ShapeDtypeStruct((shp[1], geom.tile_n), jnp.float32),
+            visit_tile=vt, visit_block=vb, wb=geom.wb, tile_n=geom.tile_n)
+        return (vt, vb, vs), kernel, work
+
+    def summed(works):
+        return tuple((key, sum(w[key] for w in works)) for key in works[0])
+
+    def device(a):
+        return jnp.asarray(a if stacked else a[0])
+
+    fwd = [schedule(r, c, (m, k)) for r, c in zip(rows_np, cols_np)]
+    prep = {f"visit_{name}": device(a) for name, a in zip(
+        ("tile", "block", "start"),
+        stack_visit_schedules([s for s, _, _ in fwd]))}
+    prep.update(wb=geom.wb, tile_n=geom.tile_n)
+    with HEALTH.span("repro.plan.transpose"):
+        trans = [transpose_pattern(r, c, (m, k))
+                 for r, c in zip(rows_np, cols_np)]
+        # layers of different nnz: pad each to the most tiles with
+        # sentinel-only tiles (no visit touches them)
+        most = max(tr[0].shape[0] for tr in trans)
+        trans = {name: np.stack([np.pad(tr[i], ((0, most - len(tr[i])),
+                                                (0, 0)), constant_values=fill)
+                                 for tr in trans])
+                 for i, (name, fill) in enumerate(zip(TRANSPOSE_ARRAYS,
+                                                      (0, k, 0)))}
+    tsched = [schedule(rt, ct, (k, m))
+              for rt, ct in zip(trans["rows"], trans["cols"])]
+    for name, a in trans.items():
+        prep[TRANSPOSE + name] = device(a)
+    for name, a in zip(("tile", "block", "start"), stack_visit_schedules(
+            [s for s, _, _ in tsched])):
+        prep[f"{TRANSPOSE}visit_{name}"] = device(a)
+    prep["work"] = PatternWork(
+        kernel=fwd[0][1],
+        topology=_opts_digest({"rows": rows_np, "cols": cols_np}),
+        forward=summed([w for _, _, w in fwd]),
+        transpose_topology=_opts_digest({"rows": trans["rows"],
+                                         "cols": trans["cols"]}),
+        transpose=summed([w for _, _, w in tsched]),
+        tiles=int(np.prod(rows_np.shape[:2])), tile=t, tile_n=geom.tile_n)
+    return prep
+
+
+def _pattern_backward(entry, interpret, statics: dict, keys: tuple, work):
+    """The identity-cached ``vjp.PatternBackward`` of a Pallas-backed
+    pattern call whose prep carries the transpose: the value gradient on
+    the SDDMM of the same backend, the input gradient on the forward's own
+    kernel over the transposed pattern (bound without ``quant``: the
+    backward sees the live float values)."""
+    from .shard import _make_inner
+    key = (entry, interpret, tuple(sorted(statics.items())), keys, work)
+    kb = _PATTERN_BWD.get(key)
+    if kb is None:
+        if len(_PATTERN_BWD) >= 256:
+            _PATTERN_BWD.clear()
+        plain = {s: v for s, v in statics.items() if s != "quant"}
+        kb = _PATTERN_BWD[key] = PatternBackward(
+            len(keys), _make_inner(entry, interpret, plain, keys),
+            functools.partial(registry.resolve("sddmm", entry.backend).fn,
+                              interpret=interpret), work)
+    return kb
 
 
 def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
@@ -1238,6 +1415,14 @@ def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
     the call as (possibly traced) kernel operands, the rest bind statically.
     Without ``prep`` a traced pattern falls back to the XLA reference and
     bumps the ``demote:traced_pattern_to_xla`` health counter.
+
+    With a ``prep`` that carries the pattern's transpose (``pattern_prep``)
+    the backward runs on the kernels too: the value gradient is the SDDMM of
+    the cotangent and ``x`` on the forward's tiles, the input gradient the
+    same SpMM over the transposed pattern (``core/vjp.PatternBackward``),
+    and no intermediate holds ``nnz × N`` elements.  Any other
+    Pallas-backed call takes the flat ``_coo_bwd`` and bumps
+    ``demote:coo_bwd``.
 
     ``mesh`` (or ``backend="sharded"``) routes through the sharded backend:
     the pattern's tiles — already fixed-nnz quotas — split evenly across
@@ -1269,13 +1454,26 @@ def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
                          f"({impl!r}, {backend!r}) consumes {entry.substrate!r}")
     if prep is not None and entry.prep is not None:
         from .shard import _make_inner
-        keys = tuple(sorted(k for k, v in prep.items() if hasattr(v, "shape")))
-        statics = {k: v for k, v in prep.items() if k not in keys}
+        work = prep.get("work")
+        fwd = {k: v for k, v in prep.items()
+               if k != "work" and not k.startswith(TRANSPOSE)}
+        keys = tuple(sorted(k for k, v in fwd.items() if hasattr(v, "shape")))
+        statics = {k: v for k, v in fwd.items() if k not in keys}
         if quant is not None:
             statics["quant"] = quant
         bound = _make_inner(entry, interpret, statics, keys)
-        return _exec_balanced((bound, tuple(shape)), rows, cols,
-                              vals.reshape(-1), x, *(prep[k] for k in keys))
+        tkeys = tuple(TRANSPOSE + k for k in TRANSPOSE_ARRAYS + keys)
+        transposed = all(k in prep for k in tkeys)
+        if work is not None and x.ndim == 2:
+            work.record_forward(int(x.shape[1]))
+        extra = [prep[k] for k in keys]
+        if transposed:
+            kb = _pattern_backward(entry, interpret, statics, keys, work)
+            extra += [prep[k] for k in tkeys]
+        else:
+            kb = FLAT_BACKWARD
+        return _exec_balanced((bound, tuple(shape), kb), rows, cols,
+                              vals.reshape(-1), x, *extra)
     if entry.prep is not None and isinstance(rows, jax.core.Tracer) and not explicit:
         # scanned per-layer patterns are traced and no prep was handed in;
         # the XLA reference can always take them, so fall back rather than
@@ -1309,5 +1507,9 @@ def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
             opts["quant"] = quant
         bound = functools.partial(entry.fn, interpret=interpret, **opts)
         _PATTERN_BOUND[key] = bound
-    return _exec_balanced((bound, tuple(shape)), rows, cols,
-                          vals.reshape(-1), x)
+    static = (bound, tuple(shape))
+    if entry.backend == "pallas":
+        # no transpose was prepared for this call: the backward is the flat
+        # one, and says so on the ``demote:coo_bwd`` counter
+        static += (FLAT_BACKWARD,)
+    return _exec_balanced(static, rows, cols, vals.reshape(-1), x)

@@ -15,14 +15,16 @@ call re-traces.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from .formats import BSR, ELL, BalancedCOO
-from .guardrails import sanitize_grads
+from .guardrails import HEALTH, sanitize_grads
 
 
 def _as_2d(a):
@@ -78,8 +80,12 @@ def _exec_balanced(static, rows, cols, vals, x, *extra):
     the bound kernel (float0 cotangents) — the sharded backend threads
     per-shard prep (VSR row windows, stacked fused visit schedules) through
     here, since inside shard_map those are traced values and must not be
-    baked into the static."""
-    bound_fn, shape = static
+    baked into the static.  ``static`` is ``(bound_fn, shape)``, or for a
+    pattern call ``(bound_fn, shape, PatternBackward)``: then the first
+    ``n_fwd`` extras are the forward kernel's and the rest its transpose's."""
+    bound_fn, shape = static[:2]
+    if len(static) > 2:              # a pattern call: the forward's extras
+        extra = extra[:static[2].n_fwd]
     bal = BalancedCOO(rows, cols, vals.reshape(rows.shape), tuple(shape))
     return bound_fn(bal, x, *extra)
 
@@ -88,12 +94,59 @@ def _exec_balanced_fwd(static, rows, cols, vals, x, *extra):
     return _exec_balanced(static, rows, cols, vals, x, *extra), (rows, cols, vals, x, extra)
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class PatternBackward:
+    """The backward of a Pallas-backed pattern call (``execute_pattern``),
+    riding the balanced family's static as its third element.
+
+    With ``spmm`` and ``sddmm`` bound it runs on the kernels:
+    ``dvals = sddmm(rows, cols, G, X)`` on the forward's own tiles (sentinel
+    entries score 0), and ``dX = Aᵀ·G`` as ``spmm`` over the transposed
+    pattern, whose arrays follow the forward kernel's ``n_fwd`` extras
+    (``perm``, ``rows``, ``cols``, then its visit schedule in the forward
+    schedule's order) and whose values are the live stream permuted in
+    graph.  Without them (``FLAT_BACKWARD``: no transpose was prepared) it
+    falls to ``_coo_bwd`` and bumps ``demote:coo_bwd``.  ``work``: the
+    pattern's ``PatternWork``, recording the backward kernels' work.
+    Identity-hashed: the caller caches one per bound kernel."""
+    n_fwd: int | None
+    spmm: Callable | None = None
+    sddmm: Callable | None = None
+    work: Any = None
+
+
+#: the backward of a Pallas-backed pattern call with no transpose: every
+#: extra is the forward's, and the gradient is the flat ``_coo_bwd``
+FLAT_BACKWARD = PatternBackward(None)
+
+
+def _kernel_bwd(kb: PatternBackward, rows, cols, vals, x, g, shape, extra):
+    m, k = shape
+    perm, rows_t, cols_t, *sched = extra[kb.n_fwd:]
+    x2, _ = _as_2d(x)
+    g2, _ = _as_2d(g)
+    if kb.work is not None:
+        kb.work.record_backward(int(x2.shape[1]))
+    dvals = kb.sddmm(rows, cols, g2, x2, shape=shape)
+    vals_t = jnp.take(vals.reshape(-1), perm.reshape(-1)).reshape(rows_t.shape)
+    dx = kb.spmm(BalancedCOO(rows_t, cols_t, vals_t, (k, m)), g2, *sched)
+    dx = dx.reshape(x.shape).astype(x.dtype)
+    return sanitize_grads(dvals, dx)
+
+
 def _exec_balanced_bwd(static, res, g):
-    _, shape = static
+    shape = static[1]
+    kb = static[2] if len(static) > 2 else None
     rows, cols, vals, x, extra = res
+    from .quant import is_quantized_dtype
+    if kb is not None:
+        if kb.spmm is not None and not is_quantized_dtype(vals.dtype):
+            dvals, dx = _kernel_bwd(kb, rows, cols, vals, x, g, shape, extra)
+            return (_float0(rows), _float0(cols), _value_cot(dvals, vals),
+                    dx, *(_zero_cot(e) for e in extra))
+        HEALTH.bump("demote:coo_bwd")
     r, c = rows.reshape(-1), cols.reshape(-1)
     v = vals.reshape(-1)
-    from .quant import is_quantized_dtype
     if is_quantized_dtype(vals.dtype) and extra:
         # baked quantized stream: by convention ``extra[0]`` carries the
         # per-tile f32 dequant scales (see core/plan._run_entry and the

@@ -85,7 +85,8 @@ def tile_scores(ag, bg):
     """In-kernel SDDMM for one nnz-tile: the row-wise dots of the gathered
     endpoint rows, as lane-major ``(1, T)`` f32 scores (an NT matmul
     against ones — the row sums land lane-major, no relayout)."""
-    prod = ag[...] * bg[...]                                  # (T, d)
+    prod = ag[...] * bg[...]                         # (T, d) or (T, 1, d)
+    prod = prod.reshape(prod.shape[0], prod.shape[-1])
     ones = jnp.ones((8, prod.shape[1]), jnp.float32)
     e = jax.lax.dot_general(ones, prod, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32,
@@ -151,46 +152,98 @@ def stats_blocks(rm, rs, wb: int):
 
 
 # ---------------------------------------------------------------------------
-# standalone SDDMM: one grid step per nnz-tile, scores written tile-in-place
+# standalone SDDMM: one grid step per (nnz-tile, feature block), scores
+# accumulated tile-in-place over the feature blocks
 # ---------------------------------------------------------------------------
 
+#: bytes of VMEM the standalone SDDMM's two gathered ``(T, feature block)``
+#: slabs may take together; wider features are cut into blocks
+SDDMM_GATHER_BYTES = 4 << 20
+
+
+def sddmm_feature_block(t: int, d: int) -> int:
+    """Feature columns per block of the standalone SDDMM: all of ``d``
+    (lane-padded) when both gathered ``(t, d)`` f32 slabs fit
+    ``SDDMM_GATHER_BYTES``, else the widest multiple of 128 that does."""
+    d_pad = -(-d // 128) * 128
+    fit = max(128, SDDMM_GATHER_BYTES // (2 * 4 * t) // 128 * 128)
+    return min(d_pad, fit)
+
+
 def _sddmm_kernel(rows_s, cols_s, a_hbm, b_hbm, o_ref, ag, bg, sems, *, m):
-    gather_endpoints(rows_s, cols_s, a_hbm, b_hbm, ag, bg, sems, m)
-    o_ref[...] = tile_scores(ag, bg)
+    jd = pl.program_id(1)
+    gather_endpoints(rows_s, cols_s, a_hbm.at[jd], b_hbm.at[jd], ag, bg,
+                     sems, m)
+    e = tile_scores(ag, bg)
+
+    @pl.when(jd == 0)
+    def _():
+        o_ref[...] = e
+
+    @pl.when(jd > 0)
+    def _():
+        o_ref[...] += e
 
 
 @functools.partial(jax.jit, static_argnames=("m", "interpret"))
 def _sddmm_call(rows, cols, a, b, *, m, interpret):
     n_tiles, t = rows.shape
-    d = a.shape[1]
-    tile = lambda i: (i, 0, 0)  # noqa: E731
+    nd, _, _, dblk = a.shape
+    # feature blocks innermost: a tile's output block is revisited on
+    # consecutive grid steps, and its index blocks are not fetched again
+    tile = lambda i, jd: (i, 0, 0)  # noqa: E731
     out = pl.pallas_call(
         functools.partial(_sddmm_kernel, m=m),
-        grid=(n_tiles,),
+        grid=(n_tiles, nd),
         in_specs=[tile_spec(t, tile, pltpu.SMEM),
                   tile_spec(t, tile, pltpu.SMEM),
                   hbm_spec(),
                   hbm_spec()],
         out_specs=tile_spec(t, tile),
         out_shape=jax.ShapeDtypeStruct((n_tiles, 1, t), jnp.float32),
-        scratch_shapes=gather_scratch(t, d),
+        scratch_shapes=[pltpu.VMEM((t, 1, dblk), jnp.float32),
+                        pltpu.VMEM((t, 1, dblk), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,))],
         name="repro_sddmm",
         interpret=interpret,
     )(as_tiles(rows), as_tiles(cols), a, b)
     return jnp.where(rows < m, out.reshape(n_tiles, t), 0.0)
 
 
+def _feature_blocks(a: jax.Array, dblk: int) -> jax.Array:
+    """``(rows, d)`` → ``(nd, rows, 1, dblk)`` f32, features zero-padded to
+    ``nd * dblk``: feature block ``j`` of a row is one contiguous copy of
+    ``a[j, row]``, whatever ``dblk`` is (``start_row_gather``)."""
+    a = _pad_2d(jnp.asarray(a), row_mult=1, col_mult=dblk)
+    r, d = a.shape
+    return a.reshape(r, d // dblk, 1, dblk).transpose(1, 0, 2, 3)
+
+
 def sddmm_pallas(rows, cols, a, b, *, interpret: bool | None = None,
                  shape=None, **_opts):
     """Pallas SDDMM over a balanced slab: f32 edge scores shaped like
     ``rows`` (sentinel entries score 0).  Needs no visit schedule — scores
-    are tile-local — so it works on traced patterns with no prep hook."""
+    are tile-local — so it works on traced patterns with no prep hook.
+    Features wider than ``sddmm_feature_block`` allows are cut into blocks
+    whose partial scores add up in the tile's output block."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     m = int(shape[0])
-    ap = _pad_2d(jnp.asarray(a))
-    bp = _pad_2d(jnp.asarray(b))
-    return _sddmm_call(rows, cols, ap, bp, m=m, interpret=interpret)
+    dblk = sddmm_feature_block(rows.shape[-1], a.shape[1])
+    return _sddmm_call(rows, cols, _feature_blocks(a, dblk),
+                       _feature_blocks(b, dblk), m=m, interpret=interpret)
+
+
+def sddmm_work(rows, a) -> tuple[str, dict]:
+    """The per-call work of ``sddmm_pallas`` (host-side, from shapes):
+    ``visits`` and ``tile_loads`` are its grid steps, one gather of both
+    endpoint slabs each (tiles × feature blocks); ``rows_gathered`` the row
+    segments those gathers copy, ``2 T`` a load."""
+    n_tiles, t = rows.shape
+    d = a.shape[1]
+    loads = n_tiles * -(-d // sddmm_feature_block(t, d))
+    return "repro_sddmm", {"visits": loads, "tile_loads": loads,
+                           "rows_gathered": 2 * t * loads}
 
 
 # ---------------------------------------------------------------------------

@@ -166,14 +166,22 @@ def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None, row0=0):
     blocks come from ``column_blocks``).  ``idx_ref`` is the tile's index
     block in SMEM (``(1, T)``); ``clamp`` caps sentinel ids (padding rows of
     a row-indexed gather) to a valid row; ``row0`` offsets the rows written
-    (one slot of a multi-tile scratch)."""
+    (one slot of a multi-tile scratch).
+
+    A ``(rows, 1, w)`` ``src`` (and ``(rows, 1, w)`` ``dst``) holds each
+    row as a tile row of its own, so a row of any width ``w`` is one
+    contiguous copy (the standalone SDDMM gathers its feature rows so)."""
+    unit = len(src_hbm.shape) == 3
 
     def start(i, carry):
         r = idx_ref[0, i]
         if clamp is not None:
             r = jnp.minimum(r, clamp)
-        pltpu.make_async_copy(src_hbm.at[pl.ds(r, 1)],
-                              dst.at[pl.ds(row0 + i, 1)], sem).start()
+        if unit:
+            src, to = src_hbm.at[r], dst.at[row0 + i]
+        else:
+            src, to = src_hbm.at[pl.ds(r, 1)], dst.at[pl.ds(row0 + i, 1)]
+        pltpu.make_async_copy(src, to, sem).start()
         return carry
 
     jax.lax.fori_loop(0, idx_ref.shape[-1], start, 0)

@@ -211,9 +211,10 @@ def mlp_apply(p: dict, x: jax.Array, act: str = "swiglu") -> jax.Array:
 class SparsePattern:
     """Static (non-trainable) sparsity pattern of one pruned weight matrix,
     in BalancedCOO layout. rows/cols: (n_tiles, tile) int32.  ``prep``: the
-    pattern's precomputed kernel prep opts (the fused kernel's visit
-    schedule), so a scanned — traced — pattern still reaches the Pallas
-    kernel (``execute_pattern(prep=...)``)."""
+    pattern's precomputed kernel prep opts (``core/plan.pattern_prep``: the
+    fused kernel's visit schedule and the transposed pattern the kernel
+    backward runs on), so a scanned — traced — pattern still reaches the
+    Pallas kernels forward and backward (``execute_pattern(prep=...)``)."""
     rows: jax.Array
     cols: jax.Array
     shape: tuple

@@ -184,9 +184,13 @@ def model_specs(cfg: ModelConfig) -> dict:
 
 
 def sparse_patterns(cfg: ModelConfig, seed: int = 17):
-    """Static pruning patterns for sparse_ffn (one per layer, stacked)."""
+    """Static pruning patterns for sparse_ffn (one per layer, stacked).  Each
+    stack carries the Pallas kernels' prep for every layer, its transpose
+    included (``core/plan.pattern_prep``): scanned with the patterns, each
+    layer's slice is its own prep."""
     if cfg.sparse_ffn is None:
         return None
+    from repro.core.plan import pattern_prep
     sp = cfg.sparse_ffn
     d, f = cfg.d_model, cfg.d_ff
     keys = jax.random.split(jax.random.PRNGKey(seed), 3 * cfg.num_layers)
@@ -197,27 +201,11 @@ def sparse_patterns(cfg: ModelConfig, seed: int = 17):
         pats["down"].append(SparsePattern.random(keys[3 * i + 2], d, f, sp.density, sp.tile))
 
     def stack(ps):
-        return SparsePattern(jnp.stack([p.rows for p in ps]),
-                             jnp.stack([p.cols for p in ps]), ps[0].shape,
-                             prep=_stacked_visit_prep(ps))
+        rows = np.stack([np.asarray(p.rows) for p in ps])
+        cols = np.stack([np.asarray(p.cols) for p in ps])
+        return SparsePattern(jnp.asarray(rows), jnp.asarray(cols), ps[0].shape,
+                             prep=pattern_prep(rows, cols, ps[0].shape))
     return {k: stack(v) for k, v in pats.items()}
-
-
-def _stacked_visit_prep(ps) -> dict:
-    """The fused NB kernel's visit schedule for every layer's pattern, padded
-    to one length with no-op visits and stacked on the layer axis — scanned
-    with the patterns, each layer's slice is its own schedule."""
-    from repro.core.formats import BalancedCOO
-    from repro.core.selector import TileGeometry
-    from repro.core.shard import stack_visit_schedules
-    from repro.kernels.vsr import plan_visits
-    geom = TileGeometry()
-    scheds = [plan_visits(BalancedCOO(np.asarray(p.rows), np.asarray(p.cols),
-                                      None, p.shape), geom.wb) for p in ps]
-    vt, vb, vs = stack_visit_schedules(scheds)
-    return {"visit_tile": jnp.asarray(vt), "visit_block": jnp.asarray(vb),
-            "visit_start": jnp.asarray(vs), "wb": geom.wb,
-            "tile_n": geom.tile_n}
 
 
 # ---------------------------------------------------------------------------

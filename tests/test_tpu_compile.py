@@ -214,3 +214,43 @@ def test_execute_pattern_prep_is_traceable(no_cache, one_chip):
 
     _compile(run, (bal.rows, bal.cols, bal.vals.reshape(-1), x, arrays),
              one_chip, ("repro_vsr_fused",))
+
+
+@pytest.fixture(scope="module")
+def phi3_ffn():
+    """One pruned Phi-3-mini FFN weight (d_ff 8192 x d_model 3072 at
+    density 0.1, tiles of 512) and its prep, transpose included."""
+    m, k, tile = 8192, 3072, 512
+    rng = np.random.default_rng(0)
+    flat = np.sort(rng.choice(m * k, size=int(m * k * 0.1), replace=False))
+    n_tiles = -(-len(flat) // tile)
+    pad = n_tiles * tile - len(flat)
+    rows = np.concatenate([flat // k, np.full(pad, m)]).astype(np.int32)
+    cols = np.concatenate([flat % k, np.zeros(pad)]).astype(np.int32)
+    rows, cols = rows.reshape(n_tiles, tile), cols.reshape(n_tiles, tile)
+    return rows, cols, (m, k), api.pattern_prep(rows, cols, (m, k))
+
+
+@pytest.mark.parametrize("tokens", [1024, 4096])
+def test_sparse_ffn_backward_compiles(no_cache, one_chip, phi3_ffn, tokens):
+    """The gradient of one pruned FFN matrix at Phi-3-mini widths: the
+    value gradient on ``repro_sddmm`` (feature-blocked at 4096 tokens) and
+    the input gradient on ``repro_vsr_fused`` over the transposed pattern,
+    with no ``(nnz, tokens)`` intermediate — which could not fit the chip."""
+    rows, cols, shape, prep = phi3_ffn
+    arrays, statics = _split_opts(prep)
+    vals = jax.ShapeDtypeStruct((rows.size,), jnp.float32)
+    x = jax.ShapeDtypeStruct((shape[1], tokens), jnp.float32)
+
+    def loss(vals, x, rows, cols, arrays):
+        y = api.pattern_matmul(rows, cols, vals, shape, x, backend="pallas",
+                               interpret=False, prep=dict(arrays, **statics))
+        return jnp.sum(y * y)
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1)),
+                        (vals, x, rows, cols, arrays), one_chip,
+                        ("repro_vsr_fused", "repro_sddmm"))
+    text = compiled.as_text()
+    assert len(re.findall(r"%repro_vsr_fused(\.\d+)? = ", text)) == 2
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < rows.size * tokens * 4
