@@ -1339,7 +1339,10 @@ def pattern_prep(rows, cols, shape: tuple) -> dict:
         return (vt, vb, vs), kernel, work
 
     def summed(works):
-        return tuple((key, sum(w[key] for w in works)) for key in works[0])
+        # counts add up over the layers; the issue loop's unroll is the
+        # kernel's own
+        return tuple((key, works[0][key] if key == "gather_unroll"
+                      else sum(w[key] for w in works)) for key in works[0])
 
     def device(a):
         return jnp.asarray(a if stacked else a[0])

@@ -47,10 +47,10 @@ from repro.core.formats import BalancedCOO
 from repro.core.selector import TileGeometry
 from repro.core.spmm import SOFTMAX_EPS, SOFTMAX_NEG
 
-from .vsr import (_pad_n, _prep_windows, accumulate_visit, as_tiles,
-                  block_hits, column_blocks, hbm_spec, mxu_dot, new_tile,
-                  plan_visits, segment_onehot, start_row_gather, tile_spec,
-                  wait_row_gather)
+from .vsr import (GATHER_UNROLL, _pad_n, _prep_windows, accumulate_visit,
+                  as_tiles, block_hits, column_blocks, hbm_spec, mxu_dot,
+                  new_tile, plan_visits, segment_onehot, start_row_gather,
+                  tile_spec, wait_row_gather)
 
 #: per-row transforms the chain supports between its SDDMM and SpMM halves
 CHAIN_TRANSFORMS: tuple[str, ...] = ("identity", "scale", "softmax")
@@ -238,12 +238,14 @@ def sddmm_work(rows, a) -> tuple[str, dict]:
     """The per-call work of ``sddmm_pallas`` (host-side, from shapes):
     ``visits`` and ``tile_loads`` are its grid steps, one gather of both
     endpoint slabs each (tiles × feature blocks); ``rows_gathered`` the row
-    segments those gathers copy, ``2 T`` a load."""
+    segments those gathers copy, ``2 T`` a load; ``gather_unroll`` the row
+    copies one iteration of the gathers' issue loop starts."""
     n_tiles, t = rows.shape
     d = a.shape[1]
     loads = n_tiles * -(-d // sddmm_feature_block(t, d))
     return "repro_sddmm", {"visits": loads, "tile_loads": loads,
-                           "rows_gathered": 2 * t * loads}
+                           "rows_gathered": 2 * t * loads,
+                           "gather_unroll": GATHER_UNROLL}
 
 
 # ---------------------------------------------------------------------------

@@ -32,7 +32,8 @@ TPU adaptation (see DESIGN.md §2):
     the dense row its column id names into a ``(T, tile_n)`` VMEM scratch —
     one logical load covers all N output columns (the V→N limit of float4).
     The column ids ride an SMEM block so the scalar core can address the
-    copies; all ``T`` copies are in flight before one wait retires them (a
+    copies, ``GATHER_UNROLL`` of them an iteration of its issue loop; all
+    ``T`` copies are in flight before one wait retires them (a
     DMA semaphore counts bytes, so a descriptor the size of the scratch
     covers every row copy).  The fused kernel double-buffers the gather:
     two ``(T, tile_n)`` slots, and on a tile's first visit it starts the
@@ -153,6 +154,12 @@ def plan_visits(bal: BalancedCOO, wb: int
 # shared kernel pieces: dense-row loading, segment one-hot, block accumulation
 # ---------------------------------------------------------------------------
 
+#: row copies one iteration of ``start_row_gather``'s issue loop starts.
+#: Mosaic lowers a ``fori_loop`` only at unroll 1 or whole, so the groups
+#: are written out by hand.
+GATHER_UNROLL = 16
+
+
 def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None, row0=0):
     """Start the DMA row gather ``dst[row0 + i] = src[idx[i]]`` for each of
     the ``T`` ids ``i`` of ``idx_ref`` into the VMEM scratch ``dst``
@@ -168,23 +175,38 @@ def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None, row0=0):
     a row-indexed gather) to a valid row; ``row0`` offsets the rows written
     (one slot of a multi-tile scratch).
 
+    The scalar core issues one descriptor per copy, and that issue sets the
+    gather's pace, so the copies go out in groups of ``GATHER_UNROLL``:
+    each iteration of the issue loop reads a group's ids at static offsets
+    from its base, then starts the group's copies on ``sem``, and a static
+    tail starts the ``T % GATHER_UNROLL`` copies left over.
+
     A ``(rows, 1, w)`` ``src`` (and ``(rows, 1, w)`` ``dst``) holds each
     row as a tile row of its own, so a row of any width ``w`` is one
     contiguous copy (the standalone SDDMM gathers its feature rows so)."""
     unit = len(src_hbm.shape) == 3
+    t = idx_ref.shape[-1]
 
-    def start(i, carry):
-        r = idx_ref[0, i]
+    def issue(base, count):
+        ids = [idx_ref[0, base + k] for k in range(count)]
         if clamp is not None:
-            r = jnp.minimum(r, clamp)
-        if unit:
-            src, to = src_hbm.at[r], dst.at[row0 + i]
-        else:
-            src, to = src_hbm.at[pl.ds(r, 1)], dst.at[pl.ds(row0 + i, 1)]
-        pltpu.make_async_copy(src, to, sem).start()
+            ids = [jnp.minimum(r, clamp) for r in ids]
+        for k, r in enumerate(ids):
+            if unit:
+                src, to = src_hbm.at[r], dst.at[row0 + base + k]
+            else:
+                src = src_hbm.at[pl.ds(r, 1)]
+                to = dst.at[pl.ds(row0 + base + k, 1)]
+            pltpu.make_async_copy(src, to, sem).start()
+
+    def group(g, carry):
+        issue(pl.multiple_of(g * GATHER_UNROLL, GATHER_UNROLL), GATHER_UNROLL)
         return carry
 
-    jax.lax.fori_loop(0, idx_ref.shape[-1], start, 0)
+    groups = t // GATHER_UNROLL
+    if groups:
+        jax.lax.fori_loop(0, groups, group, 0)
+    issue(groups * GATHER_UNROLL, t % GATHER_UNROLL)
 
 
 def wait_row_gather(dst, sem):
@@ -616,7 +638,8 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
     ``csr_to_balanced`` builds it); ``rows_gathered``: for N > 1 the DMA
     row gather's ``(tile_loads + drained prefetches) × T × column
     blocks``, for N = 1 the ``cols.size`` elements XLA gathers from ``x``
-    (``spmv._gathered``)."""
+    (``spmv._gathered``); ``gather_unroll`` (N > 1): the row copies one
+    iteration of the gather's issue loop starts (``GATHER_UNROLL``)."""
     n_tiles, t = bal.cols.shape
     n = 1 if x.ndim == 1 else x.shape[1]
     m = bal.shape[0]
@@ -659,6 +682,8 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
         tile_n = TileGeometry().tile_n if tile_n is None else tile_n
         rows = (tile_loads + drained) * t * -(-n // tile_n)
     work = {"visits": visits, "tile_loads": tile_loads, "rows_gathered": rows}
+    if x.ndim > 1:
+        work["gather_unroll"] = GATHER_UNROLL
     if prefetched is not None:
         work["prefetched_loads"] = prefetched
     if dummy is not None:

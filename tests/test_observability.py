@@ -15,8 +15,9 @@ from repro import api
 from repro.core import csr_from_coo, csr_to_balanced, rmat
 from repro.core import guardrails as G
 from repro.core.formats import BalancedCOO
-from repro.core.plan import plan
-from repro.kernels.vsr import _nb_work, plan_visits
+from repro.core.plan import pattern_prep, plan
+from repro.kernels.fused_chain import sddmm_work
+from repro.kernels.vsr import GATHER_UNROLL, _nb_work, plan_visits
 
 
 @pytest.fixture(autouse=True)
@@ -203,6 +204,30 @@ def test_work_counts_a_drained_prefetch():
     assert _prefetches(vt) == (loads - 2, 1)
     assert work["prefetched_loads"] == loads - 1 - 1   # less the drain
     assert work["rows_gathered"] == (loads + 1) * t * 2  # two sweeps
+
+
+def test_work_names_the_gather_unroll():
+    """The row-gather kernels record the copies one iteration of their
+    issue loop starts; stacked layers sum their counts, not the unroll.
+    SpMV gathers no rows, so its record has none."""
+    bal = csr_to_balanced(rmat(8, 8, seed=5), tile=64)
+    m = bal.shape[0]
+    vt, vb, _ = plan_visits(bal, 16)
+    for x, spill in (((m, 128), False), ((m, 128), True), ((m,), False)):
+        _, work = _nb_work(bal, jax.ShapeDtypeStruct(x, jnp.float32),
+                           visit_tile=vt, visit_block=vb, wb=16, spill=spill)
+        assert work.get("gather_unroll") == (GATHER_UNROLL if len(x) > 1
+                                             else None)
+    name, work = sddmm_work(bal.rows, jax.ShapeDtypeStruct((m, 1024),
+                                                           jnp.float32))
+    assert name == "repro_sddmm" and work["gather_unroll"] == GATHER_UNROLL
+    rows = np.stack([np.asarray(bal.rows)] * 3)
+    cols = np.stack([np.asarray(bal.cols)] * 3)
+    pw = pattern_prep(rows, cols, bal.shape)["work"]
+    one = dict(pattern_prep(rows[0], cols[0], bal.shape)["work"].forward)
+    forward = dict(pw.forward)
+    assert forward["gather_unroll"] == GATHER_UNROLL
+    assert forward["tile_loads"] == 3 * one["tile_loads"]
 
 
 def test_banded_graph_has_dummy_visits():

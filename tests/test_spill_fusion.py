@@ -22,9 +22,10 @@ from repro.core.cache import PlanCache, cached_plan, pattern_fingerprint
 from repro.core.formats import BalancedCOO
 from repro.core.quant import dequantize_stream, quantize_stream
 from repro.core.shard import VISIT_PAD, stack_visit_schedules
+from repro.kernels.fused_chain import sddmm_pallas
 from repro.kernels.spmv import spmv_vsr, spmv_vsr_fused
-from repro.kernels.vsr import (plan_visits, plan_windows, spmm_vsr,
-                               spmm_vsr_fused)
+from repro.kernels.vsr import (GATHER_UNROLL, plan_visits, plan_windows,
+                               spmm_vsr, spmm_vsr_fused)
 
 from conftest import random_csr
 
@@ -175,6 +176,12 @@ def _pipeline_case(name, rng):
         a[70:] = (rng.random((26, 40)) < 0.2) * rng.standard_normal((26, 40))
         csr = csr_from_dense(a.astype(np.float32))
         return csr, csr_to_balanced(csr, tile=32), 8
+    if name == "remainder_tile":               # T % GATHER_UNROLL copies
+        csr = rmat(6, 8, seed=3)               # left to the static tail
+        return csr, csr_to_balanced(csr, tile=2 * GATHER_UNROLL + 3), 16
+    if name == "short_tile":                   # T < GATHER_UNROLL: the tail
+        csr, _ = random_csr(rng, 40, 40, 0.05)  # issues every copy
+        return csr, csr_to_balanced(csr, tile=GATHER_UNROLL - 1), 8
     csr = rmat(6, 8, seed=3)                   # skipped_tile: a drain
     return csr, _skip_tile(csr_to_balanced(csr, tile=64), 2), 16
 
@@ -182,10 +189,13 @@ def _pipeline_case(name, rng):
 @pytest.mark.parametrize("mode", ["on_wait", "eager"])
 @pytest.mark.parametrize("n", [8, 256])
 @pytest.mark.parametrize("case", ["single_tile", "block_crossings",
-                                  "empty_row_bands", "skipped_tile"])
+                                  "empty_row_bands", "skipped_tile",
+                                  "remainder_tile", "short_tile"])
 def test_pipelined_gather_matches_spill_and_xla(rng, case, n, mode):
     """Tile i + 1's rows land in one slot while tile i reduces out of the
-    other; N = 256 makes two column-block sweeps, each starting cold."""
+    other; N = 256 makes two column-block sweeps, each starting cold.
+    Tiles of ``T`` not a multiple of ``GATHER_UNROLL`` issue some or all
+    of their copies from the issue loop's static tail."""
     csr, bal, wb = _pipeline_case(case, rng)
     vt, vb, _ = plan_visits(bal, wb)
     runs = vt[np.r_[True, vt[1:] != vt[:-1]]]
@@ -195,6 +205,10 @@ def test_pipelined_gather_matches_spill_and_xla(rng, case, n, mode):
     assert (bal.n_tiles == 1) == (case == "single_tile")
     assert any(empty) == (case == "empty_row_bands")
     assert np.any(np.diff(runs) > 1) == (case == "skipped_tile")
+    t = bal.rows.shape[1]
+    assert (t % GATHER_UNROLL != 0) == (case in ("remainder_tile",
+                                                  "short_tile"))
+    assert (t < GATHER_UNROLL) == (case == "short_tile")
     x = jnp.asarray(rng.standard_normal((csr.shape[1], n)).astype(np.float32))
     got = np.asarray(spmm_vsr_fused(bal, x, wb=wb,
                                     interpret=_dma_interpret(mode)))
@@ -222,6 +236,28 @@ def test_pipelined_gather_int8_stream(rng, mode):
     xla = np.asarray(spmm_vsr_fused(deq, x, wb=16, interpret=True))
     np.testing.assert_allclose(got, spill, atol=2e-3)
     np.testing.assert_allclose(got, xla, atol=2e-3)
+
+
+@pytest.mark.parametrize("mode", ["on_wait", "eager"])
+@pytest.mark.parametrize("tile", ["remainder", "short"])
+def test_unit_row_gather_remainder_sddmm(rng, tile, mode):
+    """The SDDMM's ``(rows, 1, w)`` unit copies, sentinel rows clamped, at
+    a ``T`` the issue loop's groups do not divide: every score is read
+    only once all ``T`` copies of both endpoints have landed."""
+    t = 2 * GATHER_UNROLL + 3 if tile == "remainder" else GATHER_UNROLL - 1
+    csr, a = random_csr(rng, 40, 30, 0.1)
+    bal = csr_to_balanced(csr, tile=t)
+    rows, cols = np.asarray(bal.rows), np.asarray(bal.cols)
+    assert (rows == 40).any()                  # a padded last tile
+    g = rng.standard_normal((40, 200)).astype(np.float32)
+    x = rng.standard_normal((30, 200)).astype(np.float32)
+    got = np.asarray(sddmm_pallas(bal.rows, bal.cols, jnp.asarray(g),
+                                  jnp.asarray(x), shape=(40, 30),
+                                  interpret=_dma_interpret(mode)))
+    live = rows < 40
+    want = np.where(live, np.einsum("tkd,tkd->tk", g[np.minimum(rows, 39)],
+                                    x[cols]), 0.0)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-3)
 
 
 def test_pipelined_gather_grads_match_dense(rng):

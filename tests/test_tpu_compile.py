@@ -151,6 +151,26 @@ def test_sddmm_compiles(no_cache, one_chip, graph):
     _compile(run, (bal.rows, bal.cols, a, b), one_chip, ("repro_sddmm",))
 
 
+def test_row_gather_remainder_compiles(no_cache, one_chip):
+    """The SDDMM at a tile of 131 nonzeros, which no ``GATHER_UNROLL``
+    of 2 to 64 divides: its gathers issue the last copies from the issue
+    loop's static tail.  Its index blocks are SMEM and its output block
+    spans the whole ``(1, T)``, so any ``T`` is a legal tile."""
+    from repro.core import csr_to_balanced
+    from repro.kernels.fused_chain import sddmm_pallas
+    from repro.kernels.vsr import GATHER_UNROLL
+    m, k, t = 4096, 4096, 131
+    assert t % GATHER_UNROLL
+    bal = csr_to_balanced(rmat(12, 8, seed=2), tile=t)
+    a = jax.ShapeDtypeStruct((m, 1024), jnp.float32)
+    b = jax.ShapeDtypeStruct((k, 1024), jnp.float32)
+
+    def run(rows, cols, a, b):
+        return sddmm_pallas(rows, cols, a, b, interpret=False, shape=(m, k))
+
+    _compile(run, (bal.rows, bal.cols, a, b), one_chip, ("repro_sddmm",))
+
+
 def test_fused_chain_compiles(no_cache, one_chip, graph):
     m, k = graph.shape
     entry, arrays, statics, bal = _chain_operands(graph.plan, "chain")
