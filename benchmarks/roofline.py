@@ -1,12 +1,6 @@
 """§Roofline table generator: reads results/dryrun/*.json artifacts and
 renders the per-(arch x cell) roofline table to results/roofline.md +
-CSV rows for benchmarks.run.
-
-The quant section is self-contained (no artifacts needed): it runs the NB
-SpMM live with an int8-quantized value stream vs a bf16 one and reports
-where each sits on the roofline — modeled bytes at each dtype's real
-stream width, the arithmetic-intensity shift, wall time, and max abs
-error against the f32 plan (DESIGN.md §8)."""
+CSV rows for benchmarks.run."""
 from __future__ import annotations
 
 import glob
@@ -50,61 +44,8 @@ def render_table(arts: list[dict]) -> str:
     return "\n".join(lines)
 
 
-def quant_rows() -> list[str]:
-    """Live int8-vs-bf16 roofline points for the NB SpMM value stream."""
-    import numpy as np
-    import jax
-    import jax.numpy as jnp
-
-    from repro.api import TileGeometry, sparse
-    from repro.core.formats import CSR
-    from repro.kernels import modeled_traffic, modeled_traffic_sharded
-    from .common import bytes_derived, pick_suite, time_fn
-
-    rows = []
-    rng = np.random.default_rng(0)
-    name, csr = next(iter(pick_suite().items()))
-    n = 128
-    x = jnp.asarray(rng.standard_normal((csr.shape[1], n)).astype(np.float32))
-    A = sparse(csr, cache=False, backend="xla")
-    geom = TileGeometry(tile=A.plan.tile)
-    y_ref = np.asarray(A @ x)
-    variants = {
-        "bf16": (sparse(CSR(csr.indptr, csr.indices,
-                            csr.data.astype(jnp.bfloat16), csr.shape),
-                        cache=False, backend="xla"),
-                 modeled_traffic(csr, n, geometry=geom, value_bytes=2)),
-        "int8": (sparse(csr, quant="int8", cache=False, backend="xla"),
-                 modeled_traffic(csr, n, geometry=geom, quant="int8")),
-    }
-    for tag, (Av, traffic) in variants.items():
-        t = time_fn(lambda: Av @ x)
-        err = float(jnp.max(jnp.abs((Av @ x).astype(jnp.float32)
-                                    - jnp.asarray(y_ref))))
-        rows.append(csv_row(
-            f"roofline/quant/{name}/n{n}/{tag}", t * 1e6,
-            bytes_derived(traffic["flops"], traffic["fused_bytes"], t,
-                          f"value_bytes={traffic['fused_value_bytes']}"
-                          f"_max_abs_err={err:.2e}")))
-    if jax.device_count() > 1:
-        from jax.sharding import Mesh
-        mesh = Mesh(np.array(jax.devices()), ("shard",))
-        Aq = sparse(csr, quant="int8", mesh=mesh, cache=False)
-        sub = Aq.plan.substrate(Aq.plan.entry(Aq.plan.select(n)).substrate)
-        traffic = modeled_traffic_sharded(sub, n)
-        t = time_fn(lambda: Aq @ x)
-        err = float(np.abs(np.asarray(Aq @ x) - y_ref).max())
-        rows.append(csv_row(
-            f"roofline/quant/{name}/n{n}/int8_sharded{jax.device_count()}",
-            t * 1e6,
-            bytes_derived(traffic["flops"], traffic["fused_bytes"], t,
-                          f"value_bytes={traffic['fused_value_bytes']}"
-                          f"_max_abs_err={err:.2e}")))
-    return rows
-
-
 def run():
-    rows = quant_rows()
+    rows = []
     for mesh in ("single", "multi"):
         arts = load_artifacts(mesh)
         if not arts:

@@ -72,21 +72,17 @@ def main() -> None:
     from . import (adaptive_strategy, attention, csc_ablation,
                    fig6_kernel_perf, guardrails, moe_dispatch, plan_cache,
                    roofline, sddmm_chain, serving, sharded_spmm,
-                   spill_fusion, vdl_ablation, vsr_ablation)
+                   vsr_ablation)
 
     benches = {
         "plan_cache": lambda: plan_cache.run(args.full),
         "vsr_ablation": lambda: vsr_ablation.run(args.full),
-        "vdl_ablation": lambda: vdl_ablation.run(args.full),
-        "vdl_ablation_pallas": lambda: vdl_ablation.run(args.full,
-                                                        backend="pallas"),
         "csc_ablation": lambda: csc_ablation.run(args.full),
         "fig6_kernel_perf": lambda: fig6_kernel_perf.run(args.full),
         "adaptive_strategy": lambda: adaptive_strategy.run(args.full),
         "moe_dispatch": moe_dispatch.run,
         "roofline": roofline.run,
         "sharded_spmm": lambda: sharded_spmm.run(args.full),
-        "spill_fusion": lambda: spill_fusion.run(args.full),
         "sddmm_chain": lambda: sddmm_chain.run(args.full),
         "attention": lambda: attention.run(args.full),
         "serving": lambda: serving.run(args.full),

@@ -1,15 +1,8 @@
 """Sharded-SpMM sweep: single-device vs row-split vs nnz-balanced across
-R-MAT skew levels, on a mesh over the host's local devices — plus the two
-multi-chip hot-path ablations of DESIGN.md §7:
-
-* **fused vs spill** inner kernels (Pallas NB, interpret off-TPU): wall time
-  of both boundary resolutions inside ``shard_map`` next to the modeled
-  per-shard HBM bytes (``kernels/tune.modeled_traffic_sharded``) — the spill
-  path's partials window is a shared static sized by the *worst* shard, the
-  fused visit schedules are per-shard data.
-* **overlap vs psum** for tile-split (psum) plans: the width-chunked
-  collective-permute ring against one trailing blocking psum
-  (``SelectorThresholds.overlap_min_n``).
+R-MAT skew levels, on a mesh over the host's local devices — plus the
+overlap-vs-psum ablation of DESIGN.md §7 for tile-split (psum) plans: the
+width-chunked collective-permute ring against one trailing blocking psum
+(``SelectorThresholds.overlap_min_n``).
 
 Run with virtual devices to see real partitioning behaviour on CPU::
 
@@ -19,7 +12,7 @@ Run with virtual devices to see real partitioning behaviour on CPU::
 Columns: time per call for each strategy plus which partitioner the
 stats-driven rule (``SelectorThresholds.partition_cv``) would pick — on a
 single real device all three collapse to the same math, so the interesting
-output there is the *choice* (and the modeled bytes), not the timing."""
+output there is the *choice*, not the timing."""
 from __future__ import annotations
 
 import numpy as np
@@ -29,21 +22,13 @@ import jax.numpy as jnp
 from repro.api import SelectorThresholds, sparse
 from repro.core import matrix_stats, rmat
 from repro.core.selector import select_partition
-from repro.kernels import OVERLAP_NEVER, modeled_traffic_sharded
+from repro.kernels import OVERLAP_NEVER
 from repro.launch.mesh import make_local_mesh
 from . import common
-from .common import bytes_derived, csv_row, time_fn
+from .common import csv_row, time_fn
 
 SKEWS = {"uniform": (0.25, 0.25, 0.25), "mild": (0.45, 0.22, 0.22),
          "skewed": (0.57, 0.19, 0.19)}
-
-
-def _force_spill(matrix, impl: str):
-    """Flip a (cache=False) sharded plan's NB prep opts to the spill inner
-    path before the bound kernel is built — the parity-reference spelling."""
-    entry = matrix.plan.entry(impl)
-    matrix.plan.kernel_opts(entry)["spill"] = True
-    return matrix
 
 
 def run(full: bool = False, n: int = 8):
@@ -72,29 +57,6 @@ def run(full: bool = False, n: int = 8):
             rows.append(csv_row(f"{name}/{kind}", times[kind] * 1e6,
                                 f"vs_single={t_one/times[kind]:.2f}x{mark}"))
 
-        # --- fused vs spill inside shard_map (Pallas NB inner) -------------
-        impl = "nb_pr"
-        m_fused = sparse(csr, cache=False).shard(mesh, kind=chosen,
-                                                 inner_backend="pallas")
-        m_spill = _force_spill(
-            sparse(csr, cache=False).shard(mesh, kind=chosen,
-                                           inner_backend="pallas"), impl)
-        sub = m_fused.plan.substrate("shard_balanced")
-        traffic = modeled_traffic_sharded(sub, n)
-        t_fused = time_fn(lambda: m_fused.matmul(x, impl=impl, interpret=True))
-        t_spill = time_fn(lambda: m_spill.matmul(x, impl=impl, interpret=True))
-        rows.append(csv_row(
-            f"{name}/{chosen}/fused", t_fused * 1e6,
-            bytes_derived(traffic["flops"], traffic["fused_bytes"], t_fused,
-                          f"max_visits={traffic['max_visits']}")))
-        rows.append(csv_row(
-            f"{name}/{chosen}/spill", t_spill * 1e6,
-            bytes_derived(traffic["flops"], traffic["spill_bytes"], t_spill,
-                          f"win={traffic['spill_win']}")))
-        rows.append(csv_row(
-            f"{name}/{chosen}/per_shard_bytes_reduction", 0.0,
-            f"{traffic['bytes_reduction']:.2f}x"))
-
         # --- overlap (chunked ppermute ring) vs one blocking psum ----------
         xw = jnp.asarray(rng.standard_normal((csr.shape[1], n_wide))
                          .astype(np.float32))
@@ -105,8 +67,8 @@ def run(full: bool = False, n: int = 8):
                         thresholds=SelectorThresholds(
                             overlap_min_n=OVERLAP_NEVER)).shard(mesh,
                                                                 kind="nnz")
-        t_ring = time_fn(lambda: m_ring.matmul(xw, impl=impl))
-        t_psum = time_fn(lambda: m_psum.matmul(xw, impl=impl))
+        t_ring = time_fn(lambda: m_ring.matmul(xw, impl="nb_pr"))
+        t_psum = time_fn(lambda: m_psum.matmul(xw, impl="nb_pr"))
         rows.append(csv_row(f"{name}/nnz_n{n_wide}/overlap_ring",
                             t_ring * 1e6,
                             f"vs_psum={t_psum/t_ring:.2f}x"))

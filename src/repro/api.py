@@ -470,8 +470,8 @@ def health() -> dict:
 
     ``spans``: per span name (``repro.plan``, ``repro.plan.cache``,
     ``repro.plan.stats``, ``repro.plan.fingerprint``,
-    ``repro.plan.substrate``, ``repro.plan.prep`` with its children
-    ``repro.plan.windows`` and ``repro.plan.visits``, and
+    ``repro.plan.substrate``, ``repro.plan.prep`` with its child
+    ``repro.plan.visits``, and
     ``repro.execute.trace``, the Python side of ``execute``, which under
     ``jit`` runs while the step is traced): ``count``, ``total_s``,
     ``self_s`` (less the child spans), ``parents`` (enclosing span name →

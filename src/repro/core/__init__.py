@@ -22,4 +22,4 @@ from .shard import (ShardSpec, ShardedSubstrate, build_sharded_substrate,
                     execute_pattern_sharded, make_shard_spec)
 from .spmm import (spmm_as_n_spmv, spmm_nb_pr, spmm_nb_pr_trainable,
                    spmm_nb_sr, spmm_rs_pr, spmm_rs_sr)
-from .stats import MatrixStats, balanced_tile_span, matrix_stats
+from .stats import MatrixStats, matrix_stats

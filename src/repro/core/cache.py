@@ -59,10 +59,10 @@ def mesh_signature(mesh) -> Optional[tuple]:
 def thresholds_version(th: Optional[SelectorThresholds]) -> tuple:
     """The thresholds' contribution to the key: recalibration must invalidate
     cached plans (their selector decisions are baked into artifacts).
-    ``astuple`` folds in *every* field — including the v2 additions
-    (``max_win``, the sharded overlap cutoff ``overlap_min_n``, the geometry
-    table) — so a retuned overlap crossover or geometry invalidates exactly
-    the plans whose prep opts it changes."""
+    ``astuple`` folds in *every* field — including the v2 additions (the
+    sharded overlap cutoff ``overlap_min_n``, the geometry table) — so a
+    retuned overlap crossover or geometry invalidates exactly the plans
+    whose prep opts it changes."""
     if th is None:
         return ()
     return dataclasses.astuple(th)

@@ -150,14 +150,14 @@ _PREP_KWARGS: dict = {}
 
 
 #: plan-context kwargs a prep hook may opt into by declaring them
-_PREP_CONTEXT_NAMES = ("geometry", "max_win", "overlap_min_n")
+_PREP_CONTEXT_NAMES = ("geometry", "overlap_min_n")
 
 
 def _prep_context_kwargs(prep, ctx: dict) -> dict:
     """Filter the plan-context kwargs (autotuned geometry, guard thresholds,
     the sharded overlap cutoff) down to the ones this prep hook declares.
     Prep hooks keep the minimal ``prep(substrate)`` signature unless they opt
-    into context — the Pallas NB prep takes ``geometry=``/``max_win=``, the
+    into context — the Pallas NB prep takes ``geometry=``, the
     sharded prep additionally ``overlap_min_n=``, the BSR prep nothing — so
     the registry contract stays backward compatible."""
     accepted = _PREP_KWARGS.get(prep)
@@ -409,7 +409,7 @@ class PlanBuilder:
 
     def with_thresholds(self, th: SelectorThresholds) -> "PlanBuilder":
         """Same matrix and substrate caches, different decision thresholds.
-        Prep opts bake thresholds-derived context (``max_win``, the sharded
+        Prep opts bake thresholds-derived context (the sharded
         ``overlap_min_n``), so the opts cache resets along with the bound
         kernels — sharing it would serve opts built under the old cutoffs
         (and alias new ones back into the original plan)."""
@@ -464,7 +464,6 @@ class PlanBuilder:
             else:
                 ctx = _prep_context_kwargs(
                     entry.prep, {"geometry": self.geometry,
-                                 "max_win": self.thresholds.max_win,
                                  "overlap_min_n": self.thresholds.overlap_min_n})
                 with HEALTH.span("repro.plan.prep"), \
                         jax.ensure_compile_time_eval():
@@ -628,9 +627,8 @@ def plan(csr: CSR, *, n_hint: int | None = None,
     ``kernels/tune.py`` is the producer.  ``tile=None`` takes the geometry's
     nnz quota (default 512); an explicit ``tile`` always wins.  The fused
     Pallas kernels size their work per ``wb``-row output block, so a tile
-    spanning a long empty-row gap costs only its own visits; only the spill
-    parity path sizes a window by the worst tile (``thresholds.max_win``
-    warns there, ``kernels/vsr.plan_windows``).
+    spanning a long empty-row gap costs only its own visits (an empty-block
+    dummy visit each), never a window sized by the worst tile.
 
     Sharded backend: ``mesh`` (required) names the device mesh; the
     partitioner is chosen from the matrix stats (``cv`` vs.
@@ -1490,7 +1488,7 @@ def execute_pattern(rows: jax.Array, cols: jax.Array, vals: jax.Array,
                 "cannot take a traced pattern; pass concrete rows/cols or "
                 "use backend='xla'")
         # key prep artifacts by pattern *content* — an id()-based key can be
-        # reused by a new array after GC and serve stale row windows
+        # reused by a new array after GC and serve stale visit schedules
         with jax.ensure_compile_time_eval():
             r = np.asarray(rows)
         digest = hashlib.sha1(r.tobytes()).hexdigest()

@@ -19,7 +19,7 @@ where ``substrate`` is the format named by ``entry.substrate`` ("ell",
 "balanced" or "bsr"), ``interpret`` is honoured by Pallas backends (ignored by
 XLA), and ``opts`` are the static per-matrix artifacts produced by the entry's
 optional ``prep`` hook.  ``prep(substrate) -> opts`` runs host-side once, at
-plan time, on concrete arrays — hoisting work like ``plan_windows`` out of the
+plan time, on concrete arrays — hoisting work like ``plan_visits`` out of the
 traced path so ``execute`` stays jit-able (see DESIGN.md §3).
 """
 from __future__ import annotations

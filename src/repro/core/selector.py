@@ -99,11 +99,6 @@ class SelectorThresholds:
     # partitioning, else row-split by M.  Same CV signal as Insight 2, one
     # level up: skewed rows make equal-row shards unequal-work shards.
     partition_cv: float = 1.0
-    # pathological-span guard: a plan whose worst tile would span more than
-    # this many rows (empty-row gaps inflate it without adding work) falls
-    # back from the Pallas backend to xla instead of sizing a spill window
-    # — and its one-hot matmul — off the gap (DESIGN.md §6).
-    max_win: int = 4096
     # sharded psum plans with dense width N >= this chunk the width axis and
     # replace the trailing blocking psum with a compute-overlapped
     # collective-permute ring (DESIGN.md §7); below it one fused psum wins.
@@ -163,45 +158,30 @@ class SelectorThresholds:
              "pr_avg_row": float(self.pr_avg_row),
              "sr_cv": float(self.sr_cv),
              "partition_cv": float(self.partition_cv)}
-        if self.geometries or self.max_win != 4096 or self.overlap_min_n != 512:
-            # geometry-bearing calibrations write the v2 schema; plain
-            # selector calibrations stay v1 so older readers keep loading
-            d["version"] = 2
-            d["max_win"] = int(self.max_win)
+        # each schema is a strict superset of the one before, so a file
+        # takes the lowest version that holds every non-default field, and
+        # older readers keep loading what they can: v2 geometries and the
+        # overlap cutoff, v3 quantization, v4 chain fusion, v5 attention
+        version = max((v for v, needed in (
+            (2, self.geometries or self.overlap_min_n != 512),
+            (3, self.quant_min_n != 1),
+            (4, self.chain_fuse_min_n != 1),
+            (5, self.attn_fuse_min_seq != 1)) if needed), default=1)
+        if version >= 2:
+            d["version"] = version
             d["overlap_min_n"] = int(self.overlap_min_n)
             d["geometries"] = {k: list(v) for k, v in self.geometries}
-        if self.quant_min_n != 1:
-            # quantization-calibrated thresholds write the v3 schema (a
-            # strict superset of v2); v2 files load with the default cutoff
-            d["version"] = 3
-            d["max_win"] = int(self.max_win)
-            d["overlap_min_n"] = int(self.overlap_min_n)
-            d["geometries"] = {k: list(v) for k, v in self.geometries}
-            d["quant_min_n"] = int(self.quant_min_n)
-        if self.chain_fuse_min_n != 1:
-            # chain-calibrated thresholds write the v4 schema (a strict
-            # superset of v3); older files load with the always-fuse default
-            d["version"] = 4
-            d["max_win"] = int(self.max_win)
-            d["overlap_min_n"] = int(self.overlap_min_n)
-            d["geometries"] = {k: list(v) for k, v in self.geometries}
-            d["quant_min_n"] = int(self.quant_min_n)
-            d["chain_fuse_min_n"] = int(self.chain_fuse_min_n)
-        if self.attn_fuse_min_seq != 1:
-            # attention-calibrated thresholds write the v5 schema (a strict
-            # superset of v4); older files load with the always-fuse default
-            d["version"] = 5
-            d["max_win"] = int(self.max_win)
-            d["overlap_min_n"] = int(self.overlap_min_n)
-            d["geometries"] = {k: list(v) for k, v in self.geometries}
-            d["quant_min_n"] = int(self.quant_min_n)
-            d["chain_fuse_min_n"] = int(self.chain_fuse_min_n)
-            d["attn_fuse_min_seq"] = int(self.attn_fuse_min_seq)
+        for v, name in ((3, "quant_min_n"), (4, "chain_fuse_min_n"),
+                        (5, "attn_fuse_min_seq")):
+            if version >= v:
+                d[name] = int(getattr(self, name))
         return json.dumps(d, indent=2)
 
     @classmethod
     def from_json(cls, text: str) -> "SelectorThresholds":
         d = json.loads(text)
+        # keys this reader does not know are ignored: v2-v5 files of older
+        # releases still carry a retired spill-window bound
         if d.get("version", 1) not in (1, 2, 3, 4, 5):
             raise ValueError(f"unsupported thresholds version {d.get('version')!r}")
         geoms = tuple(sorted((str(k), tuple(int(x) for x in v))
@@ -211,7 +191,6 @@ class SelectorThresholds:
                  sr_cv=float(d["sr_cv"]),
                  # absent in pre-sharding calibrations; default keeps them valid
                  partition_cv=float(d.get("partition_cv", 1.0)),
-                 max_win=int(d.get("max_win", 4096)),
                  overlap_min_n=int(d.get("overlap_min_n", 512)),
                  # pre-quantization (v1/v2) files: always honour quant=
                  quant_min_n=int(d.get("quant_min_n", 1)),
@@ -236,8 +215,6 @@ class SelectorThresholds:
                 raise ValueError(f"{name} must be finite, got {v!r}")
             if v < 0:
                 raise ValueError(f"{name} must be >= 0, got {v!r}")
-        if self.max_win < 1:
-            raise ValueError(f"max_win must be >= 1, got {self.max_win}")
         if self.overlap_min_n < 1:
             raise ValueError(f"overlap_min_n must be >= 1, "
                              f"got {self.overlap_min_n}")

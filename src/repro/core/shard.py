@@ -36,9 +36,8 @@ substrate cache.
 Two multi-chip hot-path refinements (DESIGN.md §7): Pallas NB inners run
 the *fused* visit-schedule kernels by default — ragged per-shard schedules
 pad with no-op visits and stack ``(n_shards, max_visits)``
-(``stack_visit_schedules``), so no ``(n_tiles, WIN, N)`` partials buffer
-lives inside ``shard_map`` and low-skew shards stop paying the worst
-shard's spill window — and ``psum`` plans at ``N >=
+(``stack_visit_schedules``), so each shard pays only its own boundary
+crossings plus free padding steps — and ``psum`` plans at ``N >=
 thresholds.overlap_min_n`` replace the trailing blocking psum with a
 width-chunked ``ppermute`` ring whose per-slab collectives overlap the next
 slab's compute (``_overlapped_ring``).
@@ -308,9 +307,9 @@ def _make_inner(entry: registry.KernelEntry, interpret, statics: dict = {},
                 tensor_keys: tuple = ()):
     """Identity-cached inner-kernel callable for the shard_map body.
 
-    ``statics`` (ints: ``win``/``wb``/``tile_n``) bake into the partial;
-    ``tensor_keys`` name the per-shard prep artifacts (row windows, visit
-    schedules) the callable takes as trailing *tensor* arguments — those are
+    ``statics`` (``wb``/``tile_n``, ``quant``) bake into the partial;
+    ``tensor_keys`` name the per-shard prep artifacts (visit schedules,
+    scales) the callable takes as trailing *tensor* arguments — those are
     sliced inside shard_map and must not be baked into the (static) fn."""
     key = (entry, interpret, tuple(sorted(statics.items())), tensor_keys)
     fn = _INNER_BOUND.get(key)
@@ -358,46 +357,40 @@ def stack_visit_schedules(schedules) -> tuple:
     return np.stack(vts), np.stack(vbs), np.stack(vss)
 
 
-def _stack_prep_opts(per_shard: list, mesh, axis: str) -> dict:
-    """Stack per-shard prep-opt dicts into one sharded opts dict.
+#: the prep opts of a visit-schedule kernel: per-shard tensors, then the
+#: geometry statics every shard must agree on
+_SCHEDULE_KEYS = ("visit_tile", "visit_block", "visit_start")
+_GEOMETRY_KEYS = ("wb", "tile_n")
 
-    Tensor opts stack on a leading shard dim (visit schedules pad first);
-    the spill ``win`` is the max — the *shared static* the spill parity path
-    still needs, and exactly the tax the fused schedules avoid.  Geometry
-    statics (``wb``/``tile_n``) must agree across shards (one plan, one
-    geometry).  Tensor opts are placed on the mesh like the substrate."""
-    out: dict = {}
-    first = per_shard[0]
-    put = lambda a: place_shards(a, mesh, axis)  # noqa: E731
-    if "row_base" in first:
-        out["row_base"] = put(
-            np.stack([np.asarray(o["row_base"]) for o in per_shard]))
-        out["win"] = max(int(o["win"]) for o in per_shard)
-    if "visit_tile" in first:
-        vt, vb, vs = stack_visit_schedules(
-            [(np.asarray(o["visit_tile"]), np.asarray(o["visit_block"]),
-              np.asarray(o["visit_start"])) for o in per_shard])
-        out["visit_tile"] = put(vt)
-        out["visit_block"] = put(vb)
-        out["visit_start"] = put(vs)
-        for k in ("wb", "tile_n"):
-            vals = {int(o[k]) for o in per_shard if o.get(k) is not None}
-            if len(vals) > 1:
-                raise ValueError(f"per-shard prep disagrees on {k!r}: {vals}")
-            if vals:
-                out[k] = vals.pop()
+
+def _stack_prep_opts(per_shard: list, mesh, axis: str) -> dict:
+    """Stack per-shard visit schedules into one sharded opts dict.
+
+    The ragged schedules pad with no-op visits and stack on a leading shard
+    dim (``stack_visit_schedules``), placed on the mesh like the substrate;
+    the geometry statics (``wb``/``tile_n``) must agree across shards (one
+    plan, one geometry)."""
+    stacked = stack_visit_schedules(
+        [tuple(np.asarray(o[k]) for k in _SCHEDULE_KEYS) for o in per_shard])
+    out = {k: place_shards(a, mesh, axis)
+           for k, a in zip(_SCHEDULE_KEYS, stacked)}
+    for k in _GEOMETRY_KEYS:
+        vals = {int(o[k]) for o in per_shard if o.get(k) is not None}
+        if len(vals) > 1:
+            raise ValueError(f"per-shard prep disagrees on {k!r}: {vals}")
+        if vals:
+            out[k] = vals.pop()
     return out
 
 
 def _sharded_prep(sub: ShardedSubstrate, *, _logical: str,
-                  geometry=None, max_win=None, overlap_min_n=None) -> dict:
+                  geometry=None, overlap_min_n=None) -> dict:
     """Run the inner entry's host-side prep per shard; stack the artifacts.
 
-    Fused visit schedules are per-shard ragged (visit counts differ), so
-    they are padded with no-op visits and stacked (``stack_visit_schedules``)
-    — the sharded default is the fused inner path, same as single-device.
-    The spill row windows stack alongside as the parity reference (its
-    ``win`` is the max over shards; the fused path never pays it)."""
+    The visit schedules are per-shard ragged (visit counts differ), so
+    they are padded with no-op visits and stacked (``_stack_prep_opts``)
+    — the sharded inner path is the same visit-schedule kernel as
+    single-device."""
     inner = registry.resolve(_logical, sub.inner_backend)
     if inner.prep is None or inner.substrate != sub.inner_kind:
         # prep-less inners (the XLA reference) and inners handed a shard
@@ -407,8 +400,7 @@ def _sharded_prep(sub: ShardedSubstrate, *, _logical: str,
         return ({} if overlap_min_n is None
                 else {"overlap_min_n": int(overlap_min_n)})
     from .plan import _prep_context_kwargs
-    ctx = _prep_context_kwargs(inner.prep, {"geometry": geometry,
-                                            "max_win": max_win})
+    ctx = _prep_context_kwargs(inner.prep, {"geometry": geometry})
     # one bulk device→host transfer, then per-shard host-side slicing — N
     # round trips through np.asarray made plan build O(n_shards) transfers
     rows_h = np.asarray(sub.rows)
@@ -416,13 +408,12 @@ def _sharded_prep(sub: ShardedSubstrate, *, _logical: str,
     vals_h = np.asarray(sub.vals)
     # every emitted opt must have a stacking rule — silently dropping an
     # opt a future prep depends on would run the kernel without it
-    stackable = {"row_base", "win", "visit_tile", "visit_block",
-                 "visit_start", "wb", "tile_n"}
+    stackable = set(_SCHEDULE_KEYS + _GEOMETRY_KEYS)
     per_shard = []
     for s in range(sub.spec.n_shards):
         local = BalancedCOO(rows_h[s], cols_h[s], vals_h[s], sub.inner_shape)
         opts = dict(inner.prep(local, **ctx))
-        if not {"row_base", "win"} <= set(opts) or set(opts) - stackable:
+        if not set(_SCHEDULE_KEYS) <= set(opts) or set(opts) - stackable:
             raise ValueError(f"sharded backend cannot stack prep opts "
                              f"{sorted(opts)} of ({_logical!r}, "
                              f"{sub.inner_backend!r})")
@@ -480,34 +471,25 @@ def _overlapped_ring(run_chunk, x, chunk_w: int, axis: str, n_shards: int):
 
 
 def _sharded_exec(sub: ShardedSubstrate, x, *, _logical: str,
-                  interpret=None, row_base=None, win=None,
-                  visit_tile=None, visit_block=None, visit_start=None,
-                  wb=None, tile_n=None, overlap_min_n=None,
-                  spill: bool = False, quant: str | None = None):
+                  interpret=None, visit_tile=None, visit_block=None,
+                  visit_start=None, wb=None, tile_n=None, overlap_min_n=None,
+                  quant: str | None = None):
     """Run the inner kernel per shard under shard_map; reduce per the spec.
 
     With stacked visit schedules in the prep opts the inner path is the
-    *fused* NB kernel — no ``(n_tiles, WIN, N)`` partials buffer inside
-    shard_map; ``spill=True`` forces the spill-and-combine inner path (the
-    parity reference, via the stacked ``row_base``/max-``win`` windows).
+    visit-schedule NB kernel, each shard walking its own schedule.
     ``reduction == "psum"`` plans at ``N >= overlap_min_n`` replace the
     trailing blocking psum with the width-chunked ``ppermute`` ring."""
     from .vjp import _exec_balanced, _exec_ell
 
     spec = sub.spec
     inner = registry.resolve(_logical, sub.inner_backend)
-    fused = visit_tile is not None and not spill
-    if fused:
+    statics, tensor_keys, tensors = {}, (), []
+    if visit_tile is not None:
         statics = {k: v for k, v in (("wb", wb), ("tile_n", tile_n))
                    if v is not None}
-        tensor_keys = ("visit_tile", "visit_block", "visit_start")
+        tensor_keys = _SCHEDULE_KEYS
         tensors = [visit_tile, visit_block, visit_start]
-    elif row_base is not None:
-        statics = {"win": win}
-        tensor_keys = ("row_base",)
-        tensors = [row_base]
-    else:
-        statics, tensor_keys, tensors = {}, (), []
     if sub.inner_kind == "balanced" and sub.scales is not None:
         # quantized plan: per-shard scales prepend the tensor list so they
         # land in ``extra[0]`` of the balanced custom VJP (the backward's
@@ -629,8 +611,8 @@ def _sddmm_sharded(rows, cols, a, b, *, interpret=None, shape=None,
 def _chain_sharded(rows, cols, a, b, x, *, interpret=None, shape=None,
                    transform: str = "identity", alpha=None, mesh=None,
                    spec=None, inner_backend=None, visit_tile=None,
-                   visit_block=None, visit_start=None, row_base=None,
-                   win=None, wb=None, tile_n=None, overlap_min_n=None,
+                   visit_block=None, visit_start=None, wb=None, tile_n=None,
+                   overlap_min_n=None,
                    **_opts):
     """Sharded fused SDDMM→transform→SpMM.
 

@@ -34,25 +34,6 @@ class MatrixStats:
         return self.cv > 1.0
 
 
-def balanced_tile_span(csr: CSR, tile: int) -> int:
-    """Max rows any fixed-``tile`` nnz quota spans — the spill path's WIN
-    before sublane padding, computed straight from the indptr with no
-    substrate build.  Empty-row *gaps* inflate it without adding work, which
-    is the pathology ``SelectorThresholds.max_win`` guards against (the plan
-    layer falls back to xla rather than size a one-hot matmul off a gap)."""
-    indptr = np.asarray(csr.indptr)
-    m = csr.shape[0]
-    nnz = int(indptr[-1]) if len(indptr) else 0
-    if nnz == 0 or m == 0:
-        return 1
-    # row of nnz index i == searchsorted(indptr, i, "right") - 1: only the
-    # O(nnz/tile) tile-boundary offsets are resolved, no O(nnz) row-id array
-    starts = np.arange(0, nnz, max(1, tile), dtype=np.int64)
-    ends = np.minimum(starts + tile, nnz) - 1
-    row_of = lambda idx: np.searchsorted(indptr, idx, side="right") - 1
-    return int((row_of(ends) - row_of(starts) + 1).max())
-
-
 @HEALTH.span("repro.plan.stats")
 def matrix_stats(csr: CSR) -> MatrixStats:
     indptr = np.asarray(csr.indptr)

@@ -78,7 +78,7 @@ def _value_cot(dvals, vals):
 def _exec_balanced(static, rows, cols, vals, x, *extra):
     """``extra``: integer per-matrix prep artifacts forwarded positionally to
     the bound kernel (float0 cotangents) — the sharded backend threads
-    per-shard prep (VSR row windows, stacked fused visit schedules) through
+    per-shard prep (stacked visit schedules, quantization scales) through
     here, since inside shard_map those are traced values and must not be
     baked into the static.  ``static`` is ``(bound_fn, shape)``, or for a
     pattern call ``(bound_fn, shape, PatternBackward)``: then the first

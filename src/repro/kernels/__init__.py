@@ -12,13 +12,12 @@ from . import vsr as _vsr        # registers nb_* under "pallas"
 from .attention import attn_chain_pallas, attn_stats_pallas
 from .fused_chain import (CHAIN_TRANSFORMS, chain_pallas, chain_stats_pallas,
                           sddmm_pallas)
-from .ops import spmm, spmm_bsr, spmm_csc, spmm_vsr, spmv_vsr, use_pallas_default
+from .ops import spmm, spmm_bsr, spmm_csc, use_pallas_default
 from .spmv import spmv_vsr_fused
 from .tune import (ATTN_NEVER, CHAIN_NEVER, DEFAULT_CANDIDATES, OVERLAP_NEVER,
                    QUANT_NEVER, autotune_attention, autotune_chain,
                    autotune_geometry, autotune_overlap, autotune_quant,
                    measure_attention, measure_chain, measure_geometry,
-                   measure_overlap, measure_quant, modeled_traffic,
-                   modeled_traffic_attention, modeled_traffic_chain,
-                   modeled_traffic_sharded)
-from .vsr import plan_visits, plan_windows, spmm_as_n_spmv_pallas, spmm_vsr_fused
+                   measure_overlap, measure_quant, modeled_traffic_attention,
+                   modeled_traffic_chain)
+from .vsr import plan_visits, spmm_vsr_fused

@@ -33,7 +33,7 @@ from repro.core.selector import TileGeometry
 from .fused_chain import (_pad_2d, fold_stats, gather_endpoints,
                           gather_scratch, row_stats, softmax_weights,
                           stats_blocks, tile_scores)
-from .vsr import (_pad_n, _prep_windows, accumulate_visit, as_tiles,
+from .vsr import (_pad_n, _prep_visits, accumulate_visit, as_tiles,
                   column_blocks, hbm_spec, mxu_dot, new_tile, plan_visits,
                   segment_onehot, start_row_gather, tile_spec,
                   wait_row_gather)
@@ -179,7 +179,7 @@ def attn_chain_pallas(rows, cols, q, k, bias, x, *,
                       interpret: bool | None = None, shape=None, scale=1.0,
                       visit_tile=None, visit_block=None, visit_start=None,
                       wb: int | None = None, tile_n: int | None = None,
-                      stats=None, row_base=None, win=None, **_opts):
+                      stats=None, **_opts):
     """Fused block-sparse attention over one visit schedule: scores are
     formed, biased, softmaxed and consumed entirely in VMEM.  ``bias`` is a
     balanced slab shaped like ``rows`` (pass zeros for no bias); ``stats``
@@ -214,4 +214,4 @@ def attn_chain_pallas(rows, cols, q, k, bias, x, *,
 
 
 registry.register("attn_chain", "pallas", "balanced", attn_chain_pallas,
-                  prep=_prep_windows)
+                  prep=_prep_visits)

@@ -20,8 +20,8 @@ times), swapping ``2*nnz`` value bytes of HBM for endpoint-row gathers and
 dot recompute.
 
 Masked softmax needs row totals before any weight can be formed, so it runs
-two passes over the same schedule (same shape as the PR 4 spill-fused
-accumulation): pass 1 folds each visit's per-row ``(max, sum-of-exp)`` into
+two passes over the same schedule (the revisited-block accumulation of
+the SpMM): pass 1 folds each visit's per-row ``(max, sum-of-exp)`` into
 ``(wb, 1)`` stat columns with the online-softmax update (exchanged as
 ``(mb, wb)`` blocks), pass 2 reads the
 finished stats alongside each visit.  Stats are ``2 * m`` floats of traffic —
@@ -47,7 +47,7 @@ from repro.core.formats import BalancedCOO
 from repro.core.selector import TileGeometry
 from repro.core.spmm import SOFTMAX_EPS, SOFTMAX_NEG
 
-from .vsr import (GATHER_UNROLL, _pad_n, _prep_windows, accumulate_visit,
+from .vsr import (GATHER_UNROLL, _pad_n, _prep_visits, accumulate_visit,
                   as_tiles, block_hits, column_blocks, hbm_spec, mxu_dot,
                   new_tile, plan_visits, segment_onehot, start_row_gather,
                   tile_spec, wait_row_gather)
@@ -406,9 +406,9 @@ def chain_pallas(rows, cols, a, b, x, *, interpret: bool | None = None,
                  shape=None, transform: str = "identity", alpha=None,
                  visit_tile=None, visit_block=None, visit_start=None,
                  wb: int | None = None, tile_n: int | None = None,
-                 stats=None, row_base=None, win=None, **_opts):
+                 stats=None, **_opts):
     """Fused SDDMM→``transform``→SpMM over one visit schedule: edge scores
-    never touch HBM.  The schedule may be precomputed (``_prep_windows`` at
+    never touch HBM.  The schedule may be precomputed (``_prep_visits`` at
     plan time) so the call stays traceable; ``stats`` substitutes externally
     combined softmax statistics (the sharded backend's cross-shard merge)."""
     if transform not in CHAIN_TRANSFORMS:
@@ -448,4 +448,4 @@ def chain_pallas(rows, cols, a, b, x, *, interpret: bool | None = None,
 
 registry.register("sddmm", "pallas", "balanced", sddmm_pallas)
 registry.register("chain", "pallas", "balanced", chain_pallas,
-                  prep=_prep_windows)
+                  prep=_prep_visits)

@@ -23,8 +23,6 @@ from repro.core.selector import PreparedMatrix, SelectorThresholds
 
 from .bsr import spmm_bsr
 from .csc import spmm_csc
-from .spmv import spmv_vsr
-from .vsr import spmm_vsr
 
 
 def use_pallas_default() -> bool:
@@ -45,5 +43,5 @@ def spmm(prep, x: jax.Array, *, impl: str | None = None,
 
 
 __all__ = [
-    "spmm", "spmm_vsr", "spmm_csc", "spmm_bsr", "spmv_vsr", "use_pallas_default",
+    "spmm", "spmm_csc", "spmm_bsr", "use_pallas_default",
 ]

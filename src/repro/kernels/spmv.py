@@ -6,19 +6,17 @@ algorithm: a log-depth prefix network whose combine rule is "add if row ids
 match" (paper Fig. 2(e)), realized with lane rotates (``pltpu.roll``) — the
 TPU analogue of ``__shfl_up_sync`` — followed by a segment-head dump.
 
-Per tile of T nonzeros (lane-major ``(1, T)`` vectors):
+Per visit of a tile of T nonzeros (lane-major ``(1, T)`` vectors):
   1. p = vals * x[cols]                      (x[cols] gathered by XLA: at
      N=1 a dense row is one scalar, the same bytes as the column ids)
   2. log2(T) shift-and-add-if-same-row steps → p[i] = inclusive segment sum
-  3. segment *ends* (next row differs) dump their sum into the tile's
-     (WIN, 1) output window; cross-tile rows merge in the spill combine.
+  3. segment *ends* (next row differs) dump their sum into the visit's
+     ``(wb, 1)`` output block.
 
-Like the SpMM family (``kernels/vsr.py``), the SpMV comes in two boundary
-resolutions: the spill-and-combine reference above, and the **fused**
-default (``spmv_vsr_fused``) that walks the same host-side visit schedule
-and accumulates segment-head dumps directly into revisited ``(wb, 1)``
-output blocks — no ``(n_tiles, WIN)`` partials, no post-kernel
-``segment_sum``.
+Like the SpMM (``kernels/vsr.py``), the SpMV walks the host-side visit
+schedule and accumulates its segment-head dumps directly into revisited
+output blocks: the grid is sequential, so rows that cross tiles sum in
+VMEM, with no partials buffer and no post-kernel combine.
 """
 from __future__ import annotations
 
@@ -31,8 +29,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.formats import BalancedCOO
 from repro.core.selector import TileGeometry
-from .vsr import (accumulate_visit, as_tiles, plan_visits, plan_windows,
-                  segment_onehot, tile_spec)
+from .vsr import (accumulate_visit, as_tiles, plan_visits, segment_onehot,
+                  tile_spec)
 
 
 def _shift_prev(a, d, idx):
@@ -71,51 +69,6 @@ def _head_dump(p, rows, is_end, base, w: int, m: int):
     return jnp.sum(s, axis=1, keepdims=True)
 
 
-def _spmv_kernel(base_ref, *refs, m, win, quant):
-    # quantized streams: per-tile scale as a second scalar-prefetch operand
-    # next to row_base; dequant in register (see kernels/vsr.py, DESIGN.md §8)
-    if quant:
-        sc_ref, rows_ref, xg_ref, vals_ref, o_ref = refs
-    else:
-        rows_ref, xg_ref, vals_ref, o_ref = refs
-    i = pl.program_id(0)
-    rows = rows_ref[...]                                   # (1, T)
-    vals = vals_ref[...].astype(jnp.float32)
-    if quant:
-        vals = vals * sc_ref[i]
-    p = jnp.where(rows < m, vals * xg_ref[...], 0.0)
-    p, is_end = _segment_scan(p, rows)
-    o_ref[...] = _head_dump(p, rows, is_end, base_ref[i], win, m)
-
-
-@functools.partial(jax.jit, static_argnames=("m", "win", "interpret"))
-def _spmv_call(rows, cols, vals, row_base, x, scales=None, *, m, win,
-               interpret):
-    n_tiles, t = rows.shape
-    quant = scales is not None
-    tile = lambda i, *pf: (i, 0, 0)  # noqa: E731
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 if quant else 1,
-        grid=(n_tiles,),
-        in_specs=[tile_spec(t, tile)] * 3,
-        out_specs=pl.BlockSpec((None, win, 1), lambda i, *pf: (i, 0, 0)),
-    )
-    prefetch = ((row_base.astype(jnp.int32), scales) if quant
-                else (row_base.astype(jnp.int32),))
-    partials = pl.pallas_call(
-        functools.partial(_spmv_kernel, m=m, win=win, quant=quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, win, 1), jnp.float32),
-        name="repro_spmv_spill",
-        interpret=interpret,
-    )(*prefetch, as_tiles(rows), _gathered(x, cols), as_tiles(vals))
-
-    idx = row_base[:, None].astype(jnp.int32) + jnp.arange(win, dtype=jnp.int32)[None, :]
-    y = jax.ops.segment_sum(partials.reshape(-1), idx.reshape(-1),
-                            num_segments=m + win + 1)
-    return y[:m]
-
-
 def _gathered(x, cols):
     """The N=1 dense-row loading: ``x[cols]`` as an ``(n_tiles, 1, T)``
     f32 stream.  At N=1 a gathered row is one scalar, so the XLA-side
@@ -125,28 +78,8 @@ def _gathered(x, cols):
         return as_tiles(jnp.take(x.astype(jnp.float32), cols, axis=0))
 
 
-def spmv_vsr(bal: BalancedCOO, x: jax.Array, *,
-             interpret: bool | None = None,
-             row_base: jax.Array | None = None,
-             win: int | None = None,
-             scales: jax.Array | None = None) -> jax.Array:
-    """NB+PR SpMV, spill-and-combine variant (parity reference).  ``x``:
-    (K,). ``row_base``/``win`` may be precomputed at plan time (keeps the
-    call traceable with traced values).  ``scales``: per-tile dequant scales
-    for quantized value streams."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    assert x.ndim == 1, "spmv_vsr is the N=1 path; use spmm_vsr for N>1"
-    if row_base is None or win is None:
-        base, win = plan_windows(bal)
-        row_base = jnp.asarray(base)
-    y = _spmv_call(bal.rows, bal.cols, bal.vals, row_base, x, scales,
-                   m=bal.shape[0], win=win, interpret=interpret)
-    return y.astype(x.dtype)
-
-
 # ---------------------------------------------------------------------------
-# fused variant: segment-head dumps accumulate into revisited output blocks
+# the kernel: segment-head dumps accumulate into revisited output blocks
 # ---------------------------------------------------------------------------
 
 def _spmv_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, quant):
@@ -203,7 +136,7 @@ def spmv_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
                    visit_block: jax.Array | None = None,
                    visit_start: jax.Array | None = None,
                    scales: jax.Array | None = None) -> jax.Array:
-    """Spill-fused NB+PR SpMV: the shuffle-network segment scan with
+    """NB+PR SpMV: the shuffle-network segment scan with
     segment heads accumulated straight into revisited output blocks.  The
     visit schedule may be precomputed (``plan_visits`` at plan time) so the
     call stays traceable when ``bal`` carries traced values.  ``scales``:

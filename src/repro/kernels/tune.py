@@ -1,4 +1,4 @@
-"""Measured tile-geometry autotuner + modeled HBM traffic (DESIGN.md §6).
+"""Measured tile-geometry autotuner and crossover calibrations (DESIGN.md §6).
 
 The paper derives its *selector* thresholds empirically; the same argument
 applies one level down, to the Pallas NB kernels' tile geometry: the winning
@@ -15,11 +15,6 @@ persistence channel as the selector cutoffs (``save_thresholds`` /
 because thresholds are part of the ``PlanCache`` key, a retuned geometry
 invalidates exactly the plans it changes: distinct geometries ⇒ distinct
 cache entries, same geometry ⇒ a hit.
-
-``modeled_traffic`` is the analytical side: per-path HBM byte counts for the
-fused vs spill-and-combine boundary resolutions, used by
-``benchmarks/spill_fusion.py`` to report the fused win as arithmetic-
-intensity movement rather than interpret-mode seconds.
 """
 from __future__ import annotations
 
@@ -31,12 +26,12 @@ import numpy as np
 
 from repro.core import registry
 from repro.core.cache import pattern_fingerprint
-from repro.core.formats import CSR, BalancedCOO, csr_to_balanced
+from repro.core.formats import CSR, csr_to_balanced
 from repro.core.plan import execute, plan
 from repro.core.selector import (SelectorThresholds, TileGeometry,
                                  default_thresholds, geometry_key)
 
-from .vsr import plan_visits, plan_windows
+from .vsr import plan_visits
 
 #: the default measured sweep: nnz quota x output-block rows, lane width
 #: fixed at the MXU's 128 (wider tile_n only pays off at very large N).
@@ -116,179 +111,6 @@ def autotune_geometry(csr: CSR, *, ns: tuple = (8, 128),
         overall = min(cands, key=lambda g: float(np.mean(log_times[g])))
         th = th.with_geometry(geometry_key(backend, fp, None), overall)
     return th
-
-
-# ---------------------------------------------------------------------------
-# modeled HBM traffic: the spill-vs-fused bytes story, analytically
-# ---------------------------------------------------------------------------
-
-def modeled_traffic(csr: CSR, n: int, *,
-                    geometry: TileGeometry | None = None,
-                    dtype_bytes: int = 4, index_bytes: int = 4,
-                    value_bytes: int | None = None,
-                    quant: str | None = None) -> dict:
-    """Per-call modeled HBM bytes of the NB SpMM under both boundary
-    resolutions, charged the way the Pallas pipeline actually DMAs: a block
-    moves between HBM and VMEM only when its BlockSpec index *changes*
-    between consecutive grid steps (DESIGN.md §6).
-
-    * spill (grid ``(n_tiles, nb)``, column blocks innermost): the tile
-      stream loads once per tile, but the ``(K, tile_n)`` dense block
-      re-loads on *every* step (its index tracks the fast axis) — ``n_tiles``
-      passes over X — and the ``(n_tiles, WIN, N_pad)`` partials round-trip
-      (kernel write + ``segment_sum`` read) scales with the *global* WIN the
-      single worst tile sets.
-    * fused (grid ``(nb, V)``, visits innermost): X loads once per column
-      block — one pass total; the tile stream re-loads only when the visit
-      schedule switches tiles (block crossings and neighbour-borrowing
-      dummies re-use the resident tile); output blocks flush exactly once.
-      The spill round-trip is gone — boundary rows accumulate in VMEM.
-
-    ``dtype_bytes`` is the *dense-side* element width (X, outputs, spill
-    partials).  The value stream is charged separately at ``value_bytes``,
-    which defaults to the width of ``csr.data``'s actual dtype — a bf16
-    stream is 2 bytes/nonzero, not 4 — and under ``quant`` to the coded
-    width (1 byte for int8/fp8) plus a 4-byte f32 scale per tile load.
-    """
-    geom = (geometry or TileGeometry()).validate()
-    bal = csr_to_balanced(csr, tile=geom.tile)
-    if value_bytes is None:
-        from repro.core import quant as quant_mod
-        value_bytes = quant_mod.value_bytes(csr.data.dtype)
-    return modeled_traffic_balanced(bal, n, int(csr.nnz), geometry=geom,
-                                    dtype_bytes=dtype_bytes,
-                                    index_bytes=index_bytes,
-                                    value_bytes=value_bytes, quant=quant)
-
-
-def modeled_traffic_balanced(bal, n: int, nnz: int, *,
-                             geometry: TileGeometry | None = None,
-                             win: int | None = None,
-                             dtype_bytes: int = 4,
-                             index_bytes: int = 4,
-                             value_bytes: int | None = None,
-                             quant: str | None = None) -> dict:
-    """The `modeled_traffic` byte model on a prebuilt ``BalancedCOO`` slab —
-    the per-shard entry point (``modeled_traffic_sharded`` charges each
-    shard's own schedule, but the *spill* path with the max-over-shards
-    ``win``, the shared static the sharded spill wrapper actually pays).
-
-    The value stream is charged at its own width: ``value_bytes`` defaults
-    to ``bal.vals``'s dtype width, and ``quant`` narrows it to the coded
-    width (int8/fp8 = 1 byte) plus one 4-byte f32 scale per tile load —
-    index traffic is unchanged, which is why the *stream* reduction caps
-    near (2·index + value)/(2·index + 1) rather than value_bytes×."""
-    from repro.core import quant as quant_mod
-    geom = (geometry or TileGeometry()).validate()
-    m, k = bal.shape
-    win = plan_windows(bal)[1] if win is None else max(int(win), 1)
-    vt, _, _ = plan_visits(bal, geom.wb)
-    n_tiles, t = bal.rows.shape
-    n_visits = int(len(vt))
-    # tile-stream DMAs per column-block sweep = consecutive-run count of vt
-    stream_runs = int(1 + np.count_nonzero(vt[1:] != vt[:-1])) if n_visits else 0
-    nb = max(1, -(-n // geom.tile_n))
-    n_pad = nb * geom.tile_n
-    mb = max(1, -(-m // geom.wb))
-
-    if quant is not None:
-        vb = quant_mod.value_bytes(quant_mod.quant_dtype(quant))
-        scale_bytes = 4                               # one f32 scale per tile
-    else:
-        vb = (quant_mod.value_bytes(bal.vals.dtype)
-              if value_bytes is None else int(value_bytes))
-        scale_bytes = 4 if quant_mod.is_quantized_dtype(bal.vals.dtype) else 0
-
-    value_load = t * vb + scale_bytes                 # vals (+scale), per load
-    stream = t * 2 * index_bytes + value_load         # rows+cols+vals, per load
-    xblock = k * geom.tile_n * dtype_bytes            # one (K, tile_n) block
-    out = m * n_pad * dtype_bytes
-    spill_value = n_tiles * value_load
-    fused_value = stream_runs * nb * value_load
-    spill = (n_tiles * stream
-             + n_tiles * nb * xblock                     # X re-read per tile
-             + 2 * n_tiles * win * n_pad * dtype_bytes   # partials write+read
-             + out)
-    fused = (stream_runs * nb * stream
-             + nb * xblock                               # one pass over X
-             + mb * geom.wb * n_pad * dtype_bytes)       # blocks flushed once
-    flops = 2 * nnz * n
-    return {
-        "spill_bytes": int(spill),
-        "fused_bytes": int(fused),
-        "spill_value_bytes": int(spill_value),
-        "fused_value_bytes": int(fused_value),
-        "value_bytes": int(vb),
-        "quant": quant,
-        "spill_win": int(win),
-        "n_tiles": int(n_tiles),
-        "n_visits": n_visits,
-        "stream_runs": stream_runs,
-        "flops": int(flops),
-        "spill_ai": flops / max(spill, 1),
-        "fused_ai": flops / max(fused, 1),
-        "bytes_reduction": spill / max(fused, 1),
-    }
-
-
-def modeled_traffic_sharded(sub, n: int, *,
-                            geometry: TileGeometry | None = None,
-                            dtype_bytes: int = 4,
-                            index_bytes: int = 4,
-                            quant: str | None = None) -> dict:
-    """Per-shard fused-vs-spill HBM bytes for a ``ShardedSubstrate``.
-
-    The asymmetry this report exists to show: inside ``shard_map`` the spill
-    window is a *shared static*, so every shard's partials buffer is sized by
-    ``max`` over per-shard windows — a single skewed shard taxes all of them
-    — while the fused visit schedules are per-shard data (padding visits are
-    free grid steps), so each shard pays only its own boundary crossings.
-    ``per_shard`` carries both paths' bytes per shard; totals sum them.
-
-    A baked quantized substrate (``sub.quant`` set, int8/fp8 ``sub.vals``)
-    is charged at its coded width automatically; pass ``quant`` to model a
-    what-if narrowing of a float substrate."""
-    from repro.core import quant as quant_mod
-    geom = (geometry or TileGeometry()).validate()
-    if quant is None:
-        quant = getattr(sub, "quant", None)
-    value_bytes = None
-    if quant is None and sub.vals is not None:
-        value_bytes = quant_mod.value_bytes(sub.vals.dtype)
-        if quant_mod.is_quantized_dtype(sub.vals.dtype):
-            # baked quantized slab with no recorded mode: charge coded width
-            # + per-tile scales via the quant branch of the per-shard model
-            quant = "int8"
-    rows_h = np.asarray(sub.rows)
-    cols_h = np.asarray(sub.cols)
-    src_h = np.asarray(sub.src)
-    n_shards = rows_h.shape[0]
-    slabs = [BalancedCOO(rows_h[s], cols_h[s],
-                         np.zeros(rows_h[s].shape, np.float32),
-                         sub.inner_shape) for s in range(n_shards)]
-    win = max(plan_windows(b)[1] for b in slabs)   # the shared spill static
-    per_shard = []
-    for s, bal in enumerate(slabs):
-        nnz_s = int((src_h[s] >= 0).sum())
-        per_shard.append(modeled_traffic_balanced(
-            bal, n, nnz_s, geometry=geom, win=win,
-            dtype_bytes=dtype_bytes, index_bytes=index_bytes,
-            value_bytes=value_bytes, quant=quant))
-    spill = sum(t["spill_bytes"] for t in per_shard)
-    fused = sum(t["fused_bytes"] for t in per_shard)
-    return {
-        "per_shard": per_shard,
-        "n_shards": n_shards,
-        "spill_bytes": int(spill),
-        "fused_bytes": int(fused),
-        "spill_value_bytes": sum(t["spill_value_bytes"] for t in per_shard),
-        "fused_value_bytes": sum(t["fused_value_bytes"] for t in per_shard),
-        "quant": quant,
-        "spill_win": int(win),
-        "max_visits": max(t["n_visits"] for t in per_shard),
-        "flops": sum(t["flops"] for t in per_shard),
-        "bytes_reduction": spill / max(fused, 1),
-    }
 
 
 # ---------------------------------------------------------------------------

@@ -11,22 +11,15 @@ TPU adaptation (see DESIGN.md §2):
     local row ids ``l[T]`` and partial products ``P[T, N]``, the segment sums
     are ``S @ P`` where ``S[w, t] = (l[t] == w)`` — the same algebra the
     shuffle tree computes, expressed as the 128x128-systolic-friendly op.
-  * atomics → two resolutions of the tile-boundary rows (DESIGN.md §6):
-
-    - **fused** (default): the TPU grid is *sequential*, so row-ordered
-      nnz-tiles can accumulate directly into revisited output blocks.  A
-      host-side visit schedule (``plan_visits``) lists, per tile, the
-      ``wb``-row output blocks its rows land in; the kernel walks visits in
-      order, initialising a block on its first visit (``pl.when``) and
-      read-modify-writing it while consecutive visits share the block —
-      boundary-crossing rows simply accumulate across visits, in VMEM.  No
-      partials buffer, no post-kernel combine.
-    - **spill-and-combine** (the parity reference, ``spill=True``): each
-      tile writes its ``(WIN, N)`` window of row sums to an
-      ``(n_tiles, WIN, N)`` partials buffer and one ``segment_sum`` outside
-      the kernel adds the boundary spills — extra HBM traffic of
-      ``n_tiles*WIN*N`` per call, with the *global* ``WIN`` sized by the
-      single worst tile.
+  * atomics → accumulation into revisited output blocks (DESIGN.md §6):
+    the TPU grid is *sequential*, so row-ordered nnz-tiles can accumulate
+    directly into the output blocks they share.  A host-side visit schedule
+    (``plan_visits``) lists, per tile, the ``wb``-row output blocks its rows
+    land in; the kernel walks visits in order, initialising a block on its
+    first visit (``pl.when``) and read-modify-writing it while consecutive
+    visits share the block — boundary-crossing rows simply accumulate across
+    visits, in VMEM.  Because no two grid steps run at once, no partials
+    buffer and no post-kernel combine are needed.
   * VDL (§2.1.2) is a DMA row gather: ``X`` stays in HBM whatever its row
     count, and each nonzero of a tile copies the ``tile_n``-wide slice of
     the dense row its column id names into a ``(T, tile_n)`` VMEM scratch —
@@ -43,14 +36,13 @@ TPU adaptation (see DESIGN.md §2):
 
 Layout: the index/value streams are ``(n_tiles, 1, T)`` so one tile is a
 legal ``(1, T)`` block; T is kept a multiple of 128 (lane width),
-WIN/``wb`` multiples of 8 (sublanes); N is padded to ``tile_n`` by the
+``wb`` a multiple of 8 (sublanes); N is padded to ``tile_n`` by the
 wrapper and gathered operands are f32.  ``(T, wb, tile_n)`` is the measured
 tile geometry (``repro.kernels.tune``).
 """
 from __future__ import annotations
 
 import functools
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -62,33 +54,6 @@ from repro.core import registry
 from repro.core.formats import BalancedCOO
 from repro.core.guardrails import HEALTH
 from repro.core.selector import TileGeometry
-
-
-@HEALTH.span("repro.plan.windows")
-def plan_windows(bal: BalancedCOO, *, max_win: int | None = None
-                 ) -> tuple[np.ndarray, int]:
-    """Host-side prep for the spill path: per-tile first row (row_base) and
-    the max row-window WIN any tile spans (padded to a sublane multiple).
-
-    Only valid (non-sentinel) entries count toward the span; the kernel masks
-    sentinels so clamping cannot corrupt real rows.  ``max_win`` warns when
-    the span is pathological (empty-row gaps inflate it without adding any
-    work) — the plan layer falls back to the xla backend in that case rather
-    than sizing the spill one-hot matmul off the gap."""
-    rows = np.asarray(bal.rows)
-    m = bal.shape[0]
-    valid = rows < m
-    any_valid = valid.any(axis=1)
-    first = np.where(any_valid, rows[:, 0], m).astype(np.int32)
-    last = np.where(any_valid, np.where(valid, rows, -1).max(axis=1), 0)
-    span = int(np.maximum(last - first + 1, 1).max()) if len(rows) else 1
-    win = -(-span // 8) * 8
-    if max_win is not None and win > max_win:
-        warnings.warn(
-            f"VSR spill window {win} exceeds max_win={max_win} (one tile "
-            f"spans {span} rows — likely an empty-row gap); prefer the "
-            "fused path or the xla backend", stacklevel=2)
-    return first, win
 
 
 @HEALTH.span("repro.plan.visits")
@@ -291,7 +256,7 @@ def as_tiles(a: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# fused kernel: in-kernel spill accumulation over revisited output blocks
+# fused kernel: accumulation over revisited output blocks
 # ---------------------------------------------------------------------------
 
 def _vsr_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, tile_n, quant):
@@ -399,67 +364,6 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
 
 
 # ---------------------------------------------------------------------------
-# spill kernel (the parity reference)
-# ---------------------------------------------------------------------------
-
-def _vsr_kernel(base_ref, *refs, m, win, tile_n, quant):
-    # quantized streams carry their per-tile scale as a second scalar-
-    # prefetch operand next to ``row_base``; dequant happens in register
-    # right after the stream load.
-    if quant:
-        sc_ref, rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
-    else:
-        rows_ref, cols_ref, vals_ref, x_hbm, o_ref, xg, sem = refs
-    i, j = pl.program_id(0), pl.program_id(1)
-    start_row_gather(x_hbm.at[j], cols_ref, xg, sem)
-    wait_row_gather(xg, sem)
-    vals = vals_ref[...].astype(jnp.float32)
-    if quant:
-        vals = vals * sc_ref[i]                # in-register dequant
-    # the tile's (WIN, tile_n) window of row sums, rows [row_base, +WIN)
-    s = segment_onehot(rows_ref[...], vals, base_ref[i], win, m)
-    o_ref[...] = mxu_dot(s, xg[...])
-
-
-@functools.partial(jax.jit, static_argnames=("m", "win", "tile_n", "interpret"))
-def _vsr_call(rows, cols, vals, row_base, x, scales=None, *, m, win, tile_n,
-              interpret):
-    n_tiles, t = rows.shape
-    k, n_pad = x.shape
-    nb = n_pad // tile_n
-    quant = scales is not None
-    tile = lambda i, j, *pf: (i, 0, 0)  # noqa: E731
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 if quant else 1,
-        grid=(n_tiles, nb),
-        in_specs=[tile_spec(t, tile), tile_spec(t, tile, pltpu.SMEM),
-                  tile_spec(t, tile), hbm_spec()],
-        out_specs=pl.BlockSpec((None, win, tile_n),
-                               lambda i, j, *pf: (i, 0, j)),
-        scratch_shapes=[pltpu.VMEM((t, tile_n), jnp.float32),
-                        pltpu.SemaphoreType.DMA(())],
-    )
-    prefetch = ((row_base.astype(jnp.int32), scales) if quant
-                else (row_base.astype(jnp.int32),))
-    partials = pl.pallas_call(
-        functools.partial(_vsr_kernel, m=m, win=win, tile_n=tile_n,
-                          quant=quant),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, win, n_pad), jnp.float32),
-        name="repro_vsr_spill",
-        interpret=interpret,
-    )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(vals),
-      column_blocks(x, tile_n))
-
-    # spill combine: tile (t, w) holds the sum for global row row_base[t]+w;
-    # one segment-sum merges boundary-crossing rows (the atomics analogue).
-    idx = (row_base[:, None].astype(jnp.int32) + jnp.arange(win, dtype=jnp.int32)[None, :])
-    y = jax.ops.segment_sum(partials.reshape(-1, n_pad), idx.reshape(-1),
-                            num_segments=m + win + 1)
-    return y[:m]
-
-
-# ---------------------------------------------------------------------------
 # public entry points
 # ---------------------------------------------------------------------------
 
@@ -476,7 +380,8 @@ def spmm_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
                    visit_block: jax.Array | None = None,
                    visit_start: jax.Array | None = None,
                    scales: jax.Array | None = None) -> jax.Array:
-    """Spill-fused NB+PR SpMM: no partials buffer, no post-kernel combine.
+    """NB+PR SpMM over the visit schedule: no partials buffer, no
+    post-kernel combine.
 
     The visit schedule may be precomputed (``plan_visits`` at plan time) so
     the call stays traceable when ``bal`` carries traced values.  With a
@@ -502,90 +407,31 @@ def spmm_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
     return y[:, 0] if x.ndim == 1 else y
 
 
-def spmm_vsr(bal: BalancedCOO, x: jax.Array, *, tile_n: int = 128,
-             interpret: bool | None = None,
-             row_base: jax.Array | None = None,
-             win: int | None = None,
-             scales: jax.Array | None = None) -> jax.Array:
-    """NB+PR SpMM, spill-and-combine variant (the fused path's parity
-    reference).  ``x``: (K, N) — N padded to ``tile_n`` internally.
-
-    ``row_base``/``win`` may be precomputed (``plan_windows`` at plan time) so
-    the call stays traceable when ``bal`` carries traced values.  ``scales``:
-    per-tile dequant scales for quantized value streams."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    x2 = x[:, None] if x.ndim == 1 else x
-    n = x2.shape[1]
-    if row_base is None or win is None:
-        base, win = plan_windows(bal)
-        row_base = jnp.asarray(base)
-    xp = _pad_n(x2, tile_n)
-    y = _vsr_call(bal.rows, bal.cols, bal.vals, row_base, xp, scales,
-                  m=bal.shape[0], win=win, tile_n=tile_n, interpret=interpret)
-    y = y[:, :n].astype(x2.dtype)
-    return y[:, 0] if x.ndim == 1 else y
-
-
-def spmm_as_n_spmv_pallas(bal: BalancedCOO, x: jax.Array, *,
-                          interpret: bool | None = None,
-                          row_base: jax.Array | None = None,
-                          win: int | None = None) -> jax.Array:
-    """Paper §2.1.2 strawman on the *Pallas* backend: N column-by-column VSR
-    SpMVs, each re-gathering the sparse stream — the redundant loads VDL
-    eliminates, implemented with the same physical kernel family as
-    ``spmm_vsr`` so the ablation compares like-for-like backends.
-
-    With precomputed ``row_base``/``win`` the per-column SpMV is the spill
-    variant (backwards compatible); otherwise the fused variant, matching
-    the fused SpMM it is ablated against."""
-    from .spmv import spmv_vsr, spmv_vsr_fused
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    x2 = x[:, None] if x.ndim == 1 else x
-    if row_base is not None and win is not None:
-        one_col = lambda col: spmv_vsr(bal, col, interpret=interpret,
-                                       row_base=row_base, win=win)
-    else:
-        wb = TileGeometry().wb
-        vt, vb, vs = map(jnp.asarray, plan_visits(bal, wb))
-        one_col = lambda col: spmv_vsr_fused(
-            bal, col, interpret=interpret, wb=wb, visit_tile=vt,
-            visit_block=vb, visit_start=vs)
-    out = jax.lax.map(one_col, x2.T).T  # sequential over columns, like N launches
-    return out[:, 0] if x.ndim == 1 else out
-
-
 # ---------------------------------------------------------------------------
 # registry: the Pallas physical kernels for the nnz-balanced logical pair.
 # On TPU the in-tile reduction-style split collapses (DESIGN.md §2): both
 # nb_sr and nb_pr resolve to this binary; N=1 takes the VPU SpMV variant.
-# The fused path is the default; ``spill=True`` forces the parity reference.
 # ---------------------------------------------------------------------------
 
-def _prep_windows(bal: BalancedCOO, *, geometry: TileGeometry | None = None,
-                  max_win: int | None = None) -> dict:
-    """Prep hook for both NB paths: the spill row windows (the parity
-    reference; the sharded backend stacks them per shard) plus the fused
-    visit schedule and its geometry.  ``geometry`` is the plan's autotuned
+def _prep_visits(bal: BalancedCOO, *, geometry: TileGeometry | None = None
+                 ) -> dict:
+    """Prep hook of the visit-schedule kernels: the visit schedule and the
+    geometry it was cut for.  ``geometry`` is the plan's autotuned
     ``TileGeometry`` (``None`` → defaults)."""
-    base, win = plan_windows(bal, max_win=max_win)
     geom = (geometry or TileGeometry()).validate()
     vt, vb, vs = plan_visits(bal, geom.wb)
-    return {"row_base": jnp.asarray(base), "win": win,
-            "visit_tile": jnp.asarray(vt), "visit_block": jnp.asarray(vb),
+    return {"visit_tile": jnp.asarray(vt), "visit_block": jnp.asarray(vb),
             "visit_start": jnp.asarray(vs),
             "wb": geom.wb, "tile_n": geom.tile_n}
 
 
 def _pallas_nb(bal: BalancedCOO, x: jax.Array, scales: jax.Array | None = None,
                *, interpret: bool | None = None,
-               row_base: jax.Array | None = None, win: int | None = None,
                visit_tile: jax.Array | None = None,
                visit_block: jax.Array | None = None,
                visit_start: jax.Array | None = None,
                wb: int | None = None, tile_n: int | None = None,
-               quant: str | None = None, spill: bool = False):
+               quant: str | None = None):
     # Quantized-plan dispatch (DESIGN.md §8): a *baked* substrate arrives
     # already int8/fp8 with its plan-aux scales riding the custom-VJP extras
     # (positional ``scales``); a *live* float stream on a quantized plan
@@ -600,82 +446,70 @@ def _pallas_nb(bal: BalancedCOO, x: jax.Array, scales: jax.Array | None = None,
         bal = BalancedCOO(bal.rows, bal.cols, q, bal.shape)
     else:
         scales = None
-    fused = visit_tile is not None and not spill
     if x.ndim == 1:
-        from .spmv import spmv_vsr, spmv_vsr_fused
-        if fused:
-            return spmv_vsr_fused(bal, x, interpret=interpret, wb=wb,
-                                  visit_tile=visit_tile,
-                                  visit_block=visit_block,
-                                  visit_start=visit_start, scales=scales)
-        return spmv_vsr(bal, x, interpret=interpret, row_base=row_base,
-                        win=win, scales=scales)
-    if fused:
-        return spmm_vsr_fused(bal, x, interpret=interpret, wb=wb,
-                              tile_n=tile_n, visit_tile=visit_tile,
-                              visit_block=visit_block, visit_start=visit_start,
-                              scales=scales)
-    return spmm_vsr(bal, x, interpret=interpret, row_base=row_base, win=win,
-                    scales=scales,
-                    **({} if tile_n is None else {"tile_n": tile_n}))
+        from .spmv import spmv_vsr_fused
+        return spmv_vsr_fused(bal, x, interpret=interpret, wb=wb,
+                              visit_tile=visit_tile, visit_block=visit_block,
+                              visit_start=visit_start, scales=scales)
+    return spmm_vsr_fused(bal, x, interpret=interpret, wb=wb, tile_n=tile_n,
+                          visit_tile=visit_tile, visit_block=visit_block,
+                          visit_start=visit_start, scales=scales)
 
 
 def _nb_work(bal: BalancedCOO, x: jax.Array, *,
              visit_tile: jax.Array | None = None,
              visit_block: jax.Array | None = None,
              wb: int | None = None, tile_n: int | None = None,
-             spill: bool = False, **_) -> tuple[str, dict]:
+             **_) -> tuple[str, dict]:
     """The per-call work of the kernel ``_pallas_nb`` binds, counted from
-    the visit schedule and the operands of the call (host-side).
+    the visit schedule and the operands of the call (host-side; with no
+    schedule, from the one the kernel builds with ``plan_visits``).
 
     ``visits``: grid steps along the schedule; ``tile_loads``: runs of
-    equal ``visit_tile`` (``new_tile`` gathers on each), one per tile on
-    the spill path; ``prefetched_loads`` (fused SpMM only): the tile loads
-    whose rows were started while the tile before them reduced, counted
-    per column-block sweep like ``tile_loads``; ``dummy_visits``: visits
-    whose tile has no row in their block (empty output blocks; counted
-    where the row stream is concrete and row-ordered, as
-    ``csr_to_balanced`` builds it); ``rows_gathered``: for N > 1 the DMA
-    row gather's ``(tile_loads + drained prefetches) × T × column
-    blocks``, for N = 1 the ``cols.size`` elements XLA gathers from ``x``
-    (``spmv._gathered``); ``gather_unroll`` (N > 1): the row copies one
-    iteration of the gather's issue loop starts (``GATHER_UNROLL``)."""
+    equal ``visit_tile`` (``new_tile`` gathers on each); ``prefetched_loads``
+    (SpMM only): the tile loads whose rows were started while the tile
+    before them reduced, counted per column-block sweep like
+    ``tile_loads``; ``dummy_visits``: visits whose tile has no row in
+    their block (empty output blocks; counted where the row stream is
+    concrete and row-ordered, as ``csr_to_balanced`` builds it);
+    ``rows_gathered``: for N > 1 the DMA row gather's ``(tile_loads +
+    drained prefetches) × T × column blocks``, for N = 1 the ``cols.size``
+    elements XLA gathers from ``x`` (``spmv._gathered``);
+    ``gather_unroll`` (N > 1): the row copies one iteration of the
+    gather's issue loop starts (``GATHER_UNROLL``)."""
     n_tiles, t = bal.cols.shape
     n = 1 if x.ndim == 1 else x.shape[1]
     m = bal.shape[0]
+    wb = TileGeometry().wb if wb is None else wb
+    if visit_tile is None or visit_block is None:
+        visit_tile, visit_block, _ = plan_visits(bal, wb)
+    name = "repro_spmv_fused" if x.ndim == 1 else "repro_vsr_fused"
+    vt = np.asarray(visit_tile)
+    vb = np.asarray(visit_block).astype(np.int64)
+    visits = len(vt)
+    runs = vt[np.r_[True, vt[1:] != vt[:-1]]] if visits else vt
+    tile_loads = len(runs)
     prefetched, drained = None, 0
-    if visit_tile is None or spill:
-        name = "repro_spmv_spill" if x.ndim == 1 else "repro_vsr_spill"
-        visits = tile_loads = n_tiles
-        dummy = 0
-    else:
-        name = "repro_spmv_fused" if x.ndim == 1 else "repro_vsr_fused"
-        vt = np.asarray(visit_tile)
-        vb = np.asarray(visit_block).astype(np.int64)
-        visits = len(vt)
-        runs = vt[np.r_[True, vt[1:] != vt[:-1]]] if visits else vt
-        tile_loads = len(runs)
-        if x.ndim > 1:
-            # as ``_vsr_fused_kernel`` runs: each tile load starts tile
-            # i + 1 unless the sweep's last visit is on an earlier tile;
-            # the next load uses it, or drains it when the schedule skips
-            # that tile
-            started = runs[:-1] + 1 <= vt[-1:]
-            used = started & (runs[1:] == runs[:-1] + 1)
-            prefetched = int(np.count_nonzero(used))
-            drained = int(np.count_nonzero(started & ~used))
-        dummy = None
-        if not isinstance(bal.rows, jax.core.Tracer):
-            wb = TileGeometry().wb if wb is None else wb
-            rf = np.asarray(bal.rows).reshape(-1)
-            # queries in the stream's dtype: a mixed-dtype search would
-            # copy the whole row stream to the wider type
-            lo = np.searchsorted(rf, (vb * wb).astype(rf.dtype))
-            hi = np.searchsorted(rf, np.minimum((vb + 1) * wb, m)
-                                 .astype(rf.dtype))
-            start = vt.astype(np.int64) * t
-            hits = np.minimum(hi, start + t) - np.maximum(lo, start)
-            dummy = int(np.count_nonzero(hits <= 0))
+    if x.ndim > 1:
+        # as ``_vsr_fused_kernel`` runs: each tile load starts tile
+        # i + 1 unless the sweep's last visit is on an earlier tile;
+        # the next load uses it, or drains it when the schedule skips
+        # that tile
+        started = runs[:-1] + 1 <= vt[-1:]
+        used = started & (runs[1:] == runs[:-1] + 1)
+        prefetched = int(np.count_nonzero(used))
+        drained = int(np.count_nonzero(started & ~used))
+    dummy = None
+    if not isinstance(bal.rows, jax.core.Tracer):
+        rf = np.asarray(bal.rows).reshape(-1)
+        # queries in the stream's dtype: a mixed-dtype search would
+        # copy the whole row stream to the wider type
+        lo = np.searchsorted(rf, (vb * wb).astype(rf.dtype))
+        hi = np.searchsorted(rf, np.minimum((vb + 1) * wb, m)
+                             .astype(rf.dtype))
+        start = vt.astype(np.int64) * t
+        hits = np.minimum(hi, start + t) - np.maximum(lo, start)
+        dummy = int(np.count_nonzero(hits <= 0))
     if x.ndim == 1:
         rows = n_tiles * t
     else:
@@ -691,7 +525,7 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
     return name, work
 
 
-registry.register("nb_pr", "pallas", "balanced", _pallas_nb, prep=_prep_windows,
+registry.register("nb_pr", "pallas", "balanced", _pallas_nb, prep=_prep_visits,
                   work=_nb_work)
-registry.register("nb_sr", "pallas", "balanced", _pallas_nb, prep=_prep_windows,
+registry.register("nb_sr", "pallas", "balanced", _pallas_nb, prep=_prep_visits,
                   work=_nb_work)

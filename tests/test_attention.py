@@ -311,7 +311,8 @@ def test_thresholds_v5_roundtrip_and_compat():
     assert SelectorThresholds.from_json(s).attn_fuse_min_seq == 256
     # pre-attention calibrations (v1–v4) load with the always-fuse default
     for older in (SelectorThresholds(),                                  # v1
-                  dataclasses.replace(SelectorThresholds(), max_win=512),  # v2
+                  dataclasses.replace(SelectorThresholds(),
+                                      overlap_min_n=64),                 # v2
                   dataclasses.replace(SelectorThresholds(), quant_min_n=8),  # v3
                   dataclasses.replace(SelectorThresholds(),
                                       chain_fuse_min_n=64)):             # v4

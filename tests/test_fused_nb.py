@@ -1,12 +1,14 @@
-"""Spill-fusion parity + autotuned tile geometry (DESIGN.md §6).
+"""The visit-schedule NB kernels + autotuned tile geometry (DESIGN.md §6).
 
-Fused NB kernels (in-kernel spill accumulation, no partials buffer) against
-the spill-and-combine parity reference and the xla lowering, across skewed
-R-MAT patterns, empty rows, single-row matrices, bf16, and N in {1, 7, 128,
-300} — forward and backward; plus the geometry plumbing: visit-schedule
-invariants, PlanCache keying, thresholds v2 persistence, the tuner, the
-pathological-span guard, and the rs_pr width-chunking fix."""
+The NB SpMM/SpMV kernels (accumulation into revisited output blocks, no
+partials buffer) against the dense product and the xla lowering, across
+skewed R-MAT patterns, empty rows, single-row matrices, bf16, and N in
+{1, 7, 128, 300} — forward and backward; plus the geometry plumbing:
+visit-schedule invariants, PlanCache keying, thresholds persistence, the
+tuner, a tile spanning a long empty-row gap, and the rs_pr width-chunking
+fix."""
 import json
+import warnings
 
 import numpy as np
 import pytest
@@ -23,9 +25,8 @@ from repro.core.formats import BalancedCOO
 from repro.core.quant import dequantize_stream, quantize_stream
 from repro.core.shard import VISIT_PAD, stack_visit_schedules
 from repro.kernels.fused_chain import sddmm_pallas
-from repro.kernels.spmv import spmv_vsr, spmv_vsr_fused
-from repro.kernels.vsr import (GATHER_UNROLL, plan_visits, plan_windows,
-                               spmm_vsr, spmm_vsr_fused)
+from repro.kernels.spmv import spmv_vsr_fused
+from repro.kernels.vsr import GATHER_UNROLL, plan_visits, spmm_vsr_fused
 
 from conftest import random_csr
 
@@ -47,20 +48,17 @@ def _cases(rng):
 
 
 @pytest.mark.parametrize("n", [1, 7, 128, 300, 256])
-def test_fused_matches_spill_and_xla(rng, n):
+def test_fused_matches_dense_and_xla(rng, n):
     for name, csr, a in _cases(rng):
         bal = csr_to_balanced(csr, tile=64)
         x = rng.standard_normal((csr.shape[1], n)).astype(np.float32)
         xj = jnp.asarray(x[:, 0] if n == 1 else x)
         ref = a @ x[:, 0] if n == 1 else a @ x
-        if n == 1:
-            got_f = np.asarray(spmv_vsr_fused(bal, xj, wb=16, interpret=True))
-            got_s = np.asarray(spmv_vsr(bal, xj, interpret=True))
-        else:
-            got_f = np.asarray(spmm_vsr_fused(bal, xj, wb=16, interpret=True))
-            got_s = np.asarray(spmm_vsr(bal, xj, interpret=True))
-        np.testing.assert_allclose(got_f, ref, atol=2e-3, err_msg=name)
-        np.testing.assert_allclose(got_f, got_s, atol=2e-3, err_msg=name)
+        fused = spmv_vsr_fused if n == 1 else spmm_vsr_fused
+        got = np.asarray(fused(bal, xj, wb=16, interpret=True))
+        xla = np.asarray(execute(plan(csr, backend="xla"), xj))
+        np.testing.assert_allclose(got, ref, atol=2e-3, err_msg=name)
+        np.testing.assert_allclose(got, xla, atol=2e-3, err_msg=name)
 
 
 @pytest.mark.parametrize("n", [1, 7])
@@ -161,6 +159,17 @@ def _skip_tile(bal, at):
                        bal.shape)
 
 
+def _dense(bal):
+    """The dense ``(m, k)`` matrix a balanced stream holds."""
+    m, k = bal.shape
+    rows, cols = np.asarray(bal.rows).ravel(), np.asarray(bal.cols).ravel()
+    live = rows < m
+    out = np.zeros((m, k), np.float32)
+    np.add.at(out, (rows[live], cols[live]),
+              np.asarray(bal.vals, np.float32).ravel()[live])
+    return out
+
+
 def _pipeline_case(name, rng):
     """``(csr, bal, wb)`` of a schedule that takes one branch of the
     double-buffered gather."""
@@ -191,7 +200,7 @@ def _pipeline_case(name, rng):
 @pytest.mark.parametrize("case", ["single_tile", "block_crossings",
                                   "empty_row_bands", "skipped_tile",
                                   "remainder_tile", "short_tile"])
-def test_pipelined_gather_matches_spill_and_xla(rng, case, n, mode):
+def test_pipelined_gather_matches_dense_and_xla(rng, case, n, mode):
     """Tile i + 1's rows land in one slot while tile i reduces out of the
     other; N = 256 makes two column-block sweeps, each starting cold.
     Tiles of ``T`` not a multiple of ``GATHER_UNROLL`` issue some or all
@@ -212,9 +221,7 @@ def test_pipelined_gather_matches_spill_and_xla(rng, case, n, mode):
     x = jnp.asarray(rng.standard_normal((csr.shape[1], n)).astype(np.float32))
     got = np.asarray(spmm_vsr_fused(bal, x, wb=wb,
                                     interpret=_dma_interpret(mode)))
-    spill = np.asarray(spmm_vsr(bal, x, interpret=True))
     xla = np.asarray(execute(plan(csr, backend="xla"), x))
-    np.testing.assert_allclose(got, spill, atol=2e-3)
     np.testing.assert_allclose(got, xla, atol=2e-3)
     np.testing.assert_allclose(got, np.asarray(csr.to_dense()) @ x,
                                atol=2e-3)
@@ -222,7 +229,9 @@ def test_pipelined_gather_matches_spill_and_xla(rng, case, n, mode):
 
 @pytest.mark.parametrize("mode", ["on_wait", "eager"])
 def test_pipelined_gather_int8_stream(rng, mode):
-    """An int8 value stream with per-tile scales, over two sweeps."""
+    """An int8 value stream with per-tile scales, over two sweeps: the
+    in-register dequant matches the kernel on the dequantized stream and
+    the dense product of it."""
     csr = rmat(6, 8, seed=5)
     bal = csr_to_balanced(csr, tile=64)
     q, sc = quantize_stream(bal.vals, "int8")
@@ -230,12 +239,11 @@ def test_pipelined_gather_int8_stream(rng, mode):
     x = jnp.asarray(rng.standard_normal((64, 130)).astype(np.float32))
     got = np.asarray(spmm_vsr_fused(qbal, x, wb=16, scales=sc,
                                     interpret=_dma_interpret(mode)))
-    spill = np.asarray(spmm_vsr(qbal, x, scales=sc, interpret=True))
     deq = BalancedCOO(bal.rows, bal.cols, dequantize_stream(q, sc),
                       bal.shape)
-    xla = np.asarray(spmm_vsr_fused(deq, x, wb=16, interpret=True))
-    np.testing.assert_allclose(got, spill, atol=2e-3)
-    np.testing.assert_allclose(got, xla, atol=2e-3)
+    fused = np.asarray(spmm_vsr_fused(deq, x, wb=16, interpret=True))
+    np.testing.assert_allclose(got, fused, atol=2e-3)
+    np.testing.assert_allclose(got, _dense(deq) @ np.asarray(x), atol=2e-3)
 
 
 @pytest.mark.parametrize("mode", ["on_wait", "eager"])
@@ -291,9 +299,8 @@ def test_pipelined_gather_stacked_schedule(rng):
         short, x, wb=16, visit_tile=jnp.asarray(vt[1]),
         visit_block=jnp.asarray(vb[1]), visit_start=jnp.asarray(vs[1]),
         interpret=_dma_interpret("on_wait")))
-    np.testing.assert_allclose(got, np.asarray(spmm_vsr(short, x,
-                                                        interpret=True)),
-                               atol=2e-3)
+    xla = np.asarray(execute(plan(short_csr, backend="xla"), x))
+    np.testing.assert_allclose(got, xla, atol=2e-3)
     np.testing.assert_allclose(got, a @ np.asarray(x), atol=2e-3)
 
 
@@ -322,15 +329,19 @@ def test_plan_visits_invariants(rng):
 
 
 def test_plan_visits_skew_does_not_tax_every_tile():
-    """The global spill WIN is inflated by one gap-straddling tile; the
-    fused visit count only charges the tiles that cross blocks."""
+    """One tile straddles a 4,000-row gap, so a window sized by the worst
+    tile would charge every tile thousands of rows; the visit count only
+    charges the tiles that cross blocks."""
     a = np.zeros((4096, 16), np.float32)
     a[:60, :] = 1.0                                   # dense head (960 nnz)
     a[4095, 0] = 1.0                                  # far row shares a tile
     csr = csr_from_dense(a)
     bal = csr_to_balanced(csr, tile=128)
-    _, win = plan_windows(bal)
-    assert win > 3000                                  # spill: everyone pays
+    rows = np.asarray(bal.rows)
+    live = rows < bal.shape[0]
+    span = max(int(r[v].max() - r[v].min()) + 1
+               for r, v in zip(rows, live) if v.any())
+    assert span > 3000                                 # the worst tile
     vt, vb, vs = plan_visits(bal, 64)
     # the fused path's DMA cost is tile-stream *runs* (consecutive visits of
     # one tile — crossings and dummies re-use the resident tile): the gap
@@ -353,13 +364,33 @@ def test_thresholds_v2_roundtrip_and_v1_compat(tmp_path):
     v1 = json.dumps({"version": 1, "n_threshold": 4, "pr_avg_row": 32.0,
                      "sr_cv": 0.5})
     th1 = SelectorThresholds.from_json(v1)
-    assert th1.geometries == () and th1.max_win == 4096
+    assert th1.geometries == ()
     # plain thresholds still write v1
     assert json.loads(SelectorThresholds().to_json())["version"] == 1
     with pytest.raises(ValueError):
         SelectorThresholds(geometries=(("k", (0, 32, 128)),)).validate()
     with pytest.raises(ValueError):
         TileGeometry(512, 12, 128).validate()          # wb not sublane-aligned
+
+
+#: one non-default field per schema version, as each version's writer
+#: emitted it: the lowest version that holds the field
+_VERSIONED = {2: {"overlap_min_n": 64}, 3: {"quant_min_n": 8},
+              4: {"chain_fuse_min_n": 16}, 5: {"attn_fuse_min_seq": 256}}
+
+
+@pytest.mark.parametrize("version", [2, 3, 4, 5])
+def test_thresholds_file_with_max_win_loads(version):
+    """A persisted file of each version that still carries ``max_win``
+    (as older releases wrote it) loads with the key ignored, and
+    saving it again writes the same version without that key."""
+    th = SelectorThresholds(**_VERSIONED[version])
+    d = json.loads(th.to_json())
+    assert d["version"] == version and "max_win" not in d
+    legacy = dict(d, max_win=512)
+    loaded = SelectorThresholds.from_json(json.dumps(legacy))
+    assert loaded == th
+    assert json.loads(loaded.to_json()) == d
 
 
 def test_geometry_distinct_cache_entries(rng):
@@ -407,34 +438,20 @@ def test_autotuner_picks_up_in_plan(rng):
     assert m3.plan is m2.plan        # same bucket ⇒ same resolved geometry
 
 
-def test_modeled_traffic_fused_wins_on_skew():
-    from repro.kernels.tune import modeled_traffic
-    csr = rmat(8, 16, seed=11)                        # skewed 256x256
-    t = modeled_traffic(csr, 128)
-    assert t["fused_bytes"] < t["spill_bytes"]
-    assert t["bytes_reduction"] > 1.0
-    assert t["fused_ai"] > t["spill_ai"]
-
-
-def test_pathological_span_falls_back_to_xla(rng):
-    """A tile spanning a 5000-row gap no longer demotes the plan: the fused
-    kernels size work per output block, so the plan stays on Pallas and
-    only the spill window's ``max_win`` guard warns (at prep)."""
+def test_pathological_span_stays_on_pallas(rng):
+    """A tile spanning a 5,000-row gap stays on Pallas: the kernels size
+    work per output block, so the plan is correct and warns nothing."""
     a = np.zeros((5000, 16), np.float32)
     a[0, :4] = 1.0
     a[4999, 0] = 1.0                                   # 5000-row gap in 1 tile
     csr = csr_from_dense(a)
-    p = plan(csr, backend="pallas")
-    assert p.backend == "pallas"
     x = jnp.asarray(rng.standard_normal((16, 3)).astype(np.float32))
-    with pytest.warns(UserWarning, match="max_win"):
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")
+        p = plan(csr, backend="pallas")
         got = np.asarray(execute(p, x, impl="nb_pr", interpret=True))
-    np.testing.assert_allclose(got, a @ np.asarray(x), atol=2e-3)
-    # a permissive bound silences the spill-window guard
-    th = SelectorThresholds(max_win=1 << 20)
-    p2 = plan(csr, backend="pallas", thresholds=th)
-    assert p2.backend == "pallas"
-    got = np.asarray(execute(p2, x, impl="nb_pr", interpret=True))
+    assert p.backend == "pallas"
+    assert p.substrate("balanced").n_tiles == 1
     np.testing.assert_allclose(got, a @ np.asarray(x), atol=2e-3)
 
 

@@ -414,23 +414,6 @@ def test_quant_range_demotion_and_sentinel_raise():
             p2.substrate("balanced")
 
 
-def test_max_win_demotion_counters():
-    """``max_win`` guards only the spill window now: a plan over the bound
-    stays on Pallas (single-device and sharded inner), counts no demotion,
-    and the spill-window guard warns when the prep runs."""
-    csr, x = _mat(seed=16, m=16, k=12, density=0.3)
-    th = dataclasses.replace(default_thresholds(), max_win=1)
-    p = plan(csr, backend="pallas", thresholds=th)
-    assert p.backend == "pallas"
-    with pytest.warns(UserWarning, match="max_win"):
-        execute(p, x, impl="nb_pr", interpret=True)
-    mesh = make_local_mesh(jax.device_count(), 1)
-    ps = plan(csr, mesh=mesh, inner_backend="pallas", thresholds=th)
-    assert ps.inner_backend == "pallas"
-    assert not [k for k in G.HEALTH.snapshot()["counters"]
-                if k.startswith("demote:max_win")]
-
-
 def test_fuse_crossover_counters():
     rng = np.random.default_rng(17)
     csr, _ = random_csr(rng, 12, 10, 0.4)

@@ -6,7 +6,7 @@ import jax.numpy as jnp
 
 from repro.core import (csr_from_dense, csr_to_balanced, csr_to_bsr,
                         csr_to_ell, rmat)
-from repro.kernels import spmm_bsr, spmm_csc, spmm_vsr, spmv_vsr
+from repro.kernels import spmm_bsr, spmm_csc, spmm_vsr_fused, spmv_vsr_fused
 from repro.kernels.ref import (ref_spmm_balanced, ref_spmm_bsr, ref_spmm_csr,
                                ref_spmm_ell)
 
@@ -30,7 +30,7 @@ def test_vsr_sweep(rng, n, dtype):
     for csr, a in _mats(rng):
         bal = csr_to_balanced(csr, tile=128)
         x = rng.standard_normal((csr.shape[1], n)).astype(dtype)
-        got = np.asarray(spmm_vsr(bal, jnp.asarray(x), interpret=True))
+        got = np.asarray(spmm_vsr_fused(bal, jnp.asarray(x), interpret=True))
         ref = np.asarray(ref_spmm_balanced(bal, jnp.asarray(x)))
         np.testing.assert_allclose(got, ref, atol=2e-3)
 
@@ -60,7 +60,7 @@ def test_spmv_sweep(rng):
     for csr, a in _mats(rng):
         bal = csr_to_balanced(csr, tile=128)
         x = rng.standard_normal(csr.shape[1]).astype(np.float32)
-        got = np.asarray(spmv_vsr(bal, jnp.asarray(x), interpret=True))
+        got = np.asarray(spmv_vsr_fused(bal, jnp.asarray(x), interpret=True))
         ref = np.asarray(ref_spmm_csr(csr, jnp.asarray(x)))
         np.testing.assert_allclose(got, ref, atol=2e-3)
 
@@ -69,7 +69,7 @@ def test_vsr_bf16(rng):
     csr, a = random_csr(rng, 64, 64, 0.2)
     bal = csr_to_balanced(csr, tile=64)
     x = rng.standard_normal((64, 8)).astype(np.float32)
-    got = np.asarray(spmm_vsr(
+    got = np.asarray(spmm_vsr_fused(
         csr_to_balanced(csr, tile=64), jnp.asarray(x, jnp.bfloat16),
         interpret=True)).astype(np.float32)
     np.testing.assert_allclose(got, a @ x, atol=0.15, rtol=0.05)
@@ -81,23 +81,8 @@ def test_skewed_rmat_kernels():
     rng = np.random.default_rng(0)
     x = rng.standard_normal((csr.shape[1], 16)).astype(np.float32)
     ref = np.asarray(ref_spmm_csr(csr, jnp.asarray(x)))
-    got_v = np.asarray(spmm_vsr(csr_to_balanced(csr, tile=128),
-                                jnp.asarray(x), interpret=True))
+    got_v = np.asarray(spmm_vsr_fused(csr_to_balanced(csr, tile=128),
+                                      jnp.asarray(x), interpret=True))
     got_c = np.asarray(spmm_csc(csr_to_ell(csr), jnp.asarray(x), interpret=True))
     np.testing.assert_allclose(got_v, ref, atol=2e-3)
     np.testing.assert_allclose(got_c, ref, atol=2e-3)
-
-
-def test_window_planner():
-    from repro.kernels.vsr import plan_windows
-    csr = rmat(7, 4, seed=5)
-    bal = csr_to_balanced(csr, tile=64)
-    base, win = plan_windows(bal)
-    rows = np.asarray(bal.rows)
-    m = bal.shape[0]
-    assert win % 8 == 0
-    for t in range(bal.n_tiles):
-        valid = rows[t][rows[t] < m]
-        if len(valid):
-            assert base[t] == rows[t][0]
-            assert valid.max() - base[t] < win, "window must cover tile span"

@@ -94,7 +94,7 @@ def test_planning_and_tracing_record_their_spans():
         jax.ShapeDtypeStruct((A.shape[1], 16), jnp.float32))
     spans = api.health()["spans"]
     for name in ("repro.plan", "repro.plan.stats", "repro.plan.substrate",
-                 "repro.plan.prep", "repro.plan.windows", "repro.plan.visits",
+                 "repro.plan.prep", "repro.plan.visits",
                  "repro.plan.fingerprint", "repro.execute.trace"):
         assert spans[name]["count"] >= 1, name
     assert spans["repro.plan.substrate"]["args"] == {"kind=balanced": 1}
@@ -213,9 +213,9 @@ def test_work_names_the_gather_unroll():
     bal = csr_to_balanced(rmat(8, 8, seed=5), tile=64)
     m = bal.shape[0]
     vt, vb, _ = plan_visits(bal, 16)
-    for x, spill in (((m, 128), False), ((m, 128), True), ((m,), False)):
+    for x in ((m, 128), (m,)):
         _, work = _nb_work(bal, jax.ShapeDtypeStruct(x, jnp.float32),
-                           visit_tile=vt, visit_block=vb, wb=16, spill=spill)
+                           visit_tile=vt, visit_block=vb, wb=16)
         assert work.get("gather_unroll") == (GATHER_UNROLL if len(x) > 1
                                              else None)
     name, work = sddmm_work(bal.rows, jax.ShapeDtypeStruct((m, 1024),

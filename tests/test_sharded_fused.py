@@ -1,6 +1,6 @@
 """Sharded fused visit-schedule path (core/shard.py, DESIGN.md §7):
-stacked-schedule invariants, fused-vs-spill and fused-vs-single-device
-parity (outputs and grads), empty-row shards, a single-shard mesh, bf16,
+stacked-schedule invariants, fused-vs-single-device parity (outputs and
+grads), empty-row shards, a single-shard mesh, bf16,
 the width-chunked ppermute ring, and the plan-free pattern entry's fused
 routing.
 
@@ -8,6 +8,8 @@ Runs on however many devices the host exposes (1 locally; the CI
 multi-device job forces 8 virtual CPU devices via
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``); schedule-stacking
 invariants use a fake 8-shard mesh so raggedness is exercised regardless."""
+import dataclasses
+
 import numpy as np
 import pytest
 import jax
@@ -17,9 +19,10 @@ from repro.core import (SelectorThresholds, csr_from_dense, execute,
                         execute_pattern, make_shard_spec, matrix_stats, plan,
                         rmat)
 from repro.core.formats import BalancedCOO
+from repro.core import registry
 from repro.core.shard import (VISIT_PAD, _INNER_BOUND, _INNER_BOUND_CAP,
-                              _make_inner, build_sharded_substrate,
-                              stack_visit_schedules)
+                              _make_inner, _sharded_prep,
+                              build_sharded_substrate, stack_visit_schedules)
 from repro.core.registry import resolve
 from repro.kernels.vsr import plan_visits
 from repro.launch.mesh import make_local_mesh
@@ -48,15 +51,6 @@ def _dense_of(csr):
     rows = np.repeat(np.arange(m), np.diff(indptr))
     a[rows, np.asarray(csr.indices)] = np.asarray(csr.data)
     return a
-
-
-def _spill_plan(csr, *, kind, tile=64, thresholds=None):
-    """A sharded Pallas plan forced onto the spill inner path (the parity
-    reference): flip the prep opts before the bound kernel is built."""
-    p = plan(csr, backend="sharded", mesh=_mesh(), shard_kind=kind, tile=tile,
-             inner_backend="pallas", thresholds=thresholds)
-    p.kernel_opts(p.entry("nb_pr"))["spill"] = True
-    return p
 
 
 # ---------------------------------------------------------------------------
@@ -100,16 +94,39 @@ def test_sharded_prep_stacks_schedules_and_windows():
              inner_backend="pallas")
     opts = p.kernel_opts(p.entry("nb_pr"))
     n = p.shard_spec.n_shards
-    assert {"row_base", "win", "visit_tile", "visit_block", "visit_start",
-            "wb", "tile_n", "overlap_min_n"} <= set(opts)
+    assert set(opts) == {"visit_tile", "visit_block", "visit_start",
+                         "wb", "tile_n", "overlap_min_n"}
     assert opts["visit_tile"].shape[0] == n
-    assert opts["row_base"].shape[0] == n
     assert opts["visit_tile"].shape == opts["visit_block"].shape \
         == opts["visit_start"].shape
 
 
+@pytest.mark.parametrize("bad", ["unknown_key", "no_schedule"])
+def test_sharded_prep_rejects_unstackable_opt(monkeypatch, bad):
+    """Every opt an inner prep emits needs a stacking rule, and the
+    schedule must be there: the sharded prep raises rather than run the
+    kernel without an opt it was meant to get."""
+    entry = resolve("nb_pr", "pallas")
+
+    def prep(bal, *, geometry=None):
+        opts = entry.prep(bal, geometry=geometry)
+        if bad == "unknown_key":
+            return dict(opts, stray_opt=np.zeros(4, np.int32))
+        return {"wb": opts["wb"], "tile_n": opts["tile_n"]}
+
+    monkeypatch.setitem(registry._REGISTRY, ("nb_pr", "pallas"),
+                        dataclasses.replace(entry, prep=prep))
+    csr = _skewed_csr()
+    spec = make_shard_spec(matrix_stats(csr), _FakeMesh(4), kind="nnz")
+    sub = build_sharded_substrate(csr, spec, _FakeMesh(4),
+                                  inner_kind="balanced", tile=64,
+                                  inner_backend="pallas")
+    with pytest.raises(ValueError, match="cannot stack prep opts"):
+        _sharded_prep(sub, _logical="nb_pr")
+
+
 # ---------------------------------------------------------------------------
-# parity: fused vs spill vs single-device, outputs and grads
+# parity: sharded fused vs single-device, outputs and grads
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("kind", ["row", "nnz"])
@@ -118,16 +135,13 @@ def test_sharded_fused_matches_spill_and_single_device(kind):
     p_one = plan(csr)
     p_fused = plan(csr, backend="sharded", mesh=_mesh(), shard_kind=kind,
                    tile=64, inner_backend="pallas")
-    p_spill = _spill_plan(csr, kind=kind)
     rng = np.random.default_rng(0)
     for n in (1, 8):
         shape = (csr.shape[1],) if n == 1 else (csr.shape[1], n)
         x = jnp.asarray(rng.standard_normal(shape).astype(np.float32))
         want = np.asarray(execute(p_one, x, impl="nb_pr"))
         got_f = np.asarray(execute(p_fused, x, impl="nb_pr", interpret=True))
-        got_s = np.asarray(execute(p_spill, x, impl="nb_pr", interpret=True))
         np.testing.assert_allclose(got_f, want, atol=2e-3)
-        np.testing.assert_allclose(got_f, got_s, atol=2e-3)
 
 
 @pytest.mark.parametrize("kind", ["row", "nnz"])
@@ -136,7 +150,6 @@ def test_sharded_fused_grads_match(kind):
     p_one = plan(csr)
     p_fused = plan(csr, backend="sharded", mesh=_mesh(), shard_kind=kind,
                    tile=64, inner_backend="pallas")
-    p_spill = _spill_plan(csr, kind=kind)
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((csr.shape[1], 6)).astype(np.float32))
 
@@ -145,13 +158,9 @@ def test_sharded_fused_grads_match(kind):
                                       interpret=interpret) ** 2).sum()
 
     gv, gx = jax.grad(loss(p_fused, True), argnums=(0, 1))(csr.data, x)
-    sv, sx = jax.grad(loss(p_spill, True), argnums=(0, 1))(csr.data, x)
     rv, rx = jax.grad(loss(p_one, None), argnums=(0, 1))(csr.data, x)
-    for got in ((gv, gx), (sv, sx)):
-        np.testing.assert_allclose(np.asarray(got[0]), np.asarray(rv),
-                                   atol=1e-2)
-        np.testing.assert_allclose(np.asarray(got[1]), np.asarray(rx),
-                                   atol=1e-2)
+    np.testing.assert_allclose(np.asarray(gv), np.asarray(rv), atol=1e-2)
+    np.testing.assert_allclose(np.asarray(gx), np.asarray(rx), atol=1e-2)
 
 
 def test_sharded_fused_empty_row_shard():
@@ -301,11 +310,13 @@ def test_inner_bound_cache_is_bounded():
     before = dict(_INNER_BOUND)
     try:
         for i in range(_INNER_BOUND_CAP + 16):
-            _make_inner(entry, None, {"win": 8 * (i + 1)}, ("row_base",))
+            _make_inner(entry, None, {"wb": 8 * (i + 1), "tile_n": 128},
+                        ("visit_tile",))
         assert len(_INNER_BOUND) <= _INNER_BOUND_CAP
         # LRU: re-touching keeps an entry alive
-        fn = _make_inner(entry, None, {"win": 8}, ("row_base",))
-        assert _make_inner(entry, None, {"win": 8}, ("row_base",)) is fn
+        statics = {"wb": 8, "tile_n": 128}
+        fn = _make_inner(entry, None, statics, ("visit_tile",))
+        assert _make_inner(entry, None, statics, ("visit_tile",)) is fn
     finally:
         _INNER_BOUND.clear()
         _INNER_BOUND.update(before)

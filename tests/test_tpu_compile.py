@@ -104,13 +104,15 @@ def test_spmm_compiles(no_cache, one_chip, graph, impl, n):
 
 
 @pytest.mark.parametrize("n", [128, 1])
-def test_spill_kernels_compile(no_cache, one_chip, graph, n):
-    """The spill-and-combine parity path of the nnz-balanced kernels."""
+def test_quantized_nb_kernels_compile(no_cache, one_chip, graph, n):
+    """An int8 value stream through the nnz-balanced kernels: the stream is
+    quantized in graph and each kernel rescales it in register from the
+    per-tile scales it takes as a fourth scalar-prefetch operand."""
     k = graph.shape[1]
     x = jax.ShapeDtypeStruct((k, n) if n > 1 else (k,), jnp.float32)
     _compile_matmul(graph.plan, "nb_pr", x, one_chip,
-                    "repro_vsr_spill" if n > 1 else "repro_spmv_spill",
-                    spill=True)
+                    "repro_vsr_fused" if n > 1 else "repro_spmv_fused",
+                    quant="int8")
 
 
 def test_bsr_compiles(no_cache, one_chip):
@@ -128,7 +130,7 @@ def test_kernel_names_are_unique():
         text = path.read_text()
         calls += text.count("pl.pallas_call(")
         names += re.findall(r'name="(repro_\w+)"', text)
-    assert calls == len(names) == 10
+    assert calls == len(names) == 8
     assert len(set(names)) == len(names), names
 
 
