@@ -1236,8 +1236,9 @@ class PatternWork:
     ``forward`` and ``transpose``: the fused SpMM's work hook
     (``kernels/vsr._nb_work``) over the forward and the transposed
     schedules at one column block, summed over the stacked layers, as
-    ``(key, value)`` pairs; ``rows_gathered`` scales with the column blocks
-    of the call's N.  ``tiles``: the forward's tiles over all layers, which
+    ``(key, value)`` pairs; at the call's N, ``column_blocks`` is the
+    kernel's (``kernels/vsr.gather_width``) and ``rows_gathered`` scales
+    with it.  ``tiles``: the forward's tiles over all layers, which
     the SDDMM of the value gradient loads once per feature block.  The
     topologies are digests of the stacked forward and transposed index
     streams, so the two SpMMs keep records of their own."""
@@ -1248,15 +1249,16 @@ class PatternWork:
     transpose: tuple
     tiles: int
     tile: int
-    tile_n: int
 
     def _record(self, kernel, topology, work, n):
         HEALTH.record_work(f"{kernel}:{topology[:12]}:n{n}",
                            dict(work, kernel=kernel, topology=topology, n=n))
 
     def _spmm(self, counts, n):
-        work = dict(counts)
-        work["rows_gathered"] *= -(-n // self.tile_n)
+        from repro.kernels.vsr import gather_width
+        blocks = -(-n // gather_width(self.tile, n))
+        work = dict(counts, column_blocks=blocks)
+        work["rows_gathered"] *= blocks
         return work
 
     def record_forward(self, n: int) -> None:
@@ -1311,7 +1313,8 @@ def pattern_prep(rows, cols, shape: tuple) -> dict:
     a model): every array of the result then carries that axis too, the
     visit schedules padded to one length with no-op visits, so a layer's
     slice is its own prep.  Entries: ``visit_tile``, ``visit_block``,
-    ``visit_start`` (arrays), ``wb``, ``tile_n`` (the geometry), ``work``
+    ``visit_start`` (arrays), ``wb`` (the geometry's; the column blocks
+    follow the call's N), ``work``
     (a ``PatternWork``), ``transpose_perm``, ``transpose_rows``,
     ``transpose_cols`` and ``transpose_visit_*`` — ``transpose_pattern``
     and the transposed pattern's schedule, built here once (span
@@ -1331,15 +1334,17 @@ def pattern_prep(rows, cols, shape: tuple) -> dict:
     def schedule(r, c, shp):
         bal = BalancedCOO(r, c, None, shp)
         vt, vb, vs = plan_visits(bal, geom.wb)
+        # at 128 columns: one column block
         kernel, work = _nb_work(
-            bal, jax.ShapeDtypeStruct((shp[1], geom.tile_n), jnp.float32),
-            visit_tile=vt, visit_block=vb, wb=geom.wb, tile_n=geom.tile_n)
+            bal, jax.ShapeDtypeStruct((shp[1], 128), jnp.float32),
+            visit_tile=vt, visit_block=vb, wb=geom.wb)
         return (vt, vb, vs), kernel, work
 
     def summed(works):
-        # counts add up over the layers; the issue loop's unroll is the
-        # kernel's own
-        return tuple((key, works[0][key] if key == "gather_unroll"
+        # counts add up over the layers; the issue loop's unroll and the
+        # column blocks are the kernel's own
+        return tuple((key, works[0][key]
+                      if key in ("gather_unroll", "column_blocks")
                       else sum(w[key] for w in works)) for key in works[0])
 
     def device(a):
@@ -1349,7 +1354,7 @@ def pattern_prep(rows, cols, shape: tuple) -> dict:
     prep = {f"visit_{name}": device(a) for name, a in zip(
         ("tile", "block", "start"),
         stack_visit_schedules([s for s, _, _ in fwd]))}
-    prep.update(wb=geom.wb, tile_n=geom.tile_n)
+    prep["wb"] = geom.wb
     with HEALTH.span("repro.plan.transpose"):
         trans = [transpose_pattern(r, c, (m, k))
                  for r, c in zip(rows_np, cols_np)]
@@ -1375,7 +1380,7 @@ def pattern_prep(rows, cols, shape: tuple) -> dict:
         transpose_topology=_opts_digest({"rows": trans["rows"],
                                          "cols": trans["cols"]}),
         transpose=summed([w for _, _, w in tsched]),
-        tiles=int(np.prod(rows_np.shape[:2])), tile=t, tile_n=geom.tile_n)
+        tiles=int(np.prod(rows_np.shape[:2])), tile=t)
     return prep
 
 

@@ -44,7 +44,9 @@ class TileGeometry:
 
     ``tile`` is the nnz quota per BalancedCOO tile (the paper's warp quota),
     ``wb`` the fused kernel's output-block row height (sublane-aligned), and
-    ``tile_n`` the dense-column block width (lane-aligned).  The winning
+    ``tile_n`` the dense-column block width (lane-aligned) of the fused
+    chain and attention kernels; the nnz-balanced and row-split SpMMs take
+    theirs from the call's N (``kernels/vsr.gather_width``).  The winning
     geometry shifts with sparsity pattern and N (Hu et al., PAPERS.md), so
     geometries are *measured* per (pattern, N-bucket, backend) by
     ``repro.kernels.tune.autotune_geometry`` and persisted on

@@ -472,7 +472,7 @@ def _overlapped_ring(run_chunk, x, chunk_w: int, axis: str, n_shards: int):
 
 def _sharded_exec(sub: ShardedSubstrate, x, *, _logical: str,
                   interpret=None, visit_tile=None, visit_block=None,
-                  visit_start=None, wb=None, tile_n=None, overlap_min_n=None,
+                  visit_start=None, wb=None, overlap_min_n=None,
                   quant: str | None = None):
     """Run the inner kernel per shard under shard_map; reduce per the spec.
 
@@ -486,8 +486,7 @@ def _sharded_exec(sub: ShardedSubstrate, x, *, _logical: str,
     inner = registry.resolve(_logical, sub.inner_backend)
     statics, tensor_keys, tensors = {}, (), []
     if visit_tile is not None:
-        statics = {k: v for k, v in (("wb", wb), ("tile_n", tile_n))
-                   if v is not None}
+        statics = {} if wb is None else {"wb": wb}
         tensor_keys = _SCHEDULE_KEYS
         tensors = [visit_tile, visit_block, visit_start]
     if sub.inner_kind == "balanced" and sub.scales is not None:
@@ -513,8 +512,9 @@ def _sharded_exec(sub: ShardedSubstrate, x, *, _logical: str,
     out_specs = P(spec.axis) if spec.reduction == "concat" else P()
 
     # overlap decision (DESIGN.md §7): chunk the width axis and ring-reduce
-    # only where there is a collective to hide and enough width to chunk
-    chunk_w = tile_n if tile_n is not None else 128
+    # only where there is a collective to hide and enough width to chunk;
+    # a chunk is one lane-wide column block of the inner kernel
+    chunk_w = 128
     chunked = (spec.reduction == "psum" and spec.n_shards > 1
                and overlap_min_n is not None and x.ndim == 2
                and x.shape[1] >= max(int(overlap_min_n), chunk_w + 1))

@@ -30,10 +30,10 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import registry
 from repro.core.formats import BalancedCOO
 from repro.core.selector import TileGeometry
-from .fused_chain import (_pad_2d, fold_stats, gather_endpoints,
+from .fused_chain import (_pad_2d, _prep_chain, fold_stats, gather_endpoints,
                           gather_scratch, row_stats, softmax_weights,
                           stats_blocks, tile_scores)
-from .vsr import (_pad_n, _prep_visits, accumulate_visit, as_tiles,
+from .vsr import (_pad_n, accumulate_visit, as_tiles,
                   column_blocks, hbm_spec, mxu_dot, new_tile, plan_visits,
                   segment_onehot, start_row_gather, tile_spec,
                   wait_row_gather)
@@ -214,4 +214,4 @@ def attn_chain_pallas(rows, cols, q, k, bias, x, *,
 
 
 registry.register("attn_chain", "pallas", "balanced", attn_chain_pallas,
-                  prep=_prep_visits)
+                  prep=_prep_chain)

@@ -15,7 +15,8 @@ TPU adaptation (see DESIGN.md §2):
     (SMEM for the column ids) once per grid step, and the dense rows it
     names are fetched by the same DMA row gather as VSR (``kernels/vsr.py``).
   * "each lane owns a dense column" → the lane dimension of the ``(wb,
-    tile_n)`` output block carries ``tile_n`` dense columns; the walk over
+    w)`` output block carries ``w`` dense columns (``vsr.gather_width``:
+    all of N where the gather slots fit VMEM); the walk over
     the cached nonzeros is one segment-one-hot matmul, so every column
     advances in lockstep — the CSC schedule on the MXU.
 
@@ -110,25 +111,24 @@ def ell_row_tiles(ell: ELL, tm: int, tw: int):
     return bal, (vt, vt // nw, (vt % nw == 0).astype(jnp.int32))
 
 
-def _run_tiles(bal: BalancedCOO, x, schedule, *, wb, tile_n, interpret):
+def _run_tiles(bal: BalancedCOO, x, schedule, *, wb, interpret):
     vt, vb, vs = schedule
     if x.ndim == 1:
         return spmv_vsr_fused(bal, x, interpret=interpret, wb=wb,
                               visit_tile=vt, visit_block=vb, visit_start=vs)
-    return spmm_vsr_fused(bal, x, interpret=interpret, wb=wb, tile_n=tile_n,
+    return spmm_vsr_fused(bal, x, interpret=interpret, wb=wb,
                           visit_tile=vt, visit_block=vb, visit_start=vs)
 
 
 def spmm_csc(ell: ELL, x: jax.Array, *, tm: int = 8, tw: int = 128,
-             tile_n: int = 128, interpret: bool | None = None) -> jax.Array:
+             interpret: bool | None = None) -> jax.Array:
     """RS+SR SpMM on the ELL substrate. ``tm`` is the output block height
     (a multiple of 8); the ELL width is walked in ``tw``-wide chunks,
     rounded so a tile (``tm * tw`` slots) fills whole 128-lane vregs."""
     lane_rows = 128 // np.gcd(tm, 128)
     tw = -(-min(tw, ell.width) // lane_rows) * lane_rows
     bal, schedule = ell_row_tiles(ell, tm, tw)
-    return _run_tiles(bal, x, schedule, wb=tm, tile_n=tile_n,
-                      interpret=interpret)
+    return _run_tiles(bal, x, schedule, wb=tm, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -143,14 +143,13 @@ def spmm_csc(ell: ELL, x: jax.Array, *, tm: int = 8, tw: int = 128,
 def _prep_row_tiles(bal: BalancedCOO, *,
                     geometry: TileGeometry | None = None) -> dict:
     geom = (geometry or TileGeometry()).validate()
-    return {**plan_row_tiles(bal, geom.wb), "wb": geom.wb,
-            "tile_n": geom.tile_n}
+    return {**plan_row_tiles(bal, geom.wb), "wb": geom.wb}
 
 
 def _pallas_rs(sub, x, scales=None, *, interpret: bool | None = None,
                rt_rows=None, rt_cols=None, rt_src=None, visit_tile=None,
                visit_block=None, visit_start=None, wb: int | None = None,
-               tile_n: int | None = None, quant: str | None = None):
+               quant: str | None = None):
     if isinstance(sub, ELL):
         return spmm_csc(sub, x, interpret=interpret)
     if rt_src is None:
@@ -164,7 +163,7 @@ def _pallas_rs(sub, x, scales=None, *, interpret: bool | None = None,
                         0).astype(flat.dtype)
     tiles = BalancedCOO(rt_rows, rt_cols, rt_vals, sub.shape)
     return _run_tiles(tiles, x, (visit_tile, visit_block, visit_start),
-                      wb=wb, tile_n=tile_n, interpret=interpret)
+                      wb=wb, interpret=interpret)
 
 
 registry.register("rs_sr", "pallas", "balanced", _pallas_rs,

@@ -48,9 +48,9 @@ from repro.core.selector import TileGeometry
 from repro.core.spmm import SOFTMAX_EPS, SOFTMAX_NEG
 
 from .vsr import (GATHER_UNROLL, _pad_n, _prep_visits, accumulate_visit,
-                  as_tiles, block_hits, column_blocks, hbm_spec, mxu_dot,
-                  new_tile, plan_visits, segment_onehot, start_row_gather,
-                  tile_spec, wait_row_gather)
+                  as_tiles, block_hits, column_blocks, gather_width,
+                  hbm_spec, mxu_dot, new_tile, plan_visits, segment_onehot,
+                  start_row_gather, tile_spec, wait_row_gather)
 
 #: per-row transforms the chain supports between its SDDMM and SpMM halves
 CHAIN_TRANSFORMS: tuple[str, ...] = ("identity", "scale", "softmax")
@@ -156,20 +156,6 @@ def stats_blocks(rm, rs, wb: int):
 # accumulated tile-in-place over the feature blocks
 # ---------------------------------------------------------------------------
 
-#: bytes of VMEM the standalone SDDMM's two gathered ``(T, feature block)``
-#: slabs may take together; wider features are cut into blocks
-SDDMM_GATHER_BYTES = 4 << 20
-
-
-def sddmm_feature_block(t: int, d: int) -> int:
-    """Feature columns per block of the standalone SDDMM: all of ``d``
-    (lane-padded) when both gathered ``(t, d)`` f32 slabs fit
-    ``SDDMM_GATHER_BYTES``, else the widest multiple of 128 that does."""
-    d_pad = -(-d // 128) * 128
-    fit = max(128, SDDMM_GATHER_BYTES // (2 * 4 * t) // 128 * 128)
-    return min(d_pad, fit)
-
-
 def _sddmm_kernel(rows_s, cols_s, a_hbm, b_hbm, o_ref, ag, bg, sems, *, m):
     jd = pl.program_id(1)
     gather_endpoints(rows_s, cols_s, a_hbm.at[jd], b_hbm.at[jd], ag, bg,
@@ -224,12 +210,13 @@ def sddmm_pallas(rows, cols, a, b, *, interpret: bool | None = None,
     """Pallas SDDMM over a balanced slab: f32 edge scores shaped like
     ``rows`` (sentinel entries score 0).  Needs no visit schedule — scores
     are tile-local — so it works on traced patterns with no prep hook.
-    Features wider than ``sddmm_feature_block`` allows are cut into blocks
-    whose partial scores add up in the tile's output block."""
+    Features wider than ``gather_width`` allows (both gathered ``(T,
+    block)`` slabs within ``GATHER_BYTES``) are cut into equal blocks whose
+    partial scores add up in the tile's output block."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     m = int(shape[0])
-    dblk = sddmm_feature_block(rows.shape[-1], a.shape[1])
+    dblk = gather_width(rows.shape[-1], a.shape[1])
     return _sddmm_call(rows, cols, _feature_blocks(a, dblk),
                        _feature_blocks(b, dblk), m=m, interpret=interpret)
 
@@ -242,7 +229,7 @@ def sddmm_work(rows, a) -> tuple[str, dict]:
     copies one iteration of the gathers' issue loop starts."""
     n_tiles, t = rows.shape
     d = a.shape[1]
-    loads = n_tiles * -(-d // sddmm_feature_block(t, d))
+    loads = n_tiles * -(-d // gather_width(t, d))
     return "repro_sddmm", {"visits": loads, "tile_loads": loads,
                            "rows_gathered": 2 * t * loads,
                            "gather_unroll": GATHER_UNROLL}
@@ -408,7 +395,7 @@ def chain_pallas(rows, cols, a, b, x, *, interpret: bool | None = None,
                  wb: int | None = None, tile_n: int | None = None,
                  stats=None, **_opts):
     """Fused SDDMM→``transform``→SpMM over one visit schedule: edge scores
-    never touch HBM.  The schedule may be precomputed (``_prep_visits`` at
+    never touch HBM.  The schedule may be precomputed (``_prep_chain`` at
     plan time) so the call stays traceable; ``stats`` substitutes externally
     combined softmax statistics (the sharded backend's cross-shard merge)."""
     if transform not in CHAIN_TRANSFORMS:
@@ -446,6 +433,15 @@ def chain_pallas(rows, cols, a, b, x, *, interpret: bool | None = None,
     return y[:, 0] if x.ndim == 1 else y
 
 
+def _prep_chain(bal: BalancedCOO, *, geometry: TileGeometry | None = None
+                ) -> dict:
+    """Prep hook of the chain and attention kernels: the visit schedule,
+    and the geometry's column-block width ``tile_n``, which these kernels
+    still take from the plan."""
+    geom = (geometry or TileGeometry()).validate()
+    return dict(_prep_visits(bal, geometry=geom), tile_n=geom.tile_n)
+
+
 registry.register("sddmm", "pallas", "balanced", sddmm_pallas)
 registry.register("chain", "pallas", "balanced", chain_pallas,
-                  prep=_prep_visits)
+                  prep=_prep_chain)

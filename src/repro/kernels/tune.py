@@ -33,8 +33,10 @@ from repro.core.selector import (SelectorThresholds, TileGeometry,
 
 from .vsr import plan_visits
 
-#: the default measured sweep: nnz quota x output-block rows, lane width
-#: fixed at the MXU's 128 (wider tile_n only pays off at very large N).
+#: the default measured sweep: nnz quota x output-block rows.  ``tile_n``
+#: stays at 128: the fused SpMM takes its column-block width from the call's
+#: N (``vsr.gather_width``), and only the fused chain and attention kernels
+#: read the geometry's.
 DEFAULT_CANDIDATES = (
     TileGeometry(tile=256, wb=32, tile_n=128),
     TileGeometry(tile=256, wb=64, tile_n=128),
@@ -148,7 +150,7 @@ def autotune_overlap(csr: CSR, mesh, *, ns: tuple = (256, 512, 1024),
     width-chunked ring beats the blocking psum becomes ``overlap_min_n``
     (``OVERLAP_NEVER`` when the ring never wins — e.g. a single-device mesh,
     where there is no collective to hide).  Widths at or below the ring's
-    chunk width (the geometry ``tile_n``, >= 128) cannot chunk — both runs
+    chunk width (128 columns) cannot chunk — both runs
     would execute the identical blocking psum and the comparison would be
     pure noise — so they are skipped.  Timing off-TPU is correctness-grade;
     run on a real pod before persisting fleet-wide."""

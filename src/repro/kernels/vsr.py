@@ -21,24 +21,26 @@ TPU adaptation (see DESIGN.md §2):
     visits, in VMEM.  Because no two grid steps run at once, no partials
     buffer and no post-kernel combine are needed.
   * VDL (§2.1.2) is a DMA row gather: ``X`` stays in HBM whatever its row
-    count, and each nonzero of a tile copies the ``tile_n``-wide slice of
-    the dense row its column id names into a ``(T, tile_n)`` VMEM scratch —
-    one logical load covers all N output columns (the V→N limit of float4).
+    count, and each nonzero of a tile copies the ``w``-wide slice of the
+    dense row its column id names into a ``(T, w)`` VMEM scratch — one
+    logical load covers ``w`` output columns (the V→N limit of float4).
+    ``w = gather_width(T, N)`` is all of N wherever the two gather slots
+    fit ``GATHER_BYTES``, so each nonzero's row is one copy.
     The column ids ride an SMEM block so the scalar core can address the
     copies, ``GATHER_UNROLL`` of them an iteration of its issue loop; all
     ``T`` copies are in flight before one wait retires them (a
     DMA semaphore counts bytes, so a descriptor the size of the scratch
     covers every row copy).  The fused kernel double-buffers the gather:
-    two ``(T, tile_n)`` slots, and on a tile's first visit it starts the
+    two ``(T, w)`` slots, and on a tile's first visit it starts the
     next tile's copies (their column ids come in a second SMEM block, one
     tile ahead) before it waits on its own, which the previous tile
     started, so the copies fly while the tile reduces.
 
 Layout: the index/value streams are ``(n_tiles, 1, T)`` so one tile is a
 legal ``(1, T)`` block; T is kept a multiple of 128 (lane width),
-``wb`` a multiple of 8 (sublanes); N is padded to ``tile_n`` by the
-wrapper and gathered operands are f32.  ``(T, wb, tile_n)`` is the measured
-tile geometry (``repro.kernels.tune``).
+``wb`` a multiple of 8 (sublanes); N is padded to column blocks of
+``gather_width`` by the wrapper and gathered operands are f32.  ``(T, wb)``
+come from the measured tile geometry (``repro.kernels.tune``).
 """
 from __future__ import annotations
 
@@ -124,6 +126,21 @@ def plan_visits(bal: BalancedCOO, wb: int
 #: are written out by hand.
 GATHER_UNROLL = 16
 
+#: bytes of VMEM a row-gather kernel's two gathered ``(T, w)`` f32 slabs may
+#: take together: the fused SpMM's two slots, the SDDMM's two endpoint slabs
+GATHER_BYTES = 4 << 20
+
+
+def gather_width(t: int, n: int) -> int:
+    """Columns a row copy of a ``T``-nonzero tile gathers: all of ``n``
+    (lane-padded) where two ``(t, n)`` f32 slabs fit ``GATHER_BYTES``, so
+    each nonzero's row is one copy; else ``n`` cut into the fewest equal
+    multiples of 128 that fit, so the last block pads the least."""
+    lanes = max(1, -(-n // 128))
+    fit = max(1, GATHER_BYTES // (2 * 4 * t) // 128)
+    blocks = -(-lanes // fit)
+    return -(-lanes // blocks) * 128
+
 
 def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None, row0=0):
     """Start the DMA row gather ``dst[row0 + i] = src[idx[i]]`` for each of
@@ -134,11 +151,12 @@ def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None, row0=0):
     ``src`` stays in HBM (``hbm_spec``) whatever its row count, and each
     nonzero fetches exactly the dense row its column id names, one DMA per
     row, all in flight at once.  A copy spans the full row width (the
-    tiled HBM layout takes single-row slices only at full width — column
-    blocks come from ``column_blocks``).  ``idx_ref`` is the tile's index
-    block in SMEM (``(1, T)``); ``clamp`` caps sentinel ids (padding rows of
-    a row-indexed gather) to a valid row; ``row0`` offsets the rows written
-    (one slot of a multi-tile scratch).
+    tiled HBM layout takes a single-row slice only at full width, and only
+    128 lanes wide — column blocks come from ``column_blocks``).
+    ``idx_ref`` is the tile's index block in SMEM (``(1, T)``); ``clamp``
+    caps sentinel ids (padding rows of a row-indexed gather) to a valid
+    row; ``row0`` offsets the rows written (one slot of a multi-tile
+    scratch).
 
     The scalar core issues one descriptor per copy, and that issue sets the
     gather's pace, so the copies go out in groups of ``GATHER_UNROLL``:
@@ -148,7 +166,8 @@ def start_row_gather(src_hbm, idx_ref, dst, sem, *, clamp=None, row0=0):
 
     A ``(rows, 1, w)`` ``src`` (and ``(rows, 1, w)`` ``dst``) holds each
     row as a tile row of its own, so a row of any width ``w`` is one
-    contiguous copy (the standalone SDDMM gathers its feature rows so)."""
+    contiguous copy (the SDDMM gathers its feature rows so, and the fused
+    SpMM its column blocks wider than 128)."""
     unit = len(src_hbm.shape) == 3
     t = idx_ref.shape[-1]
 
@@ -259,7 +278,7 @@ def as_tiles(a: jax.Array) -> jax.Array:
 # fused kernel: accumulation over revisited output blocks
 # ---------------------------------------------------------------------------
 
-def _vsr_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, tile_n, quant):
+def _vsr_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, quant):
     # with ``quant`` the per-tile scale rides the scalar-prefetch path as a
     # fourth prefetch operand (next to the visit schedule): the value stream
     # stays int8/fp8 all the way into VMEM and is rescaled *in register* —
@@ -309,10 +328,11 @@ def _vsr_fused_kernel(vt_ref, vb_ref, vs_ref, *refs, m, wb, tile_n, quant):
     if quant:
         vals = vals * sc_ref[cur]                         # in-register dequant
     # segment reduction as one weighted one-hot matmul on the MXU, restricted
-    # to this visit's (wb, tile_n) output block — boundary-crossing rows
+    # to this visit's (wb, w) output block — boundary-crossing rows
     # accumulate across consecutive visits in VMEM, no partials array.
     s = segment_onehot(rows_ref[...], vals, vb_ref[v] * wb, wb, m)
-    xs = xg[pl.ds(pl.multiple_of(cur % 2 * t, t), t), :]
+    xs = xg[pl.ds(pl.multiple_of(cur % 2 * t, t), t)]
+    xs = xs.reshape(t, xs.shape[-1])                      # unit rows: (T, w)
     accumulate_visit(o_ref, mxu_dot(s, xs), vs_ref[v])
 
 
@@ -333,6 +353,10 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
     # pipeline fetches this block a step ahead like any other
     next_tile = lambda j, v, vt, *pf: (  # noqa: E731
         jnp.minimum(vt[v] + 1, n_tiles - 1), 0, 0)
+    # the tiled HBM layout takes a single-row slice only 128 lanes wide; a
+    # wider block holds each row as a tile row of its own, one contiguous
+    # copy a row (``start_row_gather``'s unit copies)
+    row = (tile_n,) if tile_n == 128 else (1, tile_n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4 if quant else 3,
         grid=(nb, n_visits),
@@ -345,20 +369,19 @@ def _vsr_fused_call(vt, vb, vs, rows, cols, vals, x, scales=None, *, m, wb,
         ],
         out_specs=pl.BlockSpec((wb, tile_n),
                                lambda j, v, vt, vb, *pf: (vb[v], j)),
-        scratch_shapes=[pltpu.VMEM((2 * t, tile_n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2 * t, *row), jnp.float32),
                         pltpu.SemaphoreType.DMA((2,)),
                         pltpu.SMEM((1,), jnp.int32)],
     )
     prefetch = (vt, vb, vs, scales) if quant else (vt, vb, vs)
     out = pl.pallas_call(
-        functools.partial(_vsr_fused_kernel, m=m, wb=wb, tile_n=tile_n,
-                          quant=quant),
+        functools.partial(_vsr_fused_kernel, m=m, wb=wb, quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((mb * wb, n_pad), jnp.float32),
         name="repro_vsr_fused",
         interpret=interpret,
     )(*prefetch, as_tiles(rows), as_tiles(cols), as_tiles(cols),
-      as_tiles(vals), column_blocks(x, tile_n))
+      as_tiles(vals), column_blocks(x, tile_n).reshape(nb, k, *row))
     with jax.named_scope("repro.spmm.unpad"):
         return out[:m]
 
@@ -374,14 +397,15 @@ def _pad_n(x2: jax.Array, tile_n: int) -> jax.Array:
 
 
 def spmm_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
-                   wb: int | None = None, tile_n: int | None = None,
+                   wb: int | None = None,
                    interpret: bool | None = None,
                    visit_tile: jax.Array | None = None,
                    visit_block: jax.Array | None = None,
                    visit_start: jax.Array | None = None,
                    scales: jax.Array | None = None) -> jax.Array:
     """NB+PR SpMM over the visit schedule: no partials buffer, no
-    post-kernel combine.
+    post-kernel combine.  The column blocks are ``gather_width`` wide: one
+    block wherever the tile's two gather slots of all of N fit VMEM.
 
     The visit schedule may be precomputed (``plan_visits`` at plan time) so
     the call stays traceable when ``bal`` carries traced values.  With a
@@ -389,18 +413,16 @@ def spmm_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
     per-tile ``scales`` — dequant happens in register (DESIGN.md §8)."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    geom = TileGeometry()
-    wb = geom.wb if wb is None else wb
-    tile_n = geom.tile_n if tile_n is None else tile_n
+    wb = TileGeometry().wb if wb is None else wb
     x2 = x[:, None] if x.ndim == 1 else x
     n = x2.shape[1]
     if visit_tile is None or visit_block is None or visit_start is None:
         vt, vb, vs = plan_visits(bal, wb)
         visit_tile, visit_block, visit_start = map(jnp.asarray, (vt, vb, vs))
-    xp = _pad_n(x2, tile_n)
+    width = gather_width(bal.rows.shape[-1], n)
     y = _vsr_fused_call(visit_tile, visit_block, visit_start,
-                        bal.rows, bal.cols, bal.vals, xp, scales,
-                        m=bal.shape[0], wb=wb, tile_n=tile_n,
+                        bal.rows, bal.cols, bal.vals, _pad_n(x2, width),
+                        scales, m=bal.shape[0], wb=wb, tile_n=width,
                         interpret=interpret)
     with jax.named_scope("repro.spmm.unpad"):
         y = y[:, :n].astype(x2.dtype)
@@ -416,13 +438,13 @@ def spmm_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
 def _prep_visits(bal: BalancedCOO, *, geometry: TileGeometry | None = None
                  ) -> dict:
     """Prep hook of the visit-schedule kernels: the visit schedule and the
-    geometry it was cut for.  ``geometry`` is the plan's autotuned
-    ``TileGeometry`` (``None`` → defaults)."""
+    output-block height it was cut for.  ``geometry`` is the plan's
+    autotuned ``TileGeometry`` (``None`` → defaults); the column blocks
+    follow the call's N (``gather_width``)."""
     geom = (geometry or TileGeometry()).validate()
     vt, vb, vs = plan_visits(bal, geom.wb)
     return {"visit_tile": jnp.asarray(vt), "visit_block": jnp.asarray(vb),
-            "visit_start": jnp.asarray(vs),
-            "wb": geom.wb, "tile_n": geom.tile_n}
+            "visit_start": jnp.asarray(vs), "wb": geom.wb}
 
 
 def _pallas_nb(bal: BalancedCOO, x: jax.Array, scales: jax.Array | None = None,
@@ -430,8 +452,7 @@ def _pallas_nb(bal: BalancedCOO, x: jax.Array, scales: jax.Array | None = None,
                visit_tile: jax.Array | None = None,
                visit_block: jax.Array | None = None,
                visit_start: jax.Array | None = None,
-               wb: int | None = None, tile_n: int | None = None,
-               quant: str | None = None):
+               wb: int | None = None, quant: str | None = None):
     # Quantized-plan dispatch (DESIGN.md §8): a *baked* substrate arrives
     # already int8/fp8 with its plan-aux scales riding the custom-VJP extras
     # (positional ``scales``); a *live* float stream on a quantized plan
@@ -451,7 +472,7 @@ def _pallas_nb(bal: BalancedCOO, x: jax.Array, scales: jax.Array | None = None,
         return spmv_vsr_fused(bal, x, interpret=interpret, wb=wb,
                               visit_tile=visit_tile, visit_block=visit_block,
                               visit_start=visit_start, scales=scales)
-    return spmm_vsr_fused(bal, x, interpret=interpret, wb=wb, tile_n=tile_n,
+    return spmm_vsr_fused(bal, x, interpret=interpret, wb=wb,
                           visit_tile=visit_tile, visit_block=visit_block,
                           visit_start=visit_start, scales=scales)
 
@@ -459,8 +480,7 @@ def _pallas_nb(bal: BalancedCOO, x: jax.Array, scales: jax.Array | None = None,
 def _nb_work(bal: BalancedCOO, x: jax.Array, *,
              visit_tile: jax.Array | None = None,
              visit_block: jax.Array | None = None,
-             wb: int | None = None, tile_n: int | None = None,
-             **_) -> tuple[str, dict]:
+             wb: int | None = None, **_) -> tuple[str, dict]:
     """The per-call work of the kernel ``_pallas_nb`` binds, counted from
     the visit schedule and the operands of the call (host-side; with no
     schedule, from the one the kernel builds with ``plan_visits``).
@@ -475,8 +495,9 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
     ``rows_gathered``: for N > 1 the DMA row gather's ``(tile_loads +
     drained prefetches) × T × column blocks``, for N = 1 the ``cols.size``
     elements XLA gathers from ``x`` (``spmv._gathered``);
-    ``gather_unroll`` (N > 1): the row copies one iteration of the
-    gather's issue loop starts (``GATHER_UNROLL``)."""
+    ``column_blocks`` (N > 1): the column-block sweeps of the call, N over
+    ``gather_width``; ``gather_unroll`` (N > 1): the row copies one
+    iteration of the gather's issue loop starts (``GATHER_UNROLL``)."""
     n_tiles, t = bal.cols.shape
     n = 1 if x.ndim == 1 else x.shape[1]
     m = bal.shape[0]
@@ -513,10 +534,11 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
     if x.ndim == 1:
         rows = n_tiles * t
     else:
-        tile_n = TileGeometry().tile_n if tile_n is None else tile_n
-        rows = (tile_loads + drained) * t * -(-n // tile_n)
+        blocks = -(-n // gather_width(t, n))
+        rows = (tile_loads + drained) * t * blocks
     work = {"visits": visits, "tile_loads": tile_loads, "rows_gathered": rows}
     if x.ndim > 1:
+        work["column_blocks"] = blocks
         work["gather_unroll"] = GATHER_UNROLL
     if prefetched is not None:
         work["prefetched_loads"] = prefetched

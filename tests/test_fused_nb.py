@@ -26,7 +26,8 @@ from repro.core.quant import dequantize_stream, quantize_stream
 from repro.core.shard import VISIT_PAD, stack_visit_schedules
 from repro.kernels.fused_chain import sddmm_pallas
 from repro.kernels.spmv import spmv_vsr_fused
-from repro.kernels.vsr import GATHER_UNROLL, plan_visits, spmm_vsr_fused
+from repro.kernels.vsr import (GATHER_BYTES, GATHER_UNROLL, _vsr_fused_call,
+                               gather_width, plan_visits, spmm_vsr_fused)
 
 from conftest import random_csr
 
@@ -47,7 +48,7 @@ def _cases(rng):
     return cases
 
 
-@pytest.mark.parametrize("n", [1, 7, 128, 300, 256])
+@pytest.mark.parametrize("n", [1, 7, 128, 300, 256, 384, 1024, 1536])
 def test_fused_matches_dense_and_xla(rng, n):
     for name, csr, a in _cases(rng):
         bal = csr_to_balanced(csr, tile=64)
@@ -70,7 +71,9 @@ def test_fused_registry_default_through_execute(rng, n):
         entry = p.entry("nb_pr")
         opts = p.kernel_opts(entry)
         assert {"visit_tile", "visit_block", "visit_start",
-                "wb", "tile_n"} <= set(opts), name
+                "wb"} <= set(opts), name
+        # the column-block width follows the call's N, not the prep
+        assert "tile_n" not in opts, name
         x = rng.standard_normal((csr.shape[1], n)).astype(np.float32)
         xj = jnp.asarray(x[:, 0] if n == 1 else x)
         got = np.asarray(execute(p, xj, impl="nb_pr", interpret=True))
@@ -202,9 +205,9 @@ def _pipeline_case(name, rng):
                                   "remainder_tile", "short_tile"])
 def test_pipelined_gather_matches_dense_and_xla(rng, case, n, mode):
     """Tile i + 1's rows land in one slot while tile i reduces out of the
-    other; N = 256 makes two column-block sweeps, each starting cold.
-    Tiles of ``T`` not a multiple of ``GATHER_UNROLL`` issue some or all
-    of their copies from the issue loop's static tail."""
+    other; N = 256 gathers each row as one 256-wide unit copy.  Tiles of
+    ``T`` not a multiple of ``GATHER_UNROLL`` issue some or all of their
+    copies from the issue loop's static tail."""
     csr, bal, wb = _pipeline_case(case, rng)
     vt, vb, _ = plan_visits(bal, wb)
     runs = vt[np.r_[True, vt[1:] != vt[:-1]]]
@@ -225,6 +228,53 @@ def test_pipelined_gather_matches_dense_and_xla(rng, case, n, mode):
     np.testing.assert_allclose(got, xla, atol=2e-3)
     np.testing.assert_allclose(got, np.asarray(csr.to_dense()) @ x,
                                atol=2e-3)
+
+
+@pytest.mark.parametrize("mode", ["on_wait", "eager"])
+@pytest.mark.parametrize("tile,n,width", [(64, 1024, 1024), (512, 1536, 768)])
+def test_gather_width_matches_128_wide_blocks_bitwise(rng, tile, n, width,
+                                                      mode):
+    """The column blocks ``gather_width`` gives — one 1,024-wide block at
+    T = 64, two sweeps of 768 at T = 512 — against the kernel forced to
+    128-wide blocks.  Small integer operands make every product and sum
+    exact whatever order a backend's matmul adds in, so equality says each
+    output column reduced the same rows as at 128 wide."""
+    bal = csr_to_balanced(rmat(7, 8, seed=4), tile=tile)
+    bal = BalancedCOO(bal.rows, bal.cols, jnp.asarray(
+        rng.integers(-4, 5, bal.rows.shape), jnp.float32), bal.shape)
+    assert gather_width(tile, n) == width
+    vt, vb, vs = map(jnp.asarray, plan_visits(bal, 16))
+    x = jnp.asarray(rng.integers(-4, 5, (bal.shape[1], n)), jnp.float32)
+
+    def run(w, interpret):
+        xp = jnp.pad(x, ((0, 0), (0, -n % w)))
+        return np.asarray(_vsr_fused_call(
+            vt, vb, vs, bal.rows, bal.cols, bal.vals, xp, m=bal.shape[0],
+            wb=16, tile_n=w, interpret=interpret))[:, :n]
+
+    got = np.asarray(spmm_vsr_fused(bal, x, wb=16,
+                                    interpret=_dma_interpret(mode)))
+    np.testing.assert_array_equal(got, run(width, _dma_interpret(mode)))
+    np.testing.assert_array_equal(got, run(128, True))
+    np.testing.assert_array_equal(got, _dense(bal) @ np.asarray(x))
+
+
+@pytest.mark.parametrize("t,n,width", [
+    (256, 128, 128), (256, 1024, 1024), (256, 1536, 1536), (256, 4096, 2048),
+    (512, 128, 128), (512, 1024, 1024), (512, 1536, 768), (512, 4096, 1024),
+    (1024, 128, 128), (1024, 1024, 512), (1024, 1536, 512),
+    (1024, 4096, 512)])
+def test_gather_width(t, n, width):
+    """All of N where both ``(T, N)`` f32 gather slots fit
+    ``GATHER_BYTES``; else the fewest blocks that fit, cut equal so the
+    last pads least (1,536 at T = 512 is 2 × 768, not 1,024 + a half-empty
+    1,024)."""
+    assert gather_width(t, n) == width
+    blocks = -(-n // width)
+    widest = GATHER_BYTES // (2 * 4 * t) // 128 * 128
+    assert width % 128 == 0 and width <= widest
+    assert blocks == -(-n // widest)
+    assert blocks * width - n < 128 * blocks
 
 
 @pytest.mark.parametrize("mode", ["on_wait", "eager"])

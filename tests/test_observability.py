@@ -17,7 +17,8 @@ from repro.core import guardrails as G
 from repro.core.formats import BalancedCOO
 from repro.core.plan import pattern_prep, plan
 from repro.kernels.fused_chain import sddmm_work
-from repro.kernels.vsr import GATHER_UNROLL, _nb_work, plan_visits
+from repro.kernels.vsr import (GATHER_UNROLL, _nb_work, gather_width,
+                               plan_visits)
 
 
 @pytest.fixture(autouse=True)
@@ -138,10 +139,10 @@ def _prefetches(vt):
     return used, drained
 
 
-def _independent_count(bal, wb, n, tile_n):
+def _independent_count(bal, wb, n, width):
     """Visits, dummy visits, tile loads, prefetched loads (N > 1) and
-    gathered rows from the ``plan_visits`` schedule and the tiles' rows,
-    by direct iteration."""
+    gathered rows (column blocks ``width`` wide) from the ``plan_visits``
+    schedule and the tiles' rows, by direct iteration."""
     vt, vb, _ = plan_visits(bal, wb)
     rows = np.asarray(bal.rows)
     m = bal.shape[0]
@@ -154,7 +155,7 @@ def _independent_count(bal, wb, n, tile_n):
         return dict(count, rows_gathered=rows.size)
     used, drained = _prefetches(vt)
     return dict(count, prefetched_loads=used,
-                rows_gathered=(loads + drained) * t * -(-n // tile_n))
+                rows_gathered=(loads + drained) * t * -(-n // width))
 
 
 @pytest.mark.parametrize("csr", ["rmat", "banded"])
@@ -170,10 +171,10 @@ def test_work_matches_the_visit_schedule(csr, n):
     kernel = "repro_spmv_fused" if n == 1 else "repro_vsr_fused"
     assert key == f"{kernel}:{p.topology_key()[:12]}:n{n}"
     assert rec["kernel"] == kernel and rec["n"] == n and rec["binds"] == 1
-    want = _independent_count(p.substrate("balanced"), opts["wb"], n,
-                              opts["tile_n"])
+    want = _independent_count(p.substrate("balanced"), opts["wb"], n, 128)
     assert {k: rec[k] for k in want} == want
     if n == 128:                        # one 128-wide column block
+        assert rec["column_blocks"] == 1
         assert want["rows_gathered"] == want["tile_loads"] * p.tile
         # a row-ordered stream visits every tile: each load but the
         # sweep's first was prefetched, and no prefetch was drained
@@ -198,12 +199,13 @@ def test_work_counts_a_drained_prefetch():
     vt, vb, _ = plan_visits(bal, 16)
     assert 3 not in vt
     name, work = _nb_work(bal, jax.ShapeDtypeStruct((m, 256), jnp.float32),
-                          visit_tile=vt, visit_block=vb, wb=16, tile_n=128)
+                          visit_tile=vt, visit_block=vb, wb=16)
     loads = work["tile_loads"]
     assert name == "repro_vsr_fused"
     assert _prefetches(vt) == (loads - 2, 1)
     assert work["prefetched_loads"] == loads - 1 - 1   # less the drain
-    assert work["rows_gathered"] == (loads + 1) * t * 2  # two sweeps
+    assert work["column_blocks"] == 1                  # 256 columns: one
+    assert work["rows_gathered"] == (loads + 1) * t    # sweep
 
 
 def test_work_names_the_gather_unroll():
@@ -228,6 +230,36 @@ def test_work_names_the_gather_unroll():
     forward = dict(pw.forward)
     assert forward["gather_unroll"] == GATHER_UNROLL
     assert forward["tile_loads"] == 3 * one["tile_loads"]
+
+
+@pytest.mark.parametrize("n,blocks", [(128, 1), (1024, 1), (4096, 4)])
+def test_work_counts_column_blocks(n, blocks):
+    """``_nb_work`` and a pattern's ``PatternWork`` count the kernel's
+    column-block sweeps: at T = 512 one up to N = 1,024, where each row is
+    one copy, and four at N = 4,096; the rows gathered scale with them."""
+    bal = csr_to_balanced(rmat(9, 8, seed=5), tile=512)
+    t, k = bal.rows.shape[1], bal.shape[1]
+    vt, vb, _ = plan_visits(bal, 64)
+    x = jax.ShapeDtypeStruct((k, n), jnp.float32)
+    _, work = _nb_work(bal, x, visit_tile=vt, visit_block=vb, wb=64)
+    assert work["column_blocks"] == blocks
+    assert work["rows_gathered"] == work["tile_loads"] * t * blocks
+    prep = pattern_prep(np.asarray(bal.rows), np.asarray(bal.cols),
+                        bal.shape)
+    prep["work"].record_forward(n)
+    (rec,) = api.health()["work"].values()
+    assert rec["n"] == n and rec["column_blocks"] == blocks
+    assert rec["rows_gathered"] == work["rows_gathered"]
+
+
+@pytest.mark.parametrize("d,blocks", [(1024, 1), (4096, 4)])
+def test_sddmm_feature_blocks_follow_the_gather_width(d, blocks):
+    """The SDDMM cuts its features by the same rule: d = 1,024 at T = 512
+    stays one block of 1,024, d = 4,096 four."""
+    rows = jax.ShapeDtypeStruct((10, 512), jnp.int32)
+    _, work = sddmm_work(rows, jax.ShapeDtypeStruct((8, d), jnp.float32))
+    assert gather_width(512, d) == 1024
+    assert work["tile_loads"] == 10 * blocks
 
 
 def test_banded_graph_has_dummy_visits():

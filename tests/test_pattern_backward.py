@@ -80,7 +80,18 @@ def test_stacked_patterns_scan_with_the_kernel_backward():
     """Per-layer patterns stacked on a leading axis, scanned: each layer's
     slice of the prep (schedules padded with no-op visits) is its own, and
     the gradients match the flat backward layer by layer."""
-    m, k, tile, n, layers = 96, 64, 64, 40, 3
+    _stacked_scan(n=40)
+
+
+def test_stacked_patterns_scan_at_full_width():
+    """The same at N = 1,024: the forward and the transposed SpMM gather
+    each row once, 1,024 wide, and the SDDMM takes the 1,024 features in
+    one block."""
+    _stacked_scan(n=1024)
+
+
+def _stacked_scan(n):
+    m, k, tile, layers = 96, 64, 64, 3
     pats = [_pattern(10 + i, m, k, 0.1 + 0.05 * i, tile) for i in range(2)]
     n_tiles = max(p[0].shape[0] for p in pats)
 
@@ -212,8 +223,9 @@ def test_backward_kernels_record_their_work():
     assert len(fused) == 2
     assert fused[0]["topology"] != fused[1]["topology"]
     n_tiles, t = rows.shape
-    blocks = -(-n // 128)
+    # 130 columns fit one gather block at T = 64: each row is one copy
     for rec in fused:
-        assert rec["rows_gathered"] == rec["tile_loads"] * t * blocks
+        assert rec["column_blocks"] == 1
+        assert rec["rows_gathered"] == rec["tile_loads"] * t
     assert sddmm["tile_loads"] == n_tiles
     assert sddmm["rows_gathered"] == 2 * t * n_tiles

@@ -95,7 +95,7 @@ def test_sharded_prep_stacks_schedules_and_windows():
     opts = p.kernel_opts(p.entry("nb_pr"))
     n = p.shard_spec.n_shards
     assert set(opts) == {"visit_tile", "visit_block", "visit_start",
-                         "wb", "tile_n", "overlap_min_n"}
+                         "wb", "overlap_min_n"}
     assert opts["visit_tile"].shape[0] == n
     assert opts["visit_tile"].shape == opts["visit_block"].shape \
         == opts["visit_start"].shape
@@ -112,7 +112,7 @@ def test_sharded_prep_rejects_unstackable_opt(monkeypatch, bad):
         opts = entry.prep(bal, geometry=geometry)
         if bad == "unknown_key":
             return dict(opts, stray_opt=np.zeros(4, np.int32))
-        return {"wb": opts["wb"], "tile_n": opts["tile_n"]}
+        return {"wb": opts["wb"]}
 
     monkeypatch.setitem(registry._REGISTRY, ("nb_pr", "pallas"),
                         dataclasses.replace(entry, prep=prep))

@@ -253,6 +253,29 @@ def phi3_ffn():
     return rows, cols, (m, k), api.pattern_prep(rows, cols, (m, k))
 
 
+@pytest.mark.parametrize("tokens,blocks", [(1024, 1), (4096, 4)])
+def test_sparse_ffn_spmm_compiles_at_full_width(no_cache, one_chip, phi3_ffn,
+                                                tokens, blocks):
+    """The pruned FFN's SpMM at 1,024 tokens gathers each row once, in one
+    1,024-wide block of ``(rows, 1, w)`` unit rows; at 4,096 tokens (the
+    model's context) in four.  Two ``(512, 1024)`` f32 gather slots take
+    the 4 MiB ``GATHER_BYTES`` of VMEM, which the compile checks."""
+    from repro.kernels.vsr import gather_width
+    rows, cols, shape, prep = phi3_ffn
+    assert -(-tokens // gather_width(rows.shape[1], tokens)) == blocks
+    arrays, statics = _split_opts(prep)
+    vals = jax.ShapeDtypeStruct((rows.size,), jnp.float32)
+    x = jax.ShapeDtypeStruct((shape[1], tokens), jnp.float32)
+
+    def run(vals, x, rows, cols, arrays):
+        return api.pattern_matmul(rows, cols, vals, shape, x,
+                                  backend="pallas", interpret=False,
+                                  prep=dict(arrays, **statics))
+
+    _compile(run, (vals, x, rows, cols, arrays), one_chip,
+             ("repro_vsr_fused",))
+
+
 @pytest.mark.parametrize("tokens", [1024, 4096])
 def test_sparse_ffn_backward_compiles(no_cache, one_chip, phi3_ffn, tokens):
     """The gradient of one pruned FFN matrix at Phi-3-mini widths: the
