@@ -1239,7 +1239,8 @@ class PatternWork:
     ``(key, value)`` pairs; at the call's N, ``column_blocks`` is the
     kernel's (``kernels/vsr.gather_width``) and ``rows_gathered`` scales
     with it.  ``tiles``: the forward's tiles over all layers, which
-    the SDDMM of the value gradient loads once per feature block.  The
+    the SDDMM of the value gradient loads once per feature block.
+    ``tile`` and ``wb``: the geometry the kernels run at.  The
     topologies are digests of the stacked forward and transposed index
     streams, so the two SpMMs keep records of their own."""
     kernel: str
@@ -1249,6 +1250,7 @@ class PatternWork:
     transpose: tuple
     tiles: int
     tile: int
+    wb: int
 
     def _record(self, kernel, topology, work, n):
         HEALTH.record_work(f"{kernel}:{topology[:12]}:n{n}",
@@ -1256,7 +1258,7 @@ class PatternWork:
 
     def _spmm(self, counts, n):
         from repro.kernels.vsr import gather_width
-        blocks = -(-n // gather_width(self.tile, n))
+        blocks = -(-n // gather_width(self.tile, n, self.wb))
         work = dict(counts, column_blocks=blocks)
         work["rows_gathered"] *= blocks
         return work
@@ -1380,7 +1382,7 @@ def pattern_prep(rows, cols, shape: tuple) -> dict:
         transpose_topology=_opts_digest({"rows": trans["rows"],
                                          "cols": trans["cols"]}),
         transpose=summed([w for _, _, w in tsched]),
-        tiles=int(np.prod(rows_np.shape[:2])), tile=t)
+        tiles=int(np.prod(rows_np.shape[:2])), tile=t, wb=geom.wb)
     return prep
 
 

@@ -24,8 +24,8 @@ TPU adaptation (see DESIGN.md §2):
     count, and each nonzero of a tile copies the ``w``-wide slice of the
     dense row its column id names into a ``(T, w)`` VMEM scratch — one
     logical load covers ``w`` output columns (the V→N limit of float4).
-    ``w = gather_width(T, N)`` is all of N wherever the two gather slots
-    fit ``GATHER_BYTES``, so each nonzero's row is one copy.
+    ``w = gather_width(T, N, wb)`` is all of N wherever the two gather
+    slots fit ``GATHER_BYTES``, so each nonzero's row is one copy.
     The column ids ride an SMEM block so the scalar core can address the
     copies, ``GATHER_UNROLL`` of them an iteration of its issue loop; all
     ``T`` copies are in flight before one wait retires them (a
@@ -131,13 +131,18 @@ GATHER_UNROLL = 16
 GATHER_BYTES = 4 << 20
 
 
-def gather_width(t: int, n: int) -> int:
+def gather_width(t: int, n: int, wb: int = 0) -> int:
     """Columns a row copy of a ``T``-nonzero tile gathers: all of ``n``
     (lane-padded) where two ``(t, n)`` f32 slabs fit ``GATHER_BYTES``, so
     each nonzero's row is one copy; else ``n`` cut into the fewest equal
-    multiples of 128 that fit, so the last block pads the least."""
+    multiples of 128 that fit, so the last block pads the least.
+
+    ``wb``: the fused SpMM's output-block height.  Its pipeline holds two
+    ``(wb, w)`` f32 output blocks beside the two gather slots, so the width
+    fits whichever pair is taller: the VMEM that grows with ``w`` stays
+    within twice ``GATHER_BYTES`` at any geometry."""
     lanes = max(1, -(-n // 128))
-    fit = max(1, GATHER_BYTES // (2 * 4 * t) // 128)
+    fit = max(1, GATHER_BYTES // (2 * 4 * max(t, wb)) // 128)
     blocks = -(-lanes // fit)
     return -(-lanes // blocks) * 128
 
@@ -419,7 +424,7 @@ def spmm_vsr_fused(bal: BalancedCOO, x: jax.Array, *,
     if visit_tile is None or visit_block is None or visit_start is None:
         vt, vb, vs = plan_visits(bal, wb)
         visit_tile, visit_block, visit_start = map(jnp.asarray, (vt, vb, vs))
-    width = gather_width(bal.rows.shape[-1], n)
+    width = gather_width(bal.rows.shape[-1], n, wb)
     y = _vsr_fused_call(visit_tile, visit_block, visit_start,
                         bal.rows, bal.cols, bal.vals, _pad_n(x2, width),
                         scales, m=bal.shape[0], wb=wb, tile_n=width,
@@ -534,7 +539,7 @@ def _nb_work(bal: BalancedCOO, x: jax.Array, *,
     if x.ndim == 1:
         rows = n_tiles * t
     else:
-        blocks = -(-n // gather_width(t, n))
+        blocks = -(-n // gather_width(t, n, wb))
         rows = (tile_loads + drained) * t * blocks
     work = {"visits": visits, "tile_loads": tile_loads, "rows_gathered": rows}
     if x.ndim > 1:

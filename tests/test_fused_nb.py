@@ -24,6 +24,7 @@ from repro.core.cache import PlanCache, cached_plan, pattern_fingerprint
 from repro.core.formats import BalancedCOO
 from repro.core.quant import dequantize_stream, quantize_stream
 from repro.core.shard import VISIT_PAD, stack_visit_schedules
+from repro.kernels import vsr
 from repro.kernels.fused_chain import sddmm_pallas
 from repro.kernels.spmv import spmv_vsr_fused
 from repro.kernels.vsr import (GATHER_BYTES, GATHER_UNROLL, _vsr_fused_call,
@@ -173,6 +174,18 @@ def _dense(bal):
     return out
 
 
+def _fused_at(bal, x, wb, width, interpret, scales=None):
+    """``spmm_vsr_fused`` with its column blocks forced ``width`` wide
+    (``_vsr_fused_call``'s static), where ``gather_width`` would pick
+    another: at 128, N over 128 takes a sweep per block."""
+    n = x.shape[1]
+    vt, vb, vs = map(jnp.asarray, plan_visits(bal, wb))
+    xp = jnp.pad(x, ((0, 0), (0, -n % width)))
+    return np.asarray(_vsr_fused_call(
+        vt, vb, vs, bal.rows, bal.cols, bal.vals, xp, scales,
+        m=bal.shape[0], wb=wb, tile_n=width, interpret=interpret))[:, :n]
+
+
 def _pipeline_case(name, rng):
     """``(csr, bal, wb)`` of a schedule that takes one branch of the
     double-buffered gather."""
@@ -208,6 +221,40 @@ def test_pipelined_gather_matches_dense_and_xla(rng, case, n, mode):
     other; N = 256 gathers each row as one 256-wide unit copy.  Tiles of
     ``T`` not a multiple of ``GATHER_UNROLL`` issue some or all of their
     copies from the issue loop's static tail."""
+    csr, bal, wb, x = _checked_pipeline_case(case, n, rng)
+    assert gather_width(bal.rows.shape[1], n, wb) >= n
+    got = np.asarray(spmm_vsr_fused(bal, x, wb=wb,
+                                    interpret=_dma_interpret(mode)))
+    xla = np.asarray(execute(plan(csr, backend="xla"), x))
+    np.testing.assert_allclose(got, xla, atol=2e-3)
+    np.testing.assert_allclose(got, np.asarray(csr.to_dense()) @ x,
+                               atol=2e-3)
+
+
+@pytest.mark.parametrize("mode", ["on_wait", "eager"])
+@pytest.mark.parametrize("case", ["single_tile", "block_crossings",
+                                  "empty_row_bands", "skipped_tile",
+                                  "remainder_tile", "short_tile"])
+def test_pipelined_gather_two_sweeps(rng, case, mode):
+    """The same schedules with N = 300 cut into three 128-wide column
+    blocks: each sweep starts cold (no rows in flight on its first visit),
+    a prefetch drained at the end of one sweep leaves the next one's
+    semaphores balanced, and the last block is a third padding.  Where
+    ``gather_width`` has one block, the program takes this path at N over
+    the VMEM budget (4,096 tokens at T = 512), on the CSC path and per
+    shard."""
+    csr, bal, wb, x = _checked_pipeline_case(case, 300, rng)
+    got = _fused_at(bal, x, wb, 128, _dma_interpret(mode))
+    np.testing.assert_allclose(got, np.asarray(csr.to_dense()) @ x,
+                               atol=2e-3)
+    np.testing.assert_allclose(
+        got, np.asarray(spmm_vsr_fused(bal, x, wb=wb, interpret=True)),
+        atol=2e-3)
+
+
+def _checked_pipeline_case(case, n, rng):
+    """``_pipeline_case`` with the branch it is for asserted, and a dense
+    ``(k, n)`` operand."""
     csr, bal, wb = _pipeline_case(case, rng)
     vt, vb, _ = plan_visits(bal, wb)
     runs = vt[np.r_[True, vt[1:] != vt[:-1]]]
@@ -222,12 +269,7 @@ def test_pipelined_gather_matches_dense_and_xla(rng, case, n, mode):
                                                   "short_tile"))
     assert (t < GATHER_UNROLL) == (case == "short_tile")
     x = jnp.asarray(rng.standard_normal((csr.shape[1], n)).astype(np.float32))
-    got = np.asarray(spmm_vsr_fused(bal, x, wb=wb,
-                                    interpret=_dma_interpret(mode)))
-    xla = np.asarray(execute(plan(csr, backend="xla"), x))
-    np.testing.assert_allclose(got, xla, atol=2e-3)
-    np.testing.assert_allclose(got, np.asarray(csr.to_dense()) @ x,
-                               atol=2e-3)
+    return csr, bal, wb, x
 
 
 @pytest.mark.parametrize("mode", ["on_wait", "eager"])
@@ -243,19 +285,12 @@ def test_gather_width_matches_128_wide_blocks_bitwise(rng, tile, n, width,
     bal = BalancedCOO(bal.rows, bal.cols, jnp.asarray(
         rng.integers(-4, 5, bal.rows.shape), jnp.float32), bal.shape)
     assert gather_width(tile, n) == width
-    vt, vb, vs = map(jnp.asarray, plan_visits(bal, 16))
     x = jnp.asarray(rng.integers(-4, 5, (bal.shape[1], n)), jnp.float32)
-
-    def run(w, interpret):
-        xp = jnp.pad(x, ((0, 0), (0, -n % w)))
-        return np.asarray(_vsr_fused_call(
-            vt, vb, vs, bal.rows, bal.cols, bal.vals, xp, m=bal.shape[0],
-            wb=16, tile_n=w, interpret=interpret))[:, :n]
-
     got = np.asarray(spmm_vsr_fused(bal, x, wb=16,
                                     interpret=_dma_interpret(mode)))
-    np.testing.assert_array_equal(got, run(width, _dma_interpret(mode)))
-    np.testing.assert_array_equal(got, run(128, True))
+    np.testing.assert_array_equal(
+        got, _fused_at(bal, x, 16, width, _dma_interpret(mode)))
+    np.testing.assert_array_equal(got, _fused_at(bal, x, 16, 128, True))
     np.testing.assert_array_equal(got, _dense(bal) @ np.asarray(x))
 
 
@@ -277,11 +312,23 @@ def test_gather_width(t, n, width):
     assert blocks * width - n < 128 * blocks
 
 
+@pytest.mark.parametrize("t,wb,n,width", [
+    (512, 64, 1024, 1024), (512, 128, 1024, 1024), (128, 512, 4096, 1024),
+    (256, 1024, 1024, 512)])
+def test_gather_width_fits_the_output_blocks(t, wb, n, width):
+    """The pipeline's two ``(wb, w)`` output blocks fit ``GATHER_BYTES``
+    as the two gather slots do, so an output block taller than the tile
+    narrows the width; at ``wb <= T`` the slots alone decide."""
+    assert gather_width(t, n, wb) == width
+    assert 2 * 4 * width * max(t, wb) <= GATHER_BYTES
+    assert (width == gather_width(t, n)) == (wb <= t)
+
+
 @pytest.mark.parametrize("mode", ["on_wait", "eager"])
 def test_pipelined_gather_int8_stream(rng, mode):
-    """An int8 value stream with per-tile scales, over two sweeps: the
-    in-register dequant matches the kernel on the dequantized stream and
-    the dense product of it."""
+    """An int8 value stream with per-tile scales, in one 256-wide sweep
+    and in two sweeps forced 128 wide: the in-register dequant matches the
+    kernel on the dequantized stream and the dense product of it."""
     csr = rmat(6, 8, seed=5)
     bal = csr_to_balanced(csr, tile=64)
     q, sc = quantize_stream(bal.vals, "int8")
@@ -294,6 +341,8 @@ def test_pipelined_gather_int8_stream(rng, mode):
     fused = np.asarray(spmm_vsr_fused(deq, x, wb=16, interpret=True))
     np.testing.assert_allclose(got, fused, atol=2e-3)
     np.testing.assert_allclose(got, _dense(deq) @ np.asarray(x), atol=2e-3)
+    swept = _fused_at(qbal, x, 16, 128, _dma_interpret(mode), scales=sc)
+    np.testing.assert_allclose(swept, fused, atol=2e-3)
 
 
 @pytest.mark.parametrize("mode", ["on_wait", "eager"])
@@ -329,6 +378,27 @@ def test_pipelined_gather_grads_match_dense(rng):
         lambda v, xx: (execute(p, xx, vals=v, impl="nb_pr",
                                interpret=ip) ** 2).sum(),
         argnums=(0, 1))(csr.data, x)
+    np.testing.assert_allclose(np.asarray(gv), np.asarray(gd_v), atol=2e-3)
+    np.testing.assert_allclose(np.asarray(gx), np.asarray(gd_x), atol=2e-3)
+
+
+def test_pipelined_gather_grads_two_sweeps(rng, monkeypatch):
+    """Gradients through ``execute`` where N does not fit one column
+    block: the VMEM budget cut to two ``(32, 128)`` slots makes N = 200
+    two 128-wide sweeps, which the traced kernel's grid shows."""
+    monkeypatch.setattr(vsr, "GATHER_BYTES", 2 * 4 * 32 * 128)
+    csr = rmat(5, 6, seed=7)
+    a = np.asarray(csr.to_dense())
+    p = plan(csr, backend="pallas", tile=32)
+    x = jnp.asarray(rng.standard_normal((32, 200)).astype(np.float32))
+    gd_v, gd_x = _dense_grads(csr, a, x)
+    ip = _dma_interpret("on_wait")
+    grad = jax.grad(
+        lambda v, xx: (execute(p, xx, vals=v, impl="nb_pr",
+                               interpret=ip) ** 2).sum(),
+        argnums=(0, 1))
+    assert "grid=(2, " in str(jax.make_jaxpr(grad)(csr.data, x))
+    gv, gx = grad(csr.data, x)
     np.testing.assert_allclose(np.asarray(gv), np.asarray(gd_v), atol=2e-3)
     np.testing.assert_allclose(np.asarray(gx), np.asarray(gd_x), atol=2e-3)
 

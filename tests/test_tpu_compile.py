@@ -115,6 +115,23 @@ def test_quantized_nb_kernels_compile(no_cache, one_chip, graph, n):
                     quant="int8")
 
 
+@pytest.mark.parametrize("tile,wb", [(1024, 128), (512, 128), (128, 512)])
+def test_wide_spmm_fits_vmem_at_any_geometry(no_cache, one_chip, graph,
+                                             tile, wb):
+    """4,096 columns under the largest autotuner candidates and under an
+    output block taller than the tile (a geometry a thresholds file may
+    carry): ``gather_width`` fits the taller of the gather slots and the
+    output blocks, so the kernel's VMEM stays within its limit."""
+    from repro.core import TileGeometry, plan
+    from repro.kernels.vsr import GATHER_BYTES, gather_width
+    p = plan(graph.plan.csr, backend="pallas",
+             geometry=TileGeometry(tile=tile, wb=wb))
+    w = gather_width(tile, 4096, wb)
+    assert 2 * 4 * w * (tile + wb) <= 2 * GATHER_BYTES
+    x = jax.ShapeDtypeStruct((graph.shape[1], 4096), jnp.float32)
+    _compile_matmul(p, "nb_pr", x, one_chip, "repro_vsr_fused")
+
+
 def test_bsr_compiles(no_cache, one_chip):
     p = api.sparse(rmat(10, 4, seed=1), backend="bsr").plan
     x = jax.ShapeDtypeStruct((p.csr.shape[1], 128), jnp.float32)
